@@ -6,12 +6,14 @@ export PYTHONPATH := src:.$(if $(PYTHONPATH),:$(PYTHONPATH))
 .PHONY: test test-all bench bench-smoke lint
 
 # Tier-1 verify: deterministic suite; hypothesis modules auto-skip if absent.
+# Tests run on the CPU (JAX_PLATFORMS=cpu): kernels in interpret mode, plus
+# compiles for a described TPU; `python chip_smoke.py` is the on-chip check.
 test:
-	$(PY) -m pytest -x -q
+	JAX_PLATFORMS=cpu $(PY) -m pytest -x -q
 
 # Includes the property-based modules (pip install -r requirements-dev.txt).
 test-all:
-	$(PY) -m pytest -q
+	JAX_PLATFORMS=cpu $(PY) -m pytest -q
 
 # All paper-reproduction benchmarks as CSV (see EXPERIMENTS.md).
 bench:

@@ -21,14 +21,10 @@ headline rows here are cluster-vs-single-engine numbers:
 
 Methodology notes:
 
-* The measurement runs in a **subprocess** with ``XLA_FLAGS`` pinning XLA's
-  CPU intra-op pool to one thread.  Replicated serving on CPU wants
-  core-per-replica isolation — one engine must not fan its tiny per-step
-  ops across every core, or N replicas just fight over the same pool (the
-  thread-level mirror of the paper's one-core-per-array design).  The flag
-  applies to the single-engine baseline *and* the pool alike, so the
-  comparison stays same-host, same-thread-pool — and the subprocess keeps
-  the flag from leaking into other benchmark sections.
+* The measurement runs in the calling process: a device belongs to one
+  process, so a child started by a parent that already touched JAX could
+  not reach it.  The single engine and the pool share that process's XLA
+  thread pool, so the comparison stays same-host, same-threads.
 * Engines share one set of jitted step functions (same config, same
   shapes), so the whole benchmark compiles each step exactly once.
 * Both scenarios replay seeded traces (cluster/traffic.py): rerunning the
@@ -42,12 +38,6 @@ shrinks the model and traces to a smoke run of the same code paths.
 from __future__ import annotations
 
 import os
-import subprocess
-import sys
-
-_CHILD_ENV = "REPRO_CLUSTER_BENCH_CHILD"
-# One intra-op thread per replica: see the module docstring.
-_XLA_FLAGS = "--xla_cpu_multi_thread_eigen=false intra_op_parallelism_threads=1"
 
 FAST = os.environ.get("REPRO_BENCH_FAST", "").lower() not in ("", "0", "false")
 
@@ -196,7 +186,7 @@ def _prefix_rows(cfg, params, max_seq, warm_engine):
     ]
 
 
-def _child_rows():
+def rows():
     import jax
 
     from repro.models import model as M
@@ -207,34 +197,6 @@ def _child_rows():
     params = M.init_model(jax.random.PRNGKey(0), cfg)
     mixed, warm_engine = _mixed_rows(cfg, params, max_seq)
     return mixed + _prefix_rows(cfg, params, max_seq, warm_engine)
-
-
-def rows():
-    if os.environ.get(_CHILD_ENV):
-        return _child_rows()
-    env = dict(os.environ)
-    env[_CHILD_ENV] = "1"
-    env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "") + " " + _XLA_FLAGS).strip()
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    env["PYTHONPATH"] = os.pathsep.join(
-        p for p in (os.path.join(root, "src"), root,
-                    env.get("PYTHONPATH", "")) if p)
-    proc = subprocess.run(
-        [sys.executable, os.path.abspath(__file__)],
-        capture_output=True, text=True, env=env, cwd=root, timeout=1800)
-    if proc.returncode != 0:
-        raise RuntimeError(
-            f"cluster bench child failed:\n{proc.stdout}\n{proc.stderr}")
-    out = []
-    for line in proc.stdout.splitlines():
-        parts = line.rstrip("\n").split(",", 2)
-        if len(parts) == 3 and parts[0].startswith("cluster/"):
-            out.append({"name": parts[0], "value": parts[1],
-                        "derived": parts[2]})
-    if not out:
-        raise RuntimeError(f"cluster bench child produced no rows:\n"
-                           f"{proc.stdout}\n{proc.stderr}")
-    return out
 
 
 if __name__ == "__main__":

@@ -56,6 +56,9 @@ def main(argv=None) -> None:
     # other section's rows as removed).
     json_path = args.json or (
         "BENCH_smoke.json" if args.fast and not args.only else None)
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     from benchmarks import (
         cluster_bench,
         decode_bench,

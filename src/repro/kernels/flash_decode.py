@@ -9,10 +9,11 @@ block table is the stride program, and each grid step DMAs exactly one pool
 block.  HBM traffic per step drops from the table extent to the lived-in
 blocks, and nothing is ever materialized per slot.
 
-Shape story (one grid step = one pool block for one (slot, kv-head, split)):
+Shape story (one grid step = one pool block, all kv heads, for one
+(slot, split)):
 
   q            (B, Sq, Hq, D)     -> packed (B, Hkv, G * Sq, D) rows
-  k/v pool     (num_blocks, block_size, Hkv, D), addressed via the
+  k/v pool     (num_blocks, Hkv, block_size, D), addressed via the
                scalar-prefetched block table: block index
                ``tables[b, split * cols_per_split + j]``
   outputs      per-split partial (acc, m, l) — online-softmax state — reduced
@@ -57,7 +58,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.serving.kv_cache import NULL_BLOCK
+from repro.serving.kv_cache import NULL_BLOCK, gather_kv
 
 NEG_INF = -2.0e38
 
@@ -115,8 +116,9 @@ def _decode_kernel(
     else:
         acc_out, m_out, l_out, acc_ref, m_ref, l_ref = rest
     b = pl.program_id(0)
-    s = pl.program_id(2)
-    j = pl.program_id(3)
+    s = pl.program_id(1)
+    j = pl.program_id(2)
+    n_heads, rows = q_ref.shape[1], q_ref.shape[2]
 
     @pl.when(j == 0)
     def _init():
@@ -124,55 +126,62 @@ def _decode_kernel(
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    q = q_ref[0, 0].astype(jnp.float32) * scale            # (rows, D)
-    k = k_ref[0, :, 0, :].astype(jnp.float32)              # (block_size, D)
-    v = v_ref[0, :, 0, :].astype(jnp.float32)
-    if quantized:
-        k = k * ks_ref[0, :, 0][:, None]
-        v = v * vs_ref[0, :, 0][:, None]
-    scores = jax.lax.dot(q, k.T, preferred_element_type=jnp.float32)
-
     # Row r packs (group g, query offset t) = (r // sq, r % sq); padding rows
     # past G * Sq carry zero queries and are sliced off after the combine.
+    # The mask is the same for every kv head of the block.
     col = s * cols_per_split + j
-    t = jax.lax.broadcasted_iota(jnp.int32, scores.shape, 0) % sq
+    t = jax.lax.broadcasted_iota(jnp.int32, (rows, block_size), 0) % sq
     qpos = idx_ref[b] + t
     kpos = col * block_size + jax.lax.broadcasted_iota(
-        jnp.int32, scores.shape, 1)
+        jnp.int32, (rows, block_size), 1)
     mask = (kpos <= qpos) & (kpos < seq_cap)
     if window is not None:
         mask &= (qpos - kpos) < window
-    scores = jnp.where(mask, scores, NEG_INF)
 
-    m_prev = m_ref[...]                                     # (rows, 1)
-    m_new = jnp.maximum(m_prev, jnp.max(scores, axis=-1, keepdims=True))
-    p = jnp.exp(scores - m_new)
-    alpha = jnp.exp(m_prev - m_new)
-    l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=-1, keepdims=True)
-    m_ref[...] = m_new
-    acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot(
-        p, v, preferred_element_type=jnp.float32)
+    for h in range(n_heads):                                # static unroll
+        q = q_ref[0, h].astype(jnp.float32) * scale         # (rows, D)
+        k = k_ref[0, h].astype(jnp.float32)                 # (block_size, D)
+        v = v_ref[0, h].astype(jnp.float32)
+        scores = jax.lax.dot(q, k.T, preferred_element_type=jnp.float32)
+        if quantized:
+            # int8 codes times per-position scales: the scale of key i
+            # multiplies score column i (and value i weighs p column i), so
+            # the (1, block_size) scale row broadcasts over the rows.
+            scores = scores * ks_ref[0, h:h + 1, :]
+        scores = jnp.where(mask, scores, NEG_INF)
+
+        m_prev = m_ref[h]                                   # (rows, 1)
+        m_new = jnp.maximum(m_prev, jnp.max(scores, axis=-1, keepdims=True))
+        p = jnp.exp(scores - m_new)
+        alpha = jnp.exp(m_prev - m_new)
+        l_ref[h] = l_ref[h] * alpha + jnp.sum(p, axis=-1, keepdims=True)
+        m_ref[h] = m_new
+        if quantized:
+            p = p * vs_ref[0, h:h + 1, :]
+        acc_ref[h] = acc_ref[h] * alpha + jax.lax.dot(
+            p, v, preferred_element_type=jnp.float32)
 
     @pl.when(j == cols_per_split - 1)
     def _flush():
-        acc_out[0, 0, 0] = acc_ref[...]
-        m_out[0, 0, 0] = m_ref[...][:, 0]
-        l_out[0, 0, 0] = l_ref[...][:, 0]
+        acc_out[0, 0] = acc_ref[...]
+        for h in range(n_heads):
+            m_out[0, 0, h] = m_ref[h][:, 0]
+            l_out[0, 0, h] = l_ref[h][:, 0]
 
 
 def _combine_splits(acc, m, l):
     """Stage 2 of split-K: merge per-split online-softmax partials.
 
-    acc (B, H, S, rows, D); m, l (B, H, S, rows).  A fully-masked split
+    acc (B, S, H, rows, D); m, l (B, S, H, rows).  A fully-masked split
     carries (acc=0, m=NEG_INF, l=0): its alpha underflows to zero against any
     live split, and when *every* split is masked the l floor keeps the (all
     padding rows / inactive slot) output finite — garbage, but finite, and
     hidden by the caller exactly like the gather path's null-block rows.
     """
-    m_g = jnp.max(m, axis=2)                               # (B, H, rows)
-    alpha = jnp.exp(m - m_g[:, :, None])                   # (B, H, S, rows)
-    l_g = jnp.sum(l * alpha, axis=2)
-    acc_g = jnp.sum(acc * alpha[..., None], axis=2)
+    m_g = jnp.max(m, axis=1)                               # (B, H, rows)
+    alpha = jnp.exp(m - m_g[:, None])                      # (B, S, H, rows)
+    l_g = jnp.sum(l * alpha, axis=1)
+    acc_g = jnp.sum(acc * alpha[..., None], axis=1)
     return acc_g / jnp.maximum(l_g, 1e-30)[..., None]      # (B, H, rows, D)
 
 
@@ -206,10 +215,17 @@ def flash_decode_attention(
     spec: Optional[FlashDecodeSpec] = None,
     interpret: bool = False,
 ) -> jax.Array:
-    """Decode attention over the paged pool via the Pallas kernel."""
+    """Decode attention over the paged pool via the Pallas kernel.
+
+    Every block the kernel touches keeps its last two dims equal to whole
+    array dims, so the TPU's (8, 128) tiling rule holds for any kv-head
+    count, block size and split factor: K/V come in as (1, Hkv, bs, D) pool
+    blocks, scales as (1, Hkv, bs), and the per-split partials leave as
+    (1, 1, Hkv, rows, D) and (1, 1, Hkv, rows).
+    """
     spec = spec or FlashDecodeSpec()
     B, Sq, Hq, D = q.shape
-    nb, bs, Hkv, _ = cache.k.shape
+    nb, Hkv, bs, _ = cache.k.shape
     groups = Hq // Hkv
     max_blocks = block_tables.shape[1]
     seq_cap = max_blocks * bs
@@ -226,35 +242,33 @@ def flash_decode_attention(
         idx = jnp.broadcast_to(idx, (B,))
 
     qr, rows, rows_p = _pack_q(q, groups, Hkv)
-    k_scale = getattr(cache, "k_scale", None)
-    v_scale = getattr(cache, "v_scale", None)
-    quantized = k_scale is not None
+    quantized = cache.k_scale is not None
 
-    def bmap(b, h, s, j, bt, idx):
-        return (b, h, 0, 0)
+    def qmap(b, s, j, bt, idx):
+        return (b, 0, 0, 0)
 
-    def kvmap(b, h, s, j, bt, idx, cps=cps):
-        return (bt[b, s * cps + j], 0, h, 0)
+    def kvmap(b, s, j, bt, idx, cps=cps):
+        return (bt[b, s * cps + j], 0, 0, 0)
 
-    def smap(b, h, s, j, bt, idx, cps=cps):
-        return (bt[b, s * cps + j], 0, h)
+    def smap(b, s, j, bt, idx, cps=cps):
+        return (bt[b, s * cps + j], 0, 0)
 
     in_specs = [
-        pl.BlockSpec((1, 1, rows_p, D), bmap),
-        pl.BlockSpec((1, bs, 1, D), kvmap),
-        pl.BlockSpec((1, bs, 1, D), kvmap),
+        pl.BlockSpec((1, Hkv, rows_p, D), qmap),
+        pl.BlockSpec((1, Hkv, bs, D), kvmap),
+        pl.BlockSpec((1, Hkv, bs, D), kvmap),
     ]
     operands = [qr, cache.k, cache.v]
     if quantized:
-        in_specs += [pl.BlockSpec((1, bs, 1), smap),
-                     pl.BlockSpec((1, bs, 1), smap)]
-        operands += [k_scale, v_scale]
+        in_specs += [pl.BlockSpec((1, Hkv, bs), smap),
+                     pl.BlockSpec((1, Hkv, bs), smap)]
+        operands += [cache.k_scale, cache.v_scale]
 
-    def out_map4(b, h, s, j, bt, idx):
-        return (b, h, s, 0)
+    def out_map4(b, s, j, bt, idx):
+        return (b, s, 0, 0)
 
-    def out_map5(b, h, s, j, bt, idx):
-        return (b, h, s, 0, 0)
+    def out_map5(b, s, j, bt, idx):
+        return (b, s, 0, 0, 0)
 
     kernel = functools.partial(
         _decode_kernel, cols_per_split=cps, block_size=bs, sq=Sq,
@@ -262,26 +276,26 @@ def flash_decode_attention(
     )
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(B, Hkv, splits, cps),
+        grid=(B, splits, cps),
         in_specs=in_specs,
         out_specs=[
-            pl.BlockSpec((1, 1, 1, rows_p, D), out_map5),
-            pl.BlockSpec((1, 1, 1, rows_p), out_map4),
-            pl.BlockSpec((1, 1, 1, rows_p), out_map4),
+            pl.BlockSpec((1, 1, Hkv, rows_p, D), out_map5),
+            pl.BlockSpec((1, 1, Hkv, rows_p), out_map4),
+            pl.BlockSpec((1, 1, Hkv, rows_p), out_map4),
         ],
         scratch_shapes=[
-            pltpu.VMEM((rows_p, D), jnp.float32),
-            pltpu.VMEM((rows_p, 1), jnp.float32),
-            pltpu.VMEM((rows_p, 1), jnp.float32),
+            pltpu.VMEM((Hkv, rows_p, D), jnp.float32),
+            pltpu.VMEM((Hkv, rows_p, 1), jnp.float32),
+            pltpu.VMEM((Hkv, rows_p, 1), jnp.float32),
         ],
     )
     acc, m, l = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=[
-            jax.ShapeDtypeStruct((B, Hkv, splits, rows_p, D), jnp.float32),
-            jax.ShapeDtypeStruct((B, Hkv, splits, rows_p), jnp.float32),
-            jax.ShapeDtypeStruct((B, Hkv, splits, rows_p), jnp.float32),
+            jax.ShapeDtypeStruct((B, splits, Hkv, rows_p, D), jnp.float32),
+            jax.ShapeDtypeStruct((B, splits, Hkv, rows_p), jnp.float32),
+            jax.ShapeDtypeStruct((B, splits, Hkv, rows_p), jnp.float32),
         ],
         interpret=interpret,
     )(bt, idx, *operands)
@@ -313,7 +327,7 @@ def ref_paged_decode(
     length, unlike shape-bounded slicing which would recompile per length.
     """
     B, Sq, Hq, D = q.shape
-    nb, bs, Hkv, _ = cache.k.shape
+    _, Hkv, bs, _ = cache.k.shape
     groups = Hq // Hkv
     max_blocks = block_tables.shape[1]
     seq_cap = max_blocks * bs
@@ -326,13 +340,6 @@ def ref_paged_decode(
     idx = jnp.asarray(index, jnp.int32)
     if idx.ndim == 0:
         idx = jnp.broadcast_to(idx, (B,))
-
-    k_scale = getattr(cache, "k_scale", None)
-    v_scale = getattr(cache, "v_scale", None)
-    k_flat = cache.k.reshape(nb * bs, Hkv, D)
-    v_flat = cache.v.reshape(nb * bs, Hkv, D)
-    ks_flat = None if k_scale is None else k_scale.reshape(nb * bs, Hkv)
-    vs_flat = None if v_scale is None else v_scale.reshape(nb * bs, Hkv)
 
     qf = (q.astype(jnp.float32) * (D ** -0.5)).reshape(B, Sq, Hkv, groups, D)
     qf = qf.transpose(0, 2, 3, 1, 4)                       # (B, H, G, Sq, D)
@@ -348,15 +355,7 @@ def ref_paged_decode(
     def body(carry):
         col, m, l, acc = carry
         blk = jax.lax.dynamic_slice(bt, (0, col), (B, C))  # (B, C)
-        flat = (blk[:, :, None] * bs
-                + jnp.arange(bs, dtype=jnp.int32)[None, None, :]).reshape(-1)
-        k = jnp.take(k_flat, flat, axis=0).reshape(B, span, Hkv, D)
-        v = jnp.take(v_flat, flat, axis=0).reshape(B, span, Hkv, D)
-        if ks_flat is not None:
-            k = k.astype(jnp.float32) * jnp.take(
-                ks_flat, flat, axis=0).reshape(B, span, Hkv)[..., None]
-            v = v.astype(jnp.float32) * jnp.take(
-                vs_flat, flat, axis=0).reshape(B, span, Hkv)[..., None]
+        k, v = gather_kv(cache, blk)                       # (B, span, H, D)
         s = jnp.einsum(
             "bhgqd,bkhd->bhgqk", qf, k.astype(jnp.float32),
             preferred_element_type=jnp.float32,
@@ -458,7 +457,6 @@ def _gather_decode(q, cache, block_tables, index, *, window=None,
     ``prefix_len`` fallback (bidirectional prefixes never page in practice —
     VLM/encdec are excluded from paged serving)."""
     from repro.models.attention import decode_attention
-    from repro.serving.kv_cache import gather_kv
 
     k, v = gather_kv(cache, block_tables)
     return decode_attention(q, k, v, index=index, window=window,

@@ -10,8 +10,8 @@ just to reach the serving CLI.
 import importlib
 
 _SUBMODULES = (
-    "diagnose", "dryrun", "hlo_cost", "mesh", "roofline", "serve", "steps",
-    "train",
+    "compile_cache", "diagnose", "dryrun", "hlo_cost", "mesh", "roofline",
+    "serve", "steps", "train",
 )
 
 _LAZY = {

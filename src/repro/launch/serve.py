@@ -9,6 +9,12 @@ EXPERIMENTS.md §Serving for the mechanism table and measured speedups.
   PYTHONPATH=src python -m repro.launch.serve --arch gemma3-1b --requests 8 \
       --autotune --compare-prefill
 
+``--size full`` serves the arch's published config (``configs.get``) instead
+of its reduced CPU preset; run it on the accelerator:
+
+  PYTHONPATH=src python -m repro.launch.serve --arch gemma3-1b --size full \
+      --autotune --prompt-len 512 --gen-len 32
+
 ``--compare-prefill`` additionally times the legacy token-by-token prefill
 loop (decode steps over a padded batch) against the engine's chunked prefill
 on the same prompts and prints the wall-clock speedup.
@@ -31,6 +37,7 @@ import numpy as np
 
 from repro import configs
 from repro.launch import steps as steps_lib
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import model as M
 from repro.serving.engine import (  # re-exported for back-compat
     Engine,
@@ -184,7 +191,9 @@ def _evaluate_slo(args, snapshot, recorder, engines):
 
 
 def serve_cluster(cfg, args) -> None:
-    """Multi-replica serving (repro.cluster): pool + router + traffic."""
+    """Multi-replica serving (repro.cluster): pool + router + traffic.
+
+    Exits nonzero (SystemExit) when any request resolved with an error."""
     from repro import cluster
 
     max_seq = args.prompt_len + args.gen_len + 1
@@ -233,6 +242,7 @@ def serve_cluster(cfg, args) -> None:
         sampling=None if sampling.is_greedy else sampling)
     router.drain()
     elapsed = time.time() - t0
+    failed = [h for h in handles if h.error is not None]
     m = cluster.aggregate(pool, router, elapsed_s=elapsed)
     print(f"cluster[{args.router_policy}]: {m.summary()}")
     for i, e in enumerate(pool.engines):
@@ -253,11 +263,19 @@ def serve_cluster(cfg, args) -> None:
                        "replicas": [e.metrics.as_dict()
                                     for e in pool.engines]}, f, indent=2)
         print(f"metrics: {args.metrics_json}")
+    if failed:
+        raise SystemExit(
+            f"{len(failed)} of {len(handles)} requests failed; first: "
+            f"request {failed[0].crid} on replica {failed[0].replica}: "
+            f"{failed[0].error!r}")
 
 
 def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="gemma3-1b", choices=configs.list_archs())
+    ap.add_argument("--size", default="smoke", choices=["smoke", "full"],
+                    help="smoke: the arch's reduced preset (CPU-sized); "
+                         "full: its published config")
     ap.add_argument("--requests", type=int, default=4)
     ap.add_argument("--slots", type=int, default=0,
                     help="decode batch slots (default: --requests)")
@@ -348,7 +366,9 @@ def main(argv=None):
                          "self-contained JSON incident bundles here")
     args = ap.parse_args(argv)
 
-    cfg = configs.get_smoke(args.arch)
+    enable_compile_cache()
+    cfg = (configs.get(args.arch) if args.size == "full"
+           else configs.get_smoke(args.arch))
     if args.replicas > 1:
         return serve_cluster(cfg, args)
     sampling = _sampling_from_args(args)

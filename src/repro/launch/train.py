@@ -18,6 +18,7 @@ import jax
 from repro import configs
 from repro.data import SyntheticLMData
 from repro.launch import steps as steps_lib
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_local_mesh
 from repro.models import model as M
 from repro.optim import adamw_init
@@ -78,6 +79,7 @@ def main(argv=None):
     ap.add_argument("--quant", default=None, choices=[None, "int8"])
     args = ap.parse_args(argv)
 
+    enable_compile_cache()
     cfg = preset_config(args.arch, args.preset)
     mesh = make_local_mesh(args.model_parallel)
     plan = shard_lib.make_plan(mesh, cfg.param_count(), force_fsdp=False)

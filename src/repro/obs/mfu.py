@@ -28,11 +28,10 @@ The gauges are a few float adds per tick (the bound is memoized per
 (phase, rows)) — cheap enough to stay on by default; `EngineMetrics` and
 `ClusterMetrics` surface them in `summary()`.
 
-NOTE on absolute values: the hardware constants describe the target
-TPU-class chip.  On the CPU CI host the measured step is far slower than
-the TPU-modeled bound, so utilization reads in the fractions-of-a-percent
-— the *trend* (per phase, across configs, across PRs) is the signal there;
-the absolute figure becomes paper-comparable on real accelerator hardware.
+Peaks come from `core/hw.py::PEAKS`, looked up by the running device's
+``device_kind``.  On a device the table does not list (the CPU among them)
+both gauges are not measured: `utilization`/`mfu` return None, never a
+figure against another chip's peaks.
 """
 
 from __future__ import annotations
@@ -40,7 +39,7 @@ from __future__ import annotations
 import dataclasses
 from typing import Dict, Iterable, Optional
 
-from repro.core.hw import HBM_BW, PEAK_FLOPS_BF16
+from repro.core.hw import PEAKS
 
 PHASES = ("prefill", "decode", "verify")
 
@@ -70,12 +69,18 @@ class PhaseStat:
 class MfuMeter:
     """Per-phase utilization/MFU accounting for one model config."""
 
-    def __init__(self, cfg, *, peak_flops: float = PEAK_FLOPS_BF16,
-                 hbm_bw: float = HBM_BW):
+    def __init__(self, cfg, *, device_kind: Optional[str] = None):
+        """`device_kind` defaults to that of ``jax.devices()[0]``."""
+        if device_kind is None:
+            import jax
+
+            device_kind = jax.devices()[0].device_kind
         self.arch = cfg.name
         self.dtype = cfg.dtype
-        self.peak_flops = float(peak_flops)
-        self.hbm_bw = float(hbm_bw)
+        self.device_kind = device_kind
+        peaks = PEAKS.get(device_kind)
+        self.peak_flops = peaks.bf16_flops if peaks else None
+        self.hbm_bw = peaks.hbm_bw if peaks else None
         active = cfg.active_param_count()
         self.flops_per_token = 2.0 * active
         self.param_bytes = active * _DTYPE_BYTES.get(cfg.dtype, 2)
@@ -95,7 +100,13 @@ class MfuMeter:
         st.rows += rows
         st.steps += 1
         st.flops += tokens * self.flops_per_token
-        st.bound_s += self.step_bound_s(rows)
+        if self.measurable:
+            st.bound_s += self.step_bound_s(rows)
+
+    @property
+    def measurable(self) -> bool:
+        """True when the device has published peaks to measure against."""
+        return self.peak_flops is not None
 
     def step_bound_s(self, rows: int) -> float:
         """Analytic lower-bound time for one step executing `rows` token
@@ -155,14 +166,20 @@ class MfuMeter:
 
     # -- reporting -----------------------------------------------------------
 
-    def utilization(self, phase: str) -> float:
+    def utilization(self, phase: str) -> Optional[float]:
         """Modeled time / measured time for this phase (the paper's
-        temporal-utilization analogue; 0.0 before any step ran)."""
+        temporal-utilization analogue; 0.0 before any step ran, None on a
+        device without published peaks)."""
+        if not self.measurable:
+            return None
         st = self.phases[phase]
         return st.bound_s / st.time_s if st.time_s > 0 else 0.0
 
-    def mfu(self, phase: str) -> float:
-        """Useful model FLOPs / (measured time x peak FLOP/s)."""
+    def mfu(self, phase: str) -> Optional[float]:
+        """Useful model FLOPs / (measured time x peak FLOP/s); None on a
+        device without published peaks."""
+        if not self.measurable:
+            return None
         st = self.phases[phase]
         return (st.flops / (st.time_s * self.peak_flops)
                 if st.time_s > 0 else 0.0)
@@ -182,26 +199,30 @@ class MfuMeter:
         meters = [m for m in meters if m is not None]
         if not meters:
             return None
-        out = cls(meters[0]._cfg, peak_flops=meters[0].peak_flops,
-                  hbm_bw=meters[0].hbm_bw)
+        out = cls(meters[0]._cfg, device_kind=meters[0].device_kind)
         for m in meters:
             out.merge(m)
         return out
 
     def summary(self) -> str:
         """Compact per-phase fragment for EngineMetrics.summary():
-        ``util[decode]=0.12% mfu[decode]=0.03% ...`` (active phases only).
+        ``util[decode]=0.12% mfu[decode]=0.03% ...`` (active phases only;
+        "n/m" where the device has no published peaks).
         """
+        def pct(v):
+            return "n/m" if v is None else f"{v:.2%}"
+
         parts = []
         for p in self.active_phases():
-            parts.append(f"util[{p}]={self.utilization(p):.2%} "
-                         f"mfu[{p}]={self.mfu(p):.2%}")
+            parts.append(f"util[{p}]={pct(self.utilization(p))} "
+                         f"mfu[{p}]={pct(self.mfu(p))}")
         return " ".join(parts)
 
     def as_dict(self) -> dict:
         return {
             "arch": self.arch,
             "dtype": self.dtype,
+            "device_kind": self.device_kind,
             "peak_flops": self.peak_flops,
             "hbm_bw": self.hbm_bw,
             "phases": {

@@ -47,7 +47,7 @@ from repro.models import model as M
 from repro.obs import Histogram, MfuMeter, NULL_TRACER, Tracer
 from repro.obs import percentile as _obs_percentile
 from repro.serving import kv_cache as kvc
-from repro.serving.prefill import chunk_buckets
+from repro.serving.prefill import chunk_buckets, plan_chunks
 from repro.serving.request import RequestSpec, as_spec, priority_rank
 from repro.serving.scheduler import Phase, Request, Scheduler
 from repro.serving.speculative import (
@@ -794,6 +794,34 @@ class Engine:
             with self._precision_ctx():   # cold trace: bind the precision
                 return fn(*args)
         return fn(*args)
+
+    def prompt_logits(self, prompt) -> np.ndarray:
+        """Next-token logits (vocab,) of one prompt, computed by this
+        engine's own compiled prefill-chunk steps (kernels, precision and
+        decode spec as bound at warmup) on a scratch state of the engine's
+        shapes: the logits the serving path takes a request's first token
+        from, for comparison against a reference forward.  The live state
+        and slots are untouched; after warmup() nothing compiles here."""
+        prompt = np.asarray(prompt, np.int32)
+        if not 0 < len(prompt) <= self.max_seq:
+            raise ValueError(
+                f"prompt of {len(prompt)} tokens; need 1..{self.max_seq}")
+        state = M.init_paged_decode_state(
+            self.cfg, self.slots, num_blocks=self.num_blocks,
+            block_size=self.block_size,
+            max_blocks_per_slot=self.max_blocks_per_slot,
+            kv_precision=self.kv_precision)
+        tables = np.zeros((self.slots, self.max_blocks_per_slot), np.int32)
+        n = kvc.blocks_for(len(prompt), self.block_size)
+        tables[0, :n] = np.arange(1, n + 1)       # slot 0, blocks 1..n
+        state = state._replace(block_tables=jnp.asarray(tables))
+        start, logits = 0, None
+        for c in plan_chunks(len(prompt), self.max_chunk):
+            logits, state = self._run_compiled(
+                f"chunk{c}", self._chunk_fn, self.params, state,
+                jnp.asarray(prompt[None, start:start + c]), self._slot_ids[0])
+            start += c
+        return np.asarray(logits)[0, -1]
 
     # -- request lifecycle ---------------------------------------------------
 

@@ -10,8 +10,13 @@ with a new request reuses freed blocks without re-initializing the pool.
 
 Layout (per attention layer):
 
-  k_pool / v_pool : (num_blocks, block_size, H_kv, D)
+  k_pool / v_pool : (num_blocks, H_kv, block_size, D)
   block_tables    : (slots, max_blocks_per_slot) int32, entries index blocks
+
+Heads sit outside positions so that one pool block, all heads included, is
+one contiguous tile-aligned slab: the flash-decode kernel fetches it as a
+(1, H_kv, block_size, D) block whose last two dims are whole array dims,
+which the TPU's (8, 128) tiling rule accepts for any H_kv and block size.
 
 Block id 0 is a reserved *null* block: unallocated table entries point at
 it, and writes from idle slots or masked positions land there.  It is never
@@ -53,9 +58,9 @@ class PagedKVCache(NamedTuple):
     scale fields None — both layouts are valid pytrees of one NamedTuple.
     """
 
-    k: jax.Array  # (num_blocks, block_size, H_kv, D)
-    v: jax.Array  # (num_blocks, block_size, H_kv, D)
-    k_scale: Optional[jax.Array] = None  # (num_blocks, block_size, H_kv) f32
+    k: jax.Array  # (num_blocks, H_kv, block_size, D)
+    v: jax.Array  # (num_blocks, H_kv, block_size, D)
+    k_scale: Optional[jax.Array] = None  # (num_blocks, H_kv, block_size) f32
     v_scale: Optional[jax.Array] = None
 
     @property
@@ -64,7 +69,7 @@ class PagedKVCache(NamedTuple):
 
     @property
     def block_size(self) -> int:
-        return self.k.shape[-3]
+        return self.k.shape[-2]
 
     @property
     def quantized(self) -> bool:
@@ -75,7 +80,7 @@ def init_paged_kv(
     num_blocks: int, block_size: int, n_kv_heads: int, head_dim: int, dtype,
     *, kv_precision: str = "float",
 ) -> PagedKVCache:
-    shape = (num_blocks, block_size, n_kv_heads, head_dim)
+    shape = (num_blocks, n_kv_heads, block_size, head_dim)
     if kv_precision == "int8":
         sshape = shape[:-1]
         return PagedKVCache(
@@ -99,15 +104,16 @@ def quantize_kv_tokens(x: jax.Array) -> Tuple[jax.Array, jax.Array]:
     return q, scale
 
 
-def _flat_positions(block_tables: jax.Array, start, S: int, block_size: int
-                    ) -> jax.Array:
-    """Pool-flat write/read indices for S tokens starting at `start` per slot.
+def _block_positions(block_tables: jax.Array, start, S: int, block_size: int
+                     ) -> Tuple[jax.Array, jax.Array]:
+    """Pool (block, offset) write indices for S tokens starting at `start`
+    per slot.
 
-    block_tables: (B, max_blocks); start: scalar or (B,).  Returns (B, S)
-    indices into the (num_blocks * block_size)-flattened pool.  Positions
-    beyond a slot's table capacity resolve to the null block — without the
-    explicit mask, take_along_axis would clamp to the table's *last* entry
-    and silently overwrite a live block.
+    block_tables: (B, max_blocks); start: scalar or (B,).  Returns two (B, S)
+    arrays: the pool block id and the position inside it.  Positions beyond
+    a slot's table capacity resolve to the null block — without the explicit
+    mask, take_along_axis would clamp to the table's *last* entry and
+    silently overwrite a live block.
     """
     start = jnp.asarray(start, jnp.int32)
     if start.ndim == 0:
@@ -118,7 +124,7 @@ def _flat_positions(block_tables: jax.Array, start, S: int, block_size: int
     blk = jnp.take_along_axis(
         block_tables, jnp.minimum(pos, table_cap - 1) // block_size, axis=1)
     blk = jnp.where(pos < table_cap, blk, NULL_BLOCK)
-    return blk * block_size + pos % block_size
+    return blk, pos % block_size
 
 
 def write_kv(
@@ -133,25 +139,23 @@ def write_kv(
     k_new/v_new: (B, S, H, D).  Distinct live slots own distinct blocks, so
     real writes never collide; idle-slot writes collapse onto the null block.
     """
-    nb, bs, H, D = cache.k.shape
-    B, S = k_new.shape[:2]
-    flat = _flat_positions(block_tables, start, S, bs).reshape(-1)
+    H, D = cache.k.shape[1], cache.k.shape[3]
+    blk, off = _block_positions(block_tables, start, k_new.shape[1],
+                                cache.block_size)
+    blk, off = blk.reshape(-1), off.reshape(-1)
+    # Advanced indices split by a slice: the updates are (B * S, H[, D]).
     k_scale, v_scale = cache.k_scale, cache.v_scale
     if cache.quantized:
         # Quantize on write (per token x kv-head); the pool never sees floats.
         k_new, ks = quantize_kv_tokens(k_new)
         v_new, vs = quantize_kv_tokens(v_new)
-        k_scale = k_scale.reshape(nb * bs, H).at[flat].set(
-            ks.reshape(-1, H), mode="drop").reshape(nb, bs, H)
-        v_scale = v_scale.reshape(nb * bs, H).at[flat].set(
-            vs.reshape(-1, H), mode="drop").reshape(nb, bs, H)
-    k_pool = cache.k.reshape(nb * bs, H, D).at[flat].set(
+        k_scale = k_scale.at[blk, :, off].set(ks.reshape(-1, H), mode="drop")
+        v_scale = v_scale.at[blk, :, off].set(vs.reshape(-1, H), mode="drop")
+    k_pool = cache.k.at[blk, :, off].set(
         k_new.astype(cache.k.dtype).reshape(-1, H, D), mode="drop")
-    v_pool = cache.v.reshape(nb * bs, H, D).at[flat].set(
+    v_pool = cache.v.at[blk, :, off].set(
         v_new.astype(cache.v.dtype).reshape(-1, H, D), mode="drop")
-    return PagedKVCache(k=k_pool.reshape(nb, bs, H, D),
-                        v=v_pool.reshape(nb, bs, H, D),
-                        k_scale=k_scale, v_scale=v_scale)
+    return PagedKVCache(k=k_pool, v=v_pool, k_scale=k_scale, v_scale=v_scale)
 
 
 def copy_blocks(
@@ -184,20 +188,21 @@ def gather_kv(
     position, so that garbage is never attended.  int8 pools are dequantized
     here (f32 out) — this path materializes the view anyway, so there is no
     byte saving to preserve; the flash-decode kernel dequantizes in-register
-    instead (kernels/flash_decode.py).
+    instead (kernels/flash_decode.py).  Any (B, C) slice of table columns
+    works too: the bounded decode fallback reads C columns per iteration.
     """
-    nb, bs, H, D = cache.k.shape
-    B, max_blocks = block_tables.shape
-    flat = (block_tables[:, :, None] * bs
-            + jnp.arange(bs, dtype=jnp.int32)[None, None, :]).reshape(B, -1)
-    k = jnp.take(cache.k.reshape(nb * bs, H, D), flat, axis=0)
-    v = jnp.take(cache.v.reshape(nb * bs, H, D), flat, axis=0)
-    if cache.quantized:
-        ks = jnp.take(cache.k_scale.reshape(nb * bs, H), flat, axis=0)
-        vs = jnp.take(cache.v_scale.reshape(nb * bs, H), flat, axis=0)
-        k = k.astype(jnp.float32) * ks[..., None]
-        v = v.astype(jnp.float32) * vs[..., None]
-    return k, v
+    _, H, bs, D = cache.k.shape
+    B, C = block_tables.shape
+
+    def view(pool, scale):
+        x = jnp.take(pool, block_tables.reshape(-1), axis=0)  # (B*C, H, bs, D)
+        if scale is not None:
+            s = jnp.take(scale, block_tables.reshape(-1), axis=0)
+            x = x.astype(jnp.float32) * s[..., None]
+        return x.reshape(B, C, H, bs, D).transpose(0, 1, 3, 2, 4).reshape(
+            B, C * bs, H, D)
+
+    return view(cache.k, cache.k_scale), view(cache.v, cache.v_scale)
 
 
 def pool_bytes(cache: PagedKVCache) -> int:
@@ -217,8 +222,8 @@ def swap_out_blocks(caches, ids) -> List[dict]:
 
     The engine stacks per-layer pools with a leading group axis, so the
     block axis is located from the right: ``k``/``v`` are
-    (..., num_blocks, block_size, H_kv, D) and scales (when int8-resident)
-    are (..., num_blocks, block_size, H_kv).  Payload arrays keep the pool
+    (..., num_blocks, H_kv, block_size, D) and scales (when int8-resident)
+    are (..., num_blocks, H_kv, block_size).  Payload arrays keep the pool
     dtype (int8 codes swap as int8 — half the host traffic), and restoring
     into a *different* set of block ids later is fine: block contents are
     position-independent, only the table rows carry ordering.

@@ -56,9 +56,10 @@ class Autotuner:
 
     mode="analytic"   rank by the simulator-derived cycle model (fast, exact
                       ordering of the model; works on any host).
-    mode="wallclock"  time each candidate kernel on the local device; falls
-                      back to analytic when the backend cannot run here
-                      (e.g. a pallas kernel on a CPU-only host).
+    mode="wallclock"  time each candidate kernel on the local device; ranks
+                      analytically (and records source="analytic") when the
+                      backend cannot run here (a pallas kernel on a CPU-only
+                      host); raises when every candidate fails where it can.
     """
 
     def __init__(
@@ -177,17 +178,19 @@ class Autotuner:
         interpret = backend == "interpret"
         kb = _KERNEL_BACKEND.get(backend, backend)
         best, best_t = None, float("inf")
+        failed = []
         for spec in cands:
             try:
                 args = self._bench_args(kb, shape, dtype, spec)
                 t = self._time_spec(
                     make_kernel(kb, spec, interpret=interpret), args)
-            except Exception:
-                continue  # candidate fails to compile/run here: not a winner
+            except Exception as e:  # refused or failed here: not a winner
+                failed.append((f"tile=({spec.tm},{spec.tk},{spec.tn})", e))
+                continue
             if t < best_t:
                 best, best_t = spec, t
-        if best is None:  # nothing ran (e.g. driver issue): analytic fallback
-            return self._rank_analytic(cands, shape, dtype)
+        if best is None:
+            raise all_candidates_failed(f"{kb} {shape}", failed)
         return best, best_t, "wallclock"
 
     def _bench_args(self, kb: str, shape: GemmShape, dtype, spec: TpuGemmSpec):
@@ -222,6 +225,17 @@ class Autotuner:
             out = kernel(*args)
         out.block_until_ready()
         return (time.perf_counter() - t0) / self.wallclock_iters
+
+
+def all_candidates_failed(what: str, failed) -> RuntimeError:
+    """The error a wallclock search raises when no candidate ran: it names
+    every candidate and why it failed, instead of handing back an untested
+    analytic winner."""
+    detail = "; ".join(f"{name}: {type(e).__name__}: {str(e)[:200]}"
+                       for name, e in failed)
+    return RuntimeError(
+        f"wallclock tuning of {what}: all {len(failed)} candidates failed "
+        f"({detail})")
 
 
 # ---------------------------------------------------------------------------

@@ -29,7 +29,12 @@ import time
 from typing import List, NamedTuple, Optional
 
 from repro.kernels.flash_decode import FlashDecodeSpec
-from repro.tuning.autotuner import Autotuner, TuneResult, get_tuner
+from repro.tuning.autotuner import (
+    Autotuner,
+    TuneResult,
+    all_candidates_failed,
+    get_tuner,
+)
 from repro.tuning.cache import CacheEntry
 
 # Coarse cost-model constants (dimensionless "block-visit" units).
@@ -171,23 +176,27 @@ def tune_decode(
             return TuneResult(spec=hit.spec, score=hit.score,
                               source=hit.source, from_cache=True)
     cands = enumerate_decode_specs(shape)
-    best, best_score, source = None, float("inf"), "analytic"
+    best, best_score = None, float("inf")
     if mode == "wallclock":
+        failed = []
         for spec in cands:
             try:
                 s = _time_candidate(spec, shape, dtype)
-            except Exception:
-                continue                  # candidate cannot run here
+            except Exception as e:        # refused or failed here
+                failed.append((f"splits={spec.num_splits} "
+                               f"cols={spec.cols_per_iter}", e))
+                continue
             if s < best_score:
                 best, best_score = spec, s
-        if best is not None:
-            source = "wallclock"
-    if best is None:                      # analytic mode, or nothing ran
+        if best is None:
+            raise all_candidates_failed(
+                decode_cache_key(shape, dtype, mode), failed)
+    else:
         for spec in cands:
             s = predict_decode_cost(spec, shape)
             if s < best_score:            # strict <: ties break to the
                 best, best_score = spec, s  # smallest knobs (sorted cands)
-        source = "analytic"
+    source = mode
     t.cache.put(key, CacheEntry(spec=best, score=best_score, source=source),
                 persist=t.persist)
     return TuneResult(spec=best, score=best_score, source=source,

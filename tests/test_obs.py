@@ -422,9 +422,12 @@ def test_engine_as_dict_is_json_serializable(traced_run):
 # ---------------------------------------------------------------------------
 
 
+V5E = "TPU v5 lite"
+
+
 def test_mfu_meter_accounting_and_merge():
     cfg = configs.get_smoke(ARCH)
-    a = MfuMeter(cfg)
+    a = MfuMeter(cfg, device_kind=V5E)
     assert a.utilization("decode") == 0.0 and a.mfu("decode") == 0.0
     a.note("decode", tokens=2, rows=4, time_s=1e-3)
     a.note("decode", tokens=2, rows=4, time_s=1e-3)
@@ -439,7 +442,7 @@ def test_mfu_meter_accounting_and_merge():
     # bound is memoized and monotone in rows
     assert a.step_bound_s(4) == a.step_bound_s(4)
     assert a.step_bound_s(64) >= a.step_bound_s(4)
-    b = MfuMeter(cfg)
+    b = MfuMeter(cfg, device_kind=V5E)
     b.note("decode", tokens=1, rows=4, time_s=5e-4)
     merged = MfuMeter.merged([a, b])
     assert merged.phases["decode"].steps == 3
@@ -451,15 +454,38 @@ def test_mfu_meter_accounting_and_merge():
     json.dumps(a.as_dict())
 
 
+def test_mfu_meter_unknown_device_not_measured():
+    """A device without published peaks reports MFU and utilization as not
+    measured (None), never against another chip's peaks."""
+    m = MfuMeter(configs.get_smoke(ARCH), device_kind="cpu")
+    assert not m.measurable and m.peak_flops is None
+    m.note("decode", tokens=2, rows=4, time_s=1e-3)
+    assert m.utilization("decode") is None and m.mfu("decode") is None
+    assert m.phases["decode"].tokens == 2 and m.phases["decode"].bound_s == 0
+    assert "mfu[decode]=n/m" in m.summary()
+    d = json.loads(json.dumps(m.as_dict()))
+    assert d["phases"]["decode"]["mfu"] is None and d["device_kind"] == "cpu"
+    merged = MfuMeter.merged([m])
+    assert merged.device_kind == "cpu" and merged.mfu("decode") is None
+
+
 def test_engine_mfu_phases_populated(traced_run):
+    """The engine's meter follows the device it runs on: phases are
+    accounted, and on a device without published peaks (this CPU host) the
+    gauges read not measured."""
+    import jax
+
     mfu = traced_run.mfu
+    assert mfu.device_kind == jax.devices()[0].device_kind
     active = set(mfu.active_phases())
     assert {"prefill", "decode"} <= active
     for p in active:
         st = mfu.phases[p]
-        assert st.time_s > 0 and st.steps > 0 and st.bound_s > 0
-        assert 0 < mfu.utilization(p)       # CPU host: tiny but nonzero
-        assert 0 < mfu.mfu(p) < 1
+        assert st.time_s > 0 and st.steps > 0 and st.flops > 0
+        if mfu.measurable:
+            assert st.bound_s > 0 and 0 < mfu.mfu(p) < 1
+        else:
+            assert mfu.utilization(p) is None and mfu.mfu(p) is None
     assert "util[decode]=" in traced_run.metrics.summary()
 
 

@@ -11,9 +11,10 @@ SCRIPT = textwrap.dedent("""
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
     import json
     import jax, jax.numpy as jnp
+    from repro.launch.mesh import make_mesh
     from repro.parallel.pipeline import gpipe, split_stages
 
-    mesh = jax.make_mesh((4,), ("pod",))
+    mesh = make_mesh((4,), ("pod",))
     G, d = 8, 16                     # 8 layer groups -> 4 stages of 2
     key = jax.random.PRNGKey(0)
     Ws = jax.random.normal(key, (G, d, d)) * (d ** -0.5)

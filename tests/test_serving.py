@@ -12,6 +12,7 @@ from repro.models import model as M
 from repro.serving import kv_cache as kvc
 from repro.serving.engine import Engine
 from repro.serving.prefill import chunk_buckets, plan_chunks
+from repro.serving.request import RequestSpec
 from repro.serving.scheduler import Scheduler
 
 # One arch per serving family (all float32 smoke configs -> tight tolerances).
@@ -150,6 +151,28 @@ def test_engine_matches_isolated_run():
         sr = solo.submit(p, max_new=3)
         want = solo.run()[sr.rid]
         np.testing.assert_array_equal(got[r.rid], want)
+
+
+def test_prompt_logits_match_forward_and_leave_engine_idle():
+    """Engine.prompt_logits runs the compiled chunk steps on a scratch
+    state: the next-token logits equal the dense forward's, nothing
+    compiles after warmup, and the live engine still serves afterwards."""
+    cfg = configs.get_smoke("gemma3-1b")
+    params = M.init_model(jax.random.PRNGKey(0), cfg)
+    eng = Engine(cfg, params=params, slots=2, max_seq=32, block_size=4,
+                 max_chunk=8)
+    eng.warmup()
+    prompt = np.random.default_rng(5).integers(0, cfg.vocab, size=21)
+    want = M.forward(params, cfg, {"tokens": jnp.asarray(prompt[None])},
+                     last_only=True)[0, -1]
+    np.testing.assert_allclose(eng.prompt_logits(prompt), np.asarray(want),
+                               rtol=1e-4, atol=1e-4)
+    assert eng.metrics.cold_compiles == 0
+    assert not np.asarray(eng.state.lengths).any()
+    r = eng.submit(RequestSpec(prompt=prompt, max_new=2))
+    assert len(eng.run()[r.rid]) == 2
+    with pytest.raises(ValueError):
+        eng.prompt_logits(np.zeros((33,), np.int32))
 
 
 def test_block_table_reuse_after_completion():

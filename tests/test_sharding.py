@@ -36,13 +36,14 @@ MINI_DRYRUN = textwrap.dedent("""
     import json, jax
     from repro import configs
     from repro.launch import steps as steps_lib, roofline as rl, hlo_cost
+    from repro.launch.mesh import make_mesh
     from repro.parallel import sharding as shard_lib
     from repro.parallel.logical import use_rules
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     arch = os.environ["ARCH"]
     cfg = configs.get_smoke(arch)
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = make_mesh((4, 2), ("data", "model"))
     plan = shard_lib.make_plan(mesh, cfg.param_count(),
                                force_mode=os.environ.get("MODE", "dp"))
     p_struct = steps_lib.params_struct(cfg)

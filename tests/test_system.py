@@ -1,10 +1,39 @@
 """End-to-end system tests: training convergence, serving, int8 mode,
 HLO cost analyzer, and data/training determinism."""
 
+import os
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+
+
+@pytest.fixture(autouse=True)
+def _own_compile_cache(tmp_path, monkeypatch):
+    """The launchers' mains turn on JAX's persistent compile cache: keep it
+    in this test's tmp dir, and restore the process setting after."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "xla"))
+    was = jax.config.jax_compilation_cache_dir
+    yield
+    jax.config.update("jax_compilation_cache_dir", was)
+    compilation_cache.reset_cache()
+
+
+def test_compile_cache_dir_honours_env_else_fixed(tmp_path, monkeypatch):
+    """$JAX_COMPILATION_CACHE_DIR wins; without it the cache goes to the
+    same fixed .jax_cache/ at the repo root on every call."""
+    from repro.launch.compile_cache import enable_compile_cache
+
+    assert enable_compile_cache() == str(tmp_path / "xla")
+    assert jax.config.jax_compilation_cache_dir == str(tmp_path / "xla")
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    first, second = enable_compile_cache(), enable_compile_cache()
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    assert first == second == os.path.join(root, ".jax_cache")
+    assert jax.config.jax_compilation_cache_dir == first
 
 
 def test_training_loss_decreases(tmp_path):
@@ -28,6 +57,23 @@ def test_serving_generates(tmp_path):
                       "--prompt-len", "6", "--gen-len", "4"])
     assert gen.shape == (2, 4)
     assert gen.dtype.kind == "i"
+
+
+def test_serve_cluster_exits_nonzero_on_handle_error(monkeypatch):
+    """A cluster run whose requests resolve with errors must not exit 0."""
+    from repro.launch import serve
+    from repro.serving.engine import Engine
+
+    def broken_submit(self, *args, **kwargs):
+        raise RuntimeError("engine refused the request")
+
+    monkeypatch.setattr(Engine, "submit", broken_submit)
+    with pytest.raises(SystemExit) as exit_info:
+        serve.main(["--arch", "gemma3-1b", "--replicas", "2",
+                    "--requests", "2", "--slots", "1",
+                    "--prompt-len", "4", "--gen-len", "2"])
+    assert exit_info.value.code not in (0, None)
+    assert "2 of 2 requests failed" in str(exit_info.value.code)
 
 
 def test_int8_linear_close_to_f32():

@@ -263,12 +263,42 @@ def test_wallclock_mode_interpret(tmp_path):
     tuner = _tuner(tmp_path, mode="wallclock", max_candidates=2,
                    wallclock_iters=1, persist=False)
     res = tuner.tune(GemmShape(64, 128, 128), "float32", backend="interpret")
-    assert res.source in ("wallclock", "analytic")  # analytic = no cand ran
+    assert res.source == "wallclock"
     a = jnp.ones((64, 128), jnp.float32)
     b = jnp.ones((128, 128), jnp.float32)
     out = ops.gemm(a, b, spec=res.spec, backend="interpret")
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref.gemm_ref(a, b)),
                                rtol=1e-6)
+
+
+@pytest.mark.parametrize("kind", ["gemm", "decode"])
+def test_wallclock_raises_when_every_candidate_fails(kind, tmp_path,
+                                                     monkeypatch):
+    """A wallclock search in which no candidate runs raises and names the
+    candidates: it never hands back an untested analytic winner."""
+    from repro.tuning import decode as tdecode
+
+    def refuse(*args, **kwargs):
+        raise ValueError("refused by the compiler")
+
+    tuner = _tuner(tmp_path, mode="wallclock", max_candidates=2,
+                   wallclock_iters=1, persist=False)
+    if kind == "gemm":
+        monkeypatch.setattr(tuning.Autotuner, "_time_spec", refuse)
+        call = lambda: tuner.tune(GemmShape(64, 128, 128), "float32",
+                                  backend="interpret")
+        named = "tile=("
+    else:
+        monkeypatch.setattr(tdecode, "_time_candidate", refuse)
+        shape = tuning.DecodeShape(slots=2, kv_heads=1, groups=2, head_dim=16,
+                                   sq=1, block_size=4, max_blocks=4)
+        call = lambda: tuning.tune_decode(shape, "float32", mode="wallclock",
+                                          tuner=tuner)
+        named = "splits=1 cols=1"
+    with pytest.raises(RuntimeError, match="candidates failed") as err:
+        call()
+    assert named in str(err.value) and "refused by the compiler" in str(err.value)
+    assert len(tuner.cache) == 0          # nothing cached for the failed search
 
 
 def test_ops_dispatch_through_enabled_tuner(tmp_path):
