@@ -25,7 +25,7 @@ __all__ = ["HBM_BW", "ICI_BW", "PEAK_FLOPS_BF16", "make_mesh",
            "make_production_mesh", "make_local_mesh"]
 
 
-def make_mesh(shape: Sequence[int], axes: Sequence[str], *, devices=None):
+def make_mesh(shape: Sequence[int], axes: Sequence[str]):
     """``jax.make_mesh`` with Auto axes.
 
     Every mesh in this repo goes through here.  The model code annotates
@@ -34,8 +34,7 @@ def make_mesh(shape: Sequence[int], axes: Sequence[str], *, devices=None):
     ``jax.make_mesh`` defaults to Explicit axes, under which those
     annotations and ``jnp.take`` on a sharded table are rejected."""
     return jax.make_mesh(tuple(shape), tuple(axes),
-                         axis_types=(AxisType.Auto,) * len(axes),
-                         devices=devices)
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
