@@ -22,8 +22,7 @@ What never gates, but is still printed:
 
   * rows marked **informational** — ``value == "informational"`` (how
     cluster_bench reports an unmeetable-bar row) or a ``derived`` field
-    containing the word "informational" (how obs_bench marks its
-    noise-dominated A/B overhead rows);
+    containing the word "informational";
   * percentage-delta and NLL-delta rows (pure noise amplifiers: a µs-level
     wobble swings them across zero).
 

@@ -6,8 +6,8 @@ carries the paper's reference value.  Sections: fig5, table2, fig7, table3,
 kernel (incl. autotuner deltas), decode_attn (paged decode attention vs the
 gather baseline, incl. int8 KV), serving (incl. float-vs-w8a8), spec
 (speculative decoding), sched (interactive p95 under batch load, FIFO vs
-KV-swap preemption), cluster, obs (tracing overhead; also writes
-BENCH_trace.json), plus roofline rows when dry-run results exist.  Expected runtime: ~2 min total on CPU; per-script details in each
+KV-swap preemption), cluster, plus roofline rows when dry-run results
+exist.  Expected runtime: ~2 min total on CPU; per-script details in each
 module's docstring and EXPERIMENTS.md.
 
 ``--fast`` (= `make bench-smoke`, wired into CI) sets REPRO_BENCH_FAST=1
@@ -42,7 +42,7 @@ def main(argv=None) -> None:
                          "(exports REPRO_BENCH_FAST=1)")
     ap.add_argument("--only", default=None,
                     help="run a single section (fig5|table2|fig7|table3|"
-                         "kernel|decode_attn|serving|spec|sched|cluster|obs)")
+                         "kernel|decode_attn|serving|spec|sched|cluster)")
     ap.add_argument("--json", default=None, metavar="PATH",
                     help="also write a machine-readable report (default "
                          "BENCH_smoke.json with --fast; see "
@@ -65,7 +65,6 @@ def main(argv=None) -> None:
         fig5_ablation,
         fig7_gemmini,
         kernel_bench,
-        obs_bench,
         sched_bench,
         serving_bench,
         spec_bench,
@@ -84,7 +83,6 @@ def main(argv=None) -> None:
         ("spec", spec_bench),
         ("sched", sched_bench),
         ("cluster", cluster_bench),
-        ("obs", obs_bench),
     ]
     if args.only:
         modules = [(n, m) for n, m in modules if n == args.only]

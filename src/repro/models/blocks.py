@@ -31,6 +31,11 @@ def _norm(x, p, cfg):
     return layers.rms_norm(x, p, cfg.norm_eps)
 
 
+# The named scope each mixer kind runs under (apply_block).
+MIXER_SCOPE = {"attn": "attn", "attn_local": "attn", "mamba": "mamba",
+               "mlstm": "mlstm", "slstm": "slstm"}
+
+
 def _layer_uses_moe(cfg, layer_idx: int) -> bool:
     return cfg.moe is not None and (layer_idx + 1) % cfg.moe_every == 0
 
@@ -88,48 +93,57 @@ def apply_block(
     extra (S,) axis on every state leaf) instead of the final state —
     speculative verification selects the state at the accepted position.
     Attention kinds ignore it (the paged KV pool is positional already).
+
+    Each sub-layer, norms and residual add included, runs under a
+    ``jax.named_scope`` of its layer kind (MIXER_SCOPE, then "ffn" or
+    "moe"): the op_name of every operation in the compiled step says which
+    kind of layer it belongs to, so a profile splits the step by kind.
     """
-    h = _norm(x, p["norm1"], cfg)
-    if kind in ("attn", "attn_local"):
-        window = cfg.local_window if kind == "attn_local" else None
-        h, new_cache = attn_lib.attention(
-            h, p["mixer"], cfg, positions=positions, causal=causal,
-            window=window, prefix_len=prefix_len, cache=cache,
-            cache_index=cache_index, block_tables=block_tables,
-        )
-    elif kind == "mamba":
-        h, new_cache = ssm.mamba_block(h, p["mixer"], cfg, state=cache,
-                                       collect_states=collect_states)
-    elif kind == "mlstm":
-        h, new_cache = ssm.mlstm_block(h, p["mixer"], cfg, state=cache,
-                                       collect_states=collect_states)
-    elif kind == "slstm":
-        h, new_cache = ssm.slstm_block(h, p["mixer"], cfg, state=cache,
-                                       collect_states=collect_states)
-    else:
+    if kind not in MIXER_SCOPE:
         raise ValueError(kind)
-    if cfg.post_block_norm:
-        h = _norm(h, p["post_norm1"], cfg)
-    x = x + h
+    with jax.named_scope(MIXER_SCOPE[kind]):
+        h = _norm(x, p["norm1"], cfg)
+        if kind in ("attn", "attn_local"):
+            window = cfg.local_window if kind == "attn_local" else None
+            h, new_cache = attn_lib.attention(
+                h, p["mixer"], cfg, positions=positions, causal=causal,
+                window=window, prefix_len=prefix_len, cache=cache,
+                cache_index=cache_index, block_tables=block_tables,
+            )
+        elif kind == "mamba":
+            h, new_cache = ssm.mamba_block(h, p["mixer"], cfg, state=cache,
+                                           collect_states=collect_states)
+        elif kind == "mlstm":
+            h, new_cache = ssm.mlstm_block(h, p["mixer"], cfg, state=cache,
+                                           collect_states=collect_states)
+        else:
+            h, new_cache = ssm.slstm_block(h, p["mixer"], cfg, state=cache,
+                                           collect_states=collect_states)
+        if cfg.post_block_norm:
+            h = _norm(h, p["post_norm1"], cfg)
+        x = x + h
 
     if "cross" in p:
-        h = _norm(x, p["norm_cross"], cfg)
-        h, _ = attn_lib.attention(
-            h, p["cross"], cfg, positions=positions, causal=False,
-            kv_src=encoder_out if cross_cache is None else h,  # decode: cache
-            cache=cross_cache, cache_index=None,
-        )
-        x = x + h
+        with jax.named_scope("attn"):
+            h = _norm(x, p["norm_cross"], cfg)
+            h, _ = attn_lib.attention(
+                h, p["cross"], cfg, positions=positions, causal=False,
+                kv_src=encoder_out if cross_cache is None else h,  # decode
+                cache=cross_cache, cache_index=None,
+            )
+            x = x + h
 
     if "ffn" in p:
-        h = _norm(x, p["norm2"], cfg)
-        if "router" in p["ffn"]:
-            h = moe_lib.moe_block(h, p["ffn"], cfg)
-        else:
-            h = layers.mlp(h, p["ffn"], cfg.mlp_variant)
-        if cfg.post_block_norm:
-            h = _norm(h, p["post_norm2"], cfg)
-        x = x + h
+        moe = "router" in p["ffn"]
+        with jax.named_scope("moe" if moe else "ffn"):
+            h = _norm(x, p["norm2"], cfg)
+            if moe:
+                h = moe_lib.moe_block(h, p["ffn"], cfg)
+            else:
+                h = layers.mlp(h, p["ffn"], cfg.mlp_variant)
+            if cfg.post_block_norm:
+                h = _norm(h, p["post_norm2"], cfg)
+            x = x + h
     return x, new_cache
 
 

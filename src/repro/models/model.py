@@ -123,13 +123,18 @@ def forward(
         x, params["blocks"], cfg, positions=positions,
         prefix_len=prefix_len, encoder_out=encoder_out,
     )
-    x = blocks._norm(x, params["final_norm"], cfg)
     if cfg.family == "vlm":
         x = x[:, prefix_len:]
     if last_only:
         x = x[:, -1:]
-    logits = _unembed(x, params, cfg)
-    return logits
+    return _head(x, params, cfg)
+
+
+def _head(x, params, cfg):
+    """The final norm and the unembedding, under one named scope."""
+    with jax.named_scope("unembed"):
+        return _unembed(blocks._norm(x, params["final_norm"], cfg), params,
+                        cfg)
 
 
 def _unembed(x, params, cfg):
@@ -275,8 +280,7 @@ def decode_step(
             body, x, (params["blocks"], state.caches, state.cross_caches)
         )
 
-    x = blocks._norm(x, params["final_norm"], cfg)
-    logits = _unembed(x, params, cfg)
+    logits = _head(x, params, cfg)
     new_state = DecodeState(
         caches=new_caches, cross_caches=state.cross_caches, index=state.index + 1
     )
@@ -343,10 +347,11 @@ def _trunk_step(params, cfg, x, positions, caches, cache_index, block_tables,
 
 
 def _embed_tokens(params, cfg, tokens):
-    x = layers.embed(tokens, params["embed"])
-    if cfg.tie_embeddings:
-        x = x * jnp.asarray(cfg.d_model ** 0.5, x.dtype)
-    return shard(x, "batch", "seq", "embed")
+    with jax.named_scope("embed"):
+        x = layers.embed(tokens, params["embed"])
+        if cfg.tie_embeddings:
+            x = x * jnp.asarray(cfg.d_model ** 0.5, x.dtype)
+        return shard(x, "batch", "seq", "embed")
 
 
 def paged_decode_step(
@@ -373,8 +378,7 @@ def paged_decode_step(
         new_lengths = state.lengths + active.astype(jnp.int32)
     else:
         new_lengths = state.lengths + 1
-    x = blocks._norm(x, params["final_norm"], cfg)
-    logits = _unembed(x, params, cfg)
+    logits = _head(x, params, cfg)
     return logits, PagedDecodeState(
         caches=new_caches, block_tables=state.block_tables,
         lengths=new_lengths,
@@ -445,8 +449,7 @@ def paged_verify_step(
         params, cfg, x, positions, state.caches, state.lengths,
         state.block_tables, collect_states=True,
     )
-    x = blocks._norm(x, params["final_norm"], cfg)
-    logits = _unembed(x, params, cfg)                       # (B, S, vocab)
+    logits = _head(x, params, cfg)                          # (B, S, vocab)
     greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)  # (B, S)
 
     # Greedy acceptance: drafted token i is kept iff it equals the model's
@@ -615,8 +618,7 @@ def paged_verify_sample_step(
         params, cfg, x, positions, state.caches, state.lengths,
         state.block_tables, collect_states=True,
     )
-    x = blocks._norm(x, params["final_norm"], cfg)
-    logits = _unembed(x, params, cfg)                       # (B, S, vocab)
+    logits = _head(x, params, cfg)                          # (B, S, vocab)
     V = logits.shape[-1]
 
     bcast = lambda a: jnp.broadcast_to(jnp.asarray(a)[:, None], (B, S))
@@ -718,8 +720,7 @@ def prefill_chunk(
     positions = start[:, None] + jnp.arange(C, dtype=jnp.int32)[None, :]
     x, part_caches = _trunk_step(
         params, cfg, x, positions, caches, start, tables)
-    x = blocks._norm(x[:, -1:], params["final_norm"], cfg)
-    logits = _unembed(x, params, cfg)
+    logits = _head(x[:, -1:], params, cfg)
     new_lengths = jax.lax.dynamic_update_slice(
         state.lengths, start + jnp.int32(C), (slot,))
     return logits, PagedDecodeState(

@@ -5,12 +5,11 @@ the smoke configs; a tracer that allocates, locks, or formats per event
 would show up in the very utilization numbers it exists to explain.  The
 design rules, in order:
 
-  * **Pre-allocated ring writes.**  One event = three scalar stores into
-    pre-allocated numpy arrays (kind, interned-name code, monotonic
-    timestamp) plus an index increment — measured ~0.3 µs/event on the CI
-    host, against decode ticks of ~0.5-1 ms (benchmarks/obs_bench.py keeps
-    the measured overhead on the record; tests/test_obs.py holds the
-    events-per-tick x cost product under 2% of a decode tick).
+  * **Pre-allocated ring writes.**  One event = three stores into
+    pre-allocated lists (kind, interned-name code, monotonic timestamp)
+    plus an index increment — ~0.2 µs/event on the CI host, against
+    decode ticks of ~0.5-1 ms (tests/test_obs.py holds the events-per-tick
+    x cost product under 2% of a decode tick).
   * **No allocation or locks per event.**  Names are interned to small int
     codes once (engine init / first use); the hot path never touches a
     string or a dict.  The only lock guards interning, never recording.
@@ -26,8 +25,9 @@ design rules, in order:
 Event kinds map 1:1 onto Chrome-trace phases (obs/export.py):
 
   BEGIN/END         -> "B"/"E"   nested duration spans on this tracer's tid
-                                 (per-tick phases: sched, prefill, decode,
-                                 verify, draft, reset)
+                                 (per-tick phases: engine.admit, .schedule,
+                                 .stage, .dispatch, .readback, .pick,
+                                 .commit, .verify, .draft, .reset)
   COUNTER           -> "C"       sampled gauges (kv_blocks_in_use,
                                  queue_depth, ...)
   ASYNC_BEGIN/END   -> "b"/"e"   id-keyed spans that outlive any one tick
@@ -48,6 +48,15 @@ Event kinds map 1:1 onto Chrome-trace phases (obs/export.py):
 
 Timestamps are `time.perf_counter_ns()` — monotonic, comparable across
 tracers in one process (export aligns every tracer to a common origin).
+
+**Spans on the profiler's clock.**  While a JAX profiler session records
+(`jax.profiler.start_trace`), `begin`/`end` also write each span into the
+profiler's trace as a TraceMe (`jax.profiler.TraceAnnotation`), next to the
+device's operations, with a `meta` dict of counters as its metadata.  This
+holds for NullTracer too, so an engine with its ring off still shows its
+spans in a profile.  Whether a session records is looked up once per
+`poll_profiler` (the engine calls it at the top of each tick), not per
+event: with the profiler off a span costs one attribute test more.
 """
 
 from __future__ import annotations
@@ -55,9 +64,9 @@ from __future__ import annotations
 import contextlib
 import threading
 import time
-from typing import Dict, List
+from typing import Dict, List, Optional
 
-import numpy as np
+from jax.profiler import TraceAnnotation
 
 BEGIN = 0
 END = 1
@@ -72,40 +81,19 @@ INSTANT = 8
 _KIND_NAMES = ("B", "E", "C", "b", "e", "s", "t", "f", "i")
 
 
-class Tracer:
-    """Single-writer ring-buffer event log (see module docstring).
+class _Names:
+    """Name interning and the profiler forwarding that Tracer and NullTracer
+    share.  One owner thread per instance: the stack of open annotations is
+    not shared (see Engine, which gives each engine its own tracer)."""
 
-    `intern()` a name once, then record with the returned code:
+    __slots__ = ("_names", "_codes", "_lock", "_profiling", "_open")
 
-        tr = Tracer(name="engine")
-        DECODE = tr.intern("decode")
-        tr.begin(DECODE); ...; tr.end(DECODE)
-    """
-
-    __slots__ = ("capacity", "name", "pid", "enabled", "_kind", "_code",
-                 "_aid", "_value", "_ts", "_n", "_names", "_codes", "_lock",
-                 "_clock")
-
-    def __init__(self, capacity: int = 1 << 15, *, name: str = "engine",
-                 pid: int = 0, clock=time.perf_counter_ns):
-        if capacity < 1:
-            raise ValueError(f"capacity must be >= 1, got {capacity}")
-        self.capacity = capacity
-        self.name = name
-        self.pid = pid
-        self.enabled = True
-        self._kind = np.zeros(capacity, np.uint8)
-        self._code = np.zeros(capacity, np.uint32)
-        self._aid = np.zeros(capacity, np.int64)      # async id (request id)
-        self._value = np.zeros(capacity, np.float64)  # counter value
-        self._ts = np.zeros(capacity, np.int64)       # perf_counter_ns
-        self._n = 0                                   # total events recorded
+    def __init__(self):
         self._names: List[str] = []
         self._codes: Dict[str, int] = {}
         self._lock = threading.Lock()                 # interning only
-        self._clock = clock
-
-    # -- name interning (off the hot path) -----------------------------------
+        self._profiling = False
+        self._open: list = []                         # (code, annotation)
 
     def intern(self, name: str) -> int:
         """Name -> small int code; idempotent, safe from any thread."""
@@ -120,16 +108,81 @@ class Tracer:
                 self._codes[name] = code
             return code
 
+    def poll_profiler(self) -> bool:
+        """Look once whether a profiler session is recording; until the
+        next call, spans are written into it as well.  Annotations that an
+        exception left open are closed first (call it between ticks)."""
+        while self._open:
+            self._open.pop()[1].__exit__(None, None, None)
+        self._profiling = TraceAnnotation.is_enabled()
+        return self._profiling
+
+    def _annotate(self, code: int, meta) -> None:
+        ann = TraceAnnotation(self._names[code], **(meta or {}))
+        ann.__enter__()
+        self._open.append((code, ann))
+
+    def _close(self, code: int, meta) -> None:
+        """Close the innermost annotation if it is `code`'s (a span opened
+        before the profiler was polled on has none)."""
+        if self._open[-1][0] != code:
+            return
+        ann = self._open.pop()[1]
+        if meta:
+            ann.set_metadata(**meta)
+        ann.__exit__(None, None, None)
+
+
+class Tracer(_Names):
+    """Single-writer ring-buffer event log (see module docstring).
+
+    `intern()` a name once, then record with the returned code:
+
+        tr = Tracer(name="engine")
+        DECODE = tr.intern("decode")
+        tr.begin(DECODE); ...; tr.end(DECODE)
+
+    A `meta` dict of counters given to `begin` or `end` goes to the
+    profiler's trace only, as the span's metadata; the ring keeps no
+    payload for spans.
+    """
+
+    __slots__ = ("capacity", "name", "pid", "enabled", "_kind", "_code",
+                 "_aid", "_value", "_ts", "_n", "_clock")
+
+    def __init__(self, capacity: int = 1 << 15, *, name: str = "engine",
+                 pid: int = 0, clock=time.perf_counter_ns):
+        if capacity < 1:
+            raise ValueError(f"capacity must be >= 1, got {capacity}")
+        super().__init__()
+        self.capacity = capacity
+        self.name = name
+        self.pid = pid
+        self.enabled = True
+        # Plain lists: a store into one costs about half a numpy scalar
+        # store, and every slot is allocated here, once.
+        self._kind = [0] * capacity
+        self._code = [0] * capacity
+        self._aid = [0] * capacity                    # async id (request id)
+        self._value = [0.0] * capacity                # counter value
+        self._ts = [0] * capacity                     # perf_counter_ns
+        self._n = 0                                   # total events recorded
+        self._clock = clock
+
     # -- recording (hot path: 3 scalar stores + 1 increment) -----------------
 
-    def begin(self, code: int) -> None:
+    def begin(self, code: int, meta: Optional[dict] = None) -> None:
+        if self._profiling:
+            self._annotate(code, meta)
         i = self._n % self.capacity
         self._kind[i] = BEGIN
         self._code[i] = code
         self._ts[i] = self._clock()
         self._n += 1
 
-    def end(self, code: int) -> None:
+    def end(self, code: int, meta: Optional[dict] = None) -> None:
+        if self._open:
+            self._close(code, meta)
         i = self._n % self.capacity
         self._kind[i] = END
         self._code[i] = code
@@ -248,24 +301,24 @@ class Tracer:
         self._n = 0
 
 
-class NullTracer:
+class NullTracer(_Names):
     """No-op stand-in with the full Tracer API: tracing-off engines call the
     same code paths, and each call is one cheap no-op method dispatch (a few
-    tens of ns against a ~ms tick)."""
+    tens of ns against a ~ms tick).  Its names are interned for real, so
+    that its spans, too, reach a recording profiler (module docstring)."""
 
     capacity = 0
     name = "null"
     pid = 0
     enabled = False
 
-    def intern(self, name: str) -> int:
-        return 0
+    def begin(self, code: int, meta: Optional[dict] = None) -> None:
+        if self._profiling:
+            self._annotate(code, meta)
 
-    def begin(self, code: int) -> None:
-        pass
-
-    def end(self, code: int) -> None:
-        pass
+    def end(self, code: int, meta: Optional[dict] = None) -> None:
+        if self._open:
+            self._close(code, meta)
 
     def counter(self, code: int, value: float) -> None:
         pass
@@ -290,7 +343,12 @@ class NullTracer:
 
     @contextlib.contextmanager
     def span(self, name: str):
-        yield
+        code = self.intern(name)
+        self.begin(code)
+        try:
+            yield
+        finally:
+            self.end(code)
 
     def __len__(self) -> int:
         return 0

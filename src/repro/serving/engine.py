@@ -44,7 +44,7 @@ import numpy as np
 
 from repro.core.dataflow import GemmShape
 from repro.models import model as M
-from repro.obs import Histogram, MfuMeter, NULL_TRACER, Tracer
+from repro.obs import Histogram, MfuMeter, NullTracer, Tracer
 from repro.obs import percentile as _obs_percentile
 from repro.serving import kv_cache as kvc
 from repro.serving.prefill import chunk_buckets, plan_chunks
@@ -413,7 +413,6 @@ class Engine:
         sampling: bool = False,
         preempt: bool = False,
         trace=False,
-        trace_flow: bool = True,
         request_log: Optional[int] = None,
         seed: int = 0,
         verbose: bool = False,
@@ -527,24 +526,34 @@ class Engine:
         # percentiles, benchmark-friendly); an int bounds the log for
         # long-lived serving and flips percentiles onto the histograms.
         self._request_log = request_log
-        # Span/event tracing (repro.obs.trace): off by default — NULL_TRACER
-        # makes every record call a no-op method dispatch.  Pass True for a
-        # fresh ring, or a Tracer to aggregate several engines into one
-        # export (cluster/replica.py names one per replica).
+        # Span/event tracing (repro.obs.trace): off by default — a
+        # NullTracer makes every record call a no-op method dispatch, yet
+        # still writes the spans into a recording JAX profiler.  It is this
+        # engine's own, since it holds the open profiler annotations of one
+        # thread.  Pass True for a fresh ring, or a Tracer to aggregate
+        # several engines into one export (cluster/replica.py names one per
+        # replica).
         if isinstance(trace, Tracer):
             self.tracer = trace
         elif trace:
             self.tracer = Tracer(name=f"engine[{cfg.name}]")
         else:
-            self.tracer = NULL_TRACER
+            self.tracer = NullTracer()
         tc = self.tracer.intern
-        self._ev_tick = tc("tick")
-        self._ev_sched = tc("sched")
-        self._ev_prefill = tc("prefill")
-        self._ev_decode = tc("decode")
-        self._ev_verify = tc("verify")
-        self._ev_draft = tc("draft")
-        self._ev_reset = tc("reset")
+        # Each tick's spans, in order, nested in engine.tick: host work
+        # before the step (admit, schedule, stage the inputs, dispatch),
+        # then after it (read back, pick the tokens, commit them).
+        self._ev_tick = tc("engine.tick")
+        self._ev_admit = tc("engine.admit")
+        self._ev_schedule = tc("engine.schedule")
+        self._ev_stage = tc("engine.stage")
+        self._ev_dispatch = tc("engine.dispatch")
+        self._ev_readback = tc("engine.readback")
+        self._ev_pick = tc("engine.pick")
+        self._ev_commit = tc("engine.commit")
+        self._ev_verify = tc("engine.verify")
+        self._ev_draft = tc("engine.draft")
+        self._ev_reset = tc("engine.reset")
         self._ev_kv_in_use = tc("kv_blocks_in_use")
         self._ev_kv_reserved = tc("kv_blocks_reserved")
         self._ev_queue = tc("queue_depth")
@@ -552,10 +561,8 @@ class Engine:
         self._ev_req_prefill = tc("req_prefill")
         self._ev_req_decode = tc("req_decode")
         # Request-flow tracing (cross-lane arrows + annotated instants) on
-        # top of the spans above.  `trace_flow=False` restores the pre-flow
-        # event set — the A/B baseline benchmarks/obs_bench.py measures
-        # flow overhead against.
-        self._flow = bool(trace_flow) and self.tracer.enabled
+        # top of the spans above, whenever the ring is on.
+        self._flow = self.tracer.enabled
         self._ev_submit = tc("submit")
         self._ev_flow = tc("req")            # one flow chain per request
         self._ev_shed = tc("shed")
@@ -659,7 +666,7 @@ class Engine:
         inside the precision context — so the compiled executables are int8
         end to end and serving never quantizes a weight again."""
         buckets = chunk_buckets(self.max_chunk)
-        warm_code = self.tracer.intern("warmup")
+        warm_code = self.tracer.intern("engine.warmup")
         self.tracer.begin(warm_code)
         if self.autotune:
             w8a8 = self.precision != "float"
@@ -904,10 +911,11 @@ class Engine:
         self._prefix_match[req.rid] = (blocks, tokens, n_fresh)
         return True
 
-    def _admit(self) -> None:
-        self._admit_once()
+    def _admit(self) -> int:
+        """Fill free slots from the queue; returns how many were admitted."""
+        admitted = self._admit_once()
         if not self.preempt:
-            return
+            return admitted
         # Preemption sweep: while a queued request outranks running decode
         # work, swap the lowest-class, youngest decoding victim out and
         # retry admission.  Bounded by the slot count (each pass frees at
@@ -917,11 +925,13 @@ class Engine:
             if victim is None:
                 break
             self._swap_out(victim)
-            self._admit_once()
+            admitted += self._admit_once()
+        return admitted
 
-    def _admit_once(self) -> None:
+    def _admit_once(self) -> int:
         to_reset, seeds, restores = [], [], []
-        for slot, req in self.scheduler.admit(self._can_admit):
+        admitted = self.scheduler.admit(self._can_admit)
+        for slot, req in admitted:
             # Request lifecycle track: the queued span ends here, the prefill
             # span opens (closed on the prompt-complete prefill chunk) — or,
             # for a restored victim, the decode span reopens directly.
@@ -983,6 +993,7 @@ class Engine:
             self.state = self.state._replace(lengths=jnp.asarray(lengths))
         if restores:
             self._restore(restores)
+        return len(admitted)
 
     # -- KV-swap preemption --------------------------------------------------
 
@@ -1227,61 +1238,55 @@ class Engine:
         """Admit, then execute one scheduler action.  Returns False when no
         work remains."""
         tr = self.tracer
+        tr.poll_profiler()
         tr.begin(self._ev_tick)
-        tr.begin(self._ev_sched)      # host scheduling: admit + pick action
-        self._admit()
+        tr.begin(self._ev_admit)
+        admitted = self._admit()
+        tr.end(self._ev_admit, {
+            "admitted": admitted,
+            "queued": sum(map(len, self.scheduler.queues.values()))})
+        tr.begin(self._ev_schedule)
         action = self.scheduler.next_action()
-        tr.end(self._ev_sched)
+        tr.end(self._ev_schedule)
         if action is None:
             tr.end(self._ev_tick)
             return self.scheduler.has_work
         self._step += 1
         if action[0] == "prefill":
             _, req, chunk = action
-            self.tables.ensure(req.slot, req.prefilled + chunk, self.alloc)
+            start = req.prefilled
+            last = start + chunk >= req.prompt_len
+            tr.begin(self._ev_stage)
+            self.tables.ensure(req.slot, start + chunk, self.alloc)
             self._sync_tables()
-            tokens = jnp.asarray(
-                req.prompt[None, req.prefilled:req.prefilled + chunk])
-            tr.begin(self._ev_prefill)
+            tokens = jnp.asarray(req.prompt[None, start:start + chunk])
+            tr.end(self._ev_stage)
+            tr.begin(self._ev_dispatch, {"chunk": chunk, "start": start})
             if self._flow:
                 tr.flow_step(self._ev_flow, req.trace_id)
             t_pre = time.monotonic()
             logits, self.state = self._run_compiled(
                 f"chunk{chunk}", self._chunk_fn,
                 self.params, self.state, tokens, self._slot_ids[req.slot])
-            # Sync so the span/MFU time covers the device step, not just its
+            tr.end(self._ev_dispatch)
+            # Sync so the MFU time covers the device step, not just its
             # dispatch.  Chunks are state-dependent (the next chunk consumes
             # this one's KV writes), so total prefill wall time is unchanged.
+            tr.begin(self._ev_readback)
             logits = jax.block_until_ready(logits)
             dt_pre = time.monotonic() - t_pre
-            tr.end(self._ev_prefill)
-            self.scheduler.on_prefill(req, chunk, self._step)
-            self.metrics.prefill_chunks += 1
-            self.metrics.prefill_tokens += chunk
-            self.metrics.prefill_time_s += dt_pre
-            self.mfu.note("prefill", tokens=chunk, rows=chunk, time_s=dt_pre)
-            if req.phase is Phase.DECODE:
-                # Prompt complete: close the request's prefill span, open its
-                # decode span (closed in _finish).
-                tr.async_end(self._ev_req_prefill, req.trace_id)
-                tr.async_begin(self._ev_req_decode, req.trace_id)
-            if req.phase is Phase.DECODE and self.prefix_cache is not None:
-                # Prompt fully in the pool: publish its full blocks for
-                # later requests (the cache takes its own refs; the partial
-                # tail block keeps receiving decode writes and is excluded).
-                n_full = req.prompt_len // self.block_size
-                if n_full:
-                    self.prefix_cache.insert(
-                        req.prompt[: n_full * self.block_size],
-                        self.tables.blocks[req.slot][:n_full])
-            if req.phase is Phase.DECODE:
+            if last and req.sampling.is_greedy:
+                # Index on the numpy copy — slicing a device array
+                # dispatches un-jitted primitives that would compile tiny
+                # kernels at serve time.
+                logits = np.asarray(logits)[0, -1]
+            tr.end(self._ev_readback)
+            if last:
                 # Prompt complete: the chunk's last logits yield the first
-                # generated token (no separate step for it).  Index on the
-                # numpy copy — slicing a device array dispatches un-jitted
-                # primitives that would compile tiny kernels at serve time.
+                # generated token (no separate step for it).
+                tr.begin(self._ev_pick)
                 if req.sampling.is_greedy:
-                    self._record_token(
-                        req, int(np.argmax(np.asarray(logits)[0, -1])))
+                    first = int(np.argmax(logits))
                 else:
                     sp = req.sampling
                     tok = self._run_compiled(
@@ -1289,8 +1294,32 @@ class Engine:
                         np.float32(sp.temperature), np.int32(sp.top_k),
                         np.float32(sp.top_p), np.int32(req.sample_seed),
                         np.int32(len(req.out_tokens)))
+                    first = int(np.asarray(tok)[0])
                     self.metrics.sampled_tokens += 1
-                    self._record_token(req, int(np.asarray(tok)[0]))
+                tr.end(self._ev_pick)
+            tr.begin(self._ev_commit)
+            self.scheduler.on_prefill(req, chunk, self._step)
+            self.metrics.prefill_chunks += 1
+            self.metrics.prefill_tokens += chunk
+            self.metrics.prefill_time_s += dt_pre
+            self.mfu.note("prefill", tokens=chunk, rows=chunk, time_s=dt_pre)
+            if last:
+                # Close the request's prefill span, open its decode span
+                # (closed in _finish).
+                tr.async_end(self._ev_req_prefill, req.trace_id)
+                tr.async_begin(self._ev_req_decode, req.trace_id)
+                if self.prefix_cache is not None:
+                    # Prompt fully in the pool: publish its full blocks for
+                    # later requests (the cache takes its own refs; the
+                    # partial tail block keeps receiving decode writes and
+                    # is excluded).
+                    n_full = req.prompt_len // self.block_size
+                    if n_full:
+                        self.prefix_cache.insert(
+                            req.prompt[: n_full * self.block_size],
+                            self.tables.blocks[req.slot][:n_full])
+                self._record_token(req, first)
+            tr.end(self._ev_commit)
         elif self.spec is not None and self._decode_speculative(action[1]):
             pass                              # spec tick ran (metrics inside)
         else:
@@ -1298,8 +1327,11 @@ class Engine:
             # The step writes at position r.length - 1 (the last recorded
             # token's KV goes in on the step that consumes it), so covering
             # r.length tokens suffices — +1 would draw blocks a step early.
+            tr.begin(self._ev_stage)
+            ctx_tokens = 0
             for r in reqs:
                 self.tables.ensure(r.slot, r.length, self.alloc)
+                ctx_tokens += r.length
             self._sync_tables()
             # numpy args feed the jitted call directly — see the note in
             # _decode_speculative; an explicit jnp.asarray here costs more
@@ -1308,32 +1340,44 @@ class Engine:
             active = np.zeros((self.slots,), bool)
             active[[r.slot for r in reqs]] = True
             samp = self._sampling_args(reqs)
+            tr.end(self._ev_stage)
             t_dec = time.monotonic()
-            tr.begin(self._ev_decode)
+            tr.begin(self._ev_dispatch,
+                     {"rows": len(reqs), "ctx_tokens": ctx_tokens})
             if self._flow:
                 for r in reqs:
                     tr.flow_step(self._ev_flow, r.trace_id)
             if samp is None:
-                logits, self.state = self._run_compiled(
+                out, self.state = self._run_compiled(
                     "decode", self._decode_fn, self.params, self.state,
                     tokens, active)
-                # np.asarray blocks on the result — the span covers the step.
-                next_tok = np.argmax(np.asarray(logits)[:, -1], axis=-1)
             else:
-                sampled, self.state = self._run_compiled(
+                out, self.state = self._run_compiled(
                     "decode_sample", self._sample_fn, self.params, self.state,
                     tokens, active, *samp)
-                next_tok = np.asarray(sampled)
+            tr.end(self._ev_dispatch)
+            tr.begin(self._ev_readback)
+            # Blocks on the step, then copies its logits (or sampled
+            # tokens) to the host.
+            out = np.asarray(out)
+            tr.end(self._ev_readback)
+            tr.begin(self._ev_pick)
+            if samp is None:
+                next_tok = np.argmax(out[:, -1], axis=-1)
+            else:
+                next_tok = out
                 self.metrics.sampled_tokens += len(reqs)
-            tr.end(self._ev_decode)
+            tr.end(self._ev_pick)
             dt_dec = time.monotonic() - t_dec
             self.metrics.decode_time_s += dt_dec
             # Decode rows: all slots execute (padding rows included) —
             # tokens counts only the active requests' commits.
             self.mfu.note("decode", tokens=len(reqs), rows=self.slots,
                           time_s=dt_dec)
+            tr.begin(self._ev_commit)
             for r in reqs:
                 self._record_token(r, int(next_tok[r.slot]))
+            tr.end(self._ev_commit)
             self.metrics.decode_steps += 1
             self.metrics.decode_tokens += len(reqs)
         self.metrics.peak_blocks_in_use = max(

@@ -163,3 +163,47 @@ def test_moe_param_counts_match_published():
     j = configs.get("jamba-1.5-large-398b")
     assert j.param_count() / 1e9 == pytest.approx(398, rel=0.05)
     assert j.active_param_count() / 1e9 == pytest.approx(94, rel=0.1)
+
+
+# The layer-kind scopes each arch's step programs carry (blocks.MIXER_SCOPE,
+# "ffn"/"moe", and model.py's "embed" and "unembed").
+SCOPES = {
+    "mistral-nemo-12b": {"embed", "attn", "ffn", "unembed"},
+    "jamba-1.5-large-398b": {"embed", "attn", "mamba", "ffn", "moe",
+                             "unembed"},
+    "xlstm-1.3b": {"embed", "mlstm", "slstm", "unembed"},
+}
+
+
+@pytest.mark.parametrize("step", ["paged_serve_step", "prefill_chunk_step"])
+@pytest.mark.parametrize("name", sorted(SCOPES))
+def test_step_programs_carry_layer_kind_scopes(name, step):
+    """The lowered HLO of the serving steps names each operation's layer
+    kind in its op_name metadata, so a profile of the step splits by kind."""
+    import re
+
+    from repro.launch import steps
+
+    cfg = configs.get_smoke(name)
+    params = jax.eval_shape(lambda: M.init_model(jax.random.PRNGKey(0), cfg))
+    state = jax.eval_shape(lambda: M.init_paged_decode_state(
+        cfg, 2, num_blocks=9, block_size=4, max_blocks_per_slot=4))
+    if step == "paged_serve_step":
+        fn = steps.make_paged_serve_step(cfg)
+        args = (jax.ShapeDtypeStruct((2, 1), jnp.int32),
+                jax.ShapeDtypeStruct((2,), jnp.bool_))
+    else:
+        fn = steps.make_prefill_chunk_step(cfg)
+        args = (jax.ShapeDtypeStruct((1, 4), jnp.int32),
+                jax.ShapeDtypeStruct((), jnp.int32))
+    assert fn.__name__ == step
+    text = jax.jit(fn).lower(params, state, *args).as_text(
+        dialect="hlo", debug_info=True)
+    names = re.findall(r'op_name="([^"]*)"', text)
+    assert any(n.startswith(f"jit({step})/") for n in names)
+    scopes = {seg for n in names for seg in n.split("/")}
+    want = SCOPES[name]
+    assert want <= scopes
+    others = {"embed", "attn", "mamba", "mlstm", "slstm", "ffn", "moe",
+              "unembed"} - want
+    assert not scopes & others

@@ -14,6 +14,7 @@ from repro.obs import (
     Histogram,
     MfuMeter,
     NULL_TRACER,
+    NullTracer,
     Tracer,
     chrome_trace_events,
     nearest_rank_index,
@@ -68,6 +69,53 @@ def test_null_tracer_is_inert():
     with NULL_TRACER.span("y"):
         pass
     assert len(NULL_TRACER) == 0 and NULL_TRACER.events() == []
+
+
+def test_spans_reach_a_recording_profiler(monkeypatch):
+    """While a profiler session records, begin/end also open and close an
+    annotation (metadata from either end); the next poll closes any an
+    exception left open, and nothing is annotated once it stops."""
+    from repro.obs import trace as trace_mod
+
+    log = []
+
+    class Fake:
+        on = True
+
+        def __init__(self, name, **meta):
+            self.name, self.meta = name, dict(meta)
+
+        @staticmethod
+        def is_enabled():
+            return Fake.on
+
+        def set_metadata(self, **meta):
+            self.meta.update(meta)
+
+        def __enter__(self):
+            log.append(("enter", self.name))
+
+        def __exit__(self, *exc):
+            log.append(("exit", self.name, self.meta))
+
+    monkeypatch.setattr(trace_mod, "TraceAnnotation", Fake)
+    for tr in (Tracer(capacity=16), NullTracer()):
+        log.clear()
+        a, b = tr.intern("a"), tr.intern("b")
+        assert tr.poll_profiler()
+        tr.begin(a, {"rows": 2})
+        tr.begin(b)
+        tr.end(b, {"n": 1})
+        tr.end(a)
+        tr.begin(b)                          # left open, as by an exception
+        Fake.on = False
+        assert not tr.poll_profiler()
+        tr.begin(a)
+        tr.end(a)
+        Fake.on = True
+        assert log == [("enter", "a"), ("enter", "b"),
+                       ("exit", "b", {"n": 1}), ("exit", "a", {"rows": 2}),
+                       ("enter", "b"), ("exit", "b", {})]
 
 
 def test_span_contextmanager_balances_on_exception():
@@ -231,7 +279,9 @@ def test_trace_export_is_valid_chrome_trace(traced_run, tmp_path):
 def test_trace_covers_lifecycle_and_phases(traced_run):
     names = {e["name"] for e in chrome_trace_events([traced_run.tracer])}
     # per-tick phase spans
-    assert {"tick", "sched", "prefill", "decode", "warmup"} <= names
+    assert {"engine.tick", "engine.admit", "engine.schedule", "engine.stage",
+            "engine.dispatch", "engine.readback", "engine.pick",
+            "engine.commit", "engine.warmup"} <= names
     # per-request lifecycle async spans
     assert {"queued", "req_prefill", "req_decode"} <= names
     # counters
@@ -254,7 +304,8 @@ def test_untraced_engine_records_nothing(traced_run):
     eng.warmup()
     eng.submit([1, 2, 3, 4], max_new=3)
     eng.run()
-    assert eng.tracer is NULL_TRACER
+    assert isinstance(eng.tracer, NullTracer) and not eng.tracer.enabled
+    assert eng.tracer is not NULL_TRACER        # its own: one owner thread
     assert chrome_trace_events([eng.tracer]) == []
 
 
@@ -294,19 +345,6 @@ def test_flow_events_bind_to_open_slices(traced_run):
             depth[key] = depth.get(key, 0) - 1
         elif e["ph"] in ("s", "t", "f"):
             assert depth.get(key, 0) > 0, e
-
-
-def test_flow_events_gated_by_trace_flow(traced_run):
-    cfg = configs.get_smoke(ARCH)
-    eng = Engine(cfg, slots=2, max_seq=32, block_size=4, max_chunk=8,
-                 trace=True, trace_flow=False)
-    eng.share_steps_from(traced_run)
-    eng.warmup()
-    eng.submit([1, 2, 3, 4], max_new=3)
-    eng.run()
-    evs = chrome_trace_events([eng.tracer])
-    assert evs                                   # still span-traced
-    assert not [e for e in evs if e["ph"] in ("s", "t", "f", "i")]
 
 
 def test_shed_and_prefix_hit_instants():
@@ -354,30 +392,173 @@ def test_cache_evict_instant_under_pool_pressure():
 def test_tracing_overhead_under_two_percent(traced_run):
     """The acceptance bar: per-tick tracing cost < 2% of a decode tick.
 
-    Asserted analytically — measured per-event ring cost x the events a
-    decode tick records, against the engine's own measured mean tick — so
-    the test is robust to host-load noise that an A/B wall-clock diff
-    (benchmarks/obs_bench.py keeps that measurement) would flake on."""
+    Asserted analytically — measured per-event ring cost x the most events
+    any tick of the traced smoke run recorded, plus the tick's one look at
+    the profiler, against the engine's own measured mean tick — so the test
+    is robust to host-load noise that an A/B wall-clock diff would flake
+    on."""
+    events_per_tick = max(events_per_tick_of(traced_run.tracer))
     tr = Tracer(capacity=1 << 14)
     code = tr.intern("bench")
     n = 5000
-    best_ns = float("inf")
+    best_ns = poll_ns = float("inf")
     for _ in range(3):                     # best-of-3: dodge load spikes
         t0 = time.perf_counter_ns()
         for _ in range(n):
             tr.begin(code)
             tr.end(code)
-        best_ns = min(best_ns, (time.perf_counter_ns() - t0) / (2 * n))
+        t1 = time.perf_counter_ns()
+        for _ in range(n):
+            tr.poll_profiler()
+        t2 = time.perf_counter_ns()
+        best_ns = min(best_ns, (t1 - t0) / (2 * n))
+        poll_ns = min(poll_ns, (t2 - t1) / n)
     m = traced_run.metrics
     tick_s = m.decode_time_s / max(1, m.decode_steps)
-    # a plain decode tick records: tick B/E + sched B/E + decode B/E
-    # + 2 KV counters = 8 events; per-request flow steps add one per
-    # active slot and spec ticks add draft/verify spans
-    events_per_tick = 14
-    overhead = events_per_tick * best_ns * 1e-9 / tick_s
+    overhead = (events_per_tick * best_ns + poll_ns) * 1e-9 / tick_s
     assert overhead < 0.02, (
         f"tracing costs {overhead:.2%} of a {tick_s * 1e6:.0f}us decode tick "
-        f"({best_ns:.0f}ns/event)")
+        f"({events_per_tick} events of {best_ns:.0f}ns)")
+
+
+def events_per_tick_of(tracer):
+    """Ring events recorded within each engine.tick span, its own B/E
+    included (flow steps, counters, nested spans)."""
+    out, n = [], None
+    for e in tracer.events():
+        if e["name"] == "engine.tick" and e["ph"] == "B":
+            n = 0
+        if n is not None:
+            n += 1
+        if e["name"] == "engine.tick" and e["ph"] == "E":
+            out.append(n)
+            n = None
+    assert out, "the traced run recorded no tick"
+    return out
+
+
+# ---------------------------------------------------------------------------
+# engine spans on the profiler's clock
+# ---------------------------------------------------------------------------
+
+TICK_ORDER = ["engine.admit", "engine.schedule", "engine.stage",
+              "engine.dispatch", "engine.readback", "engine.pick",
+              "engine.commit"]
+
+
+def _recording_actions(eng):
+    """Wrap the scheduler's next_action to log what each tick ran, as the
+    dispatch span's metadata should carry it ({} for a tick that ran no
+    step)."""
+    ran, inner = [], eng.scheduler.next_action
+
+    def next_action():
+        a = inner()
+        if a is None:
+            ran.append({})
+        elif a[0] == "prefill":
+            ran.append({"chunk": a[2], "start": a[1].prefilled})
+        else:
+            ran.append({"rows": len(a[1]),
+                        "ctx_tokens": sum(r.length for r in a[1])})
+        return a
+
+    eng.scheduler.next_action = next_action
+    return ran
+
+
+def _host_spans(trace_dir):
+    """engine.* events of the profile under trace_dir: (name, start, end,
+    metadata), by start."""
+    import glob
+    import os
+
+    from jax.profiler import ProfileData
+
+    [path] = glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"),
+                       recursive=True)
+    out = []
+    for plane in ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            for e in line.events:
+                if e.name.startswith("engine."):
+                    out.append((e.name, e.start_ns, e.end_ns,
+                                dict(iter(e.stats))))
+    return sorted(out, key=lambda x: (x[1], -x[2]))
+
+
+@pytest.fixture(scope="module")
+def profiled_run(traced_run, tmp_path_factory):
+    """An untraced engine (ring off) served under the JAX profiler."""
+    import jax
+
+    cfg = configs.get_smoke(ARCH)
+    eng = Engine(cfg, slots=2, max_seq=64, block_size=4, max_chunk=8)
+    eng.share_steps_from(traced_run)
+    eng.warmup()
+    ran = _recording_actions(eng)
+    rng = np.random.default_rng(3)
+    for n in (11, 5, 7):
+        eng.submit(rng.integers(0, cfg.vocab, size=n), max_new=4)
+    trace_dir = str(tmp_path_factory.mktemp("profile"))
+    jax.profiler.start_trace(trace_dir)
+    try:
+        eng.run()
+    finally:
+        jax.profiler.stop_trace()
+    return eng, ran, _host_spans(trace_dir)
+
+
+def test_engine_spans_reach_the_profiler(profiled_run):
+    """With the ring off, each tick still writes engine.tick and its phases
+    into the profiler's trace, nested in the tick, in the order the tick
+    runs them; the dispatch span carries what the scheduler ran."""
+    eng, ran, spans = profiled_run
+    assert len(eng.tracer) == 0                  # the ring stayed off
+    ticks = [s for s in spans if s[0] == "engine.tick"]
+    assert len(ticks) == len(ran) > 0
+    dispatched = []
+    for _, a, b, _ in ticks:
+        inner = [s for s in spans if s[0] != "engine.tick"
+                 and a <= s[1] and s[2] <= b]
+        # the tick's own phases: not nested in another phase
+        top = [s for s in inner if not any(
+            o is not s and o[1] <= s[1] and s[2] <= o[2] for o in inner)]
+        names = [s[0] for s in top]
+        assert names == [n for n in TICK_ORDER if n in names], names
+        assert names[:4] == TICK_ORDER[:4]
+        assert {"engine.readback", "engine.commit"} <= set(names)
+        assert all(x[2] <= y[1] for x, y in zip(top, top[1:]))
+        dispatched += [s[3] for s in top if s[0] == "engine.dispatch"]
+    assert dispatched == [a for a in ran if a]
+    assert any("chunk" in d for d in ran) and any("rows" in d for d in ran)
+    admits = [s[3] for s in spans if s[0] == "engine.admit"]
+    assert sum(d["admitted"] for d in admits) == 3
+    assert admits[0]["queued"] == 1              # 2 slots, 3 requests
+
+
+def test_no_annotation_without_a_profile(traced_run, monkeypatch):
+    """With the profiler off, an untraced engine creates no annotation:
+    its spans cost one attribute test each."""
+    from repro.obs import trace as trace_mod
+
+    made = []
+    real = trace_mod.TraceAnnotation
+
+    class Counting(real):
+        def __init__(self, *a, **kw):
+            made.append(a)
+            super().__init__(*a, **kw)
+
+    monkeypatch.setattr(trace_mod, "TraceAnnotation", Counting)
+    cfg = configs.get_smoke(ARCH)
+    eng = Engine(cfg, slots=2, max_seq=32, block_size=4, max_chunk=8)
+    eng.share_steps_from(traced_run)
+    eng.warmup()
+    eng.submit([1, 2, 3, 4, 5], max_new=3)
+    eng.run()
+    assert eng.metrics.decode_steps > 0
+    assert made == []
 
 
 # ---------------------------------------------------------------------------
@@ -538,7 +719,8 @@ def test_replica_pool_trace_multi_pid(tmp_path):
     pids = {e["pid"] for e in evs}
     assert pids == {0, 1}                  # one process lane per replica
     for pid in pids:                       # both replicas actually traced
-        assert any(e["ph"] == "B" and e["name"] == "tick" and e["pid"] == pid
+        assert any(e["ph"] == "B" and e["name"] == "engine.tick"
+                   and e["pid"] == pid
                    for e in evs)
     names = {e["args"]["name"] for e in evs if e["ph"] == "M"
              and e["name"] == "process_name"}
