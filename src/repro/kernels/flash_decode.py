@@ -3,35 +3,40 @@
 The serving decode path previously materialized every slot's cache view with
 ``gather_kv`` (a (B, max_blocks * block_size, H, D) gather — the *full* table
 extent, mostly null blocks at short lengths) and ran a whole-cache einsum.
-This kernel instead walks the per-slot block tables *inside* the grid — the
-paper's programmable strided memory access (Sec 3.3) applied to decode: the
-block table is the stride program, and each grid step DMAs exactly one pool
-block.  HBM traffic per step drops from the table extent to the lived-in
-blocks, and nothing is ever materialized per slot.
+This kernel instead walks the per-slot block tables — the paper's
+programmable strided memory access (Sec 3.3) applied to decode: the block
+table is the stride program.  Each (slot, split) program walks only its
+row's live steps, so the DMA traffic *and* the compute of a decode step
+follow the live context, not the table extent, and nothing is ever
+materialized per slot.
 
-Shape story (one grid step = one pool block, all kv heads, for one
-(slot, split)):
+Shape story (one grid program per (slot, split); one loop step = 128 key
+positions, all kv heads):
 
   q            (B, Sq, Hq, D)     -> packed (B, Hkv, G * Sq, D) rows
-  k/v pool     (num_blocks, Hkv, block_size, D), addressed via the
-               scalar-prefetched block table: block index
-               ``tables[b, split * cols_per_split + j]``
+  k/v pool     (num_blocks, Hkv, block_size, D), left in HBM: a step DMAs
+               its ``P = blocks_per_step(...)`` blocks by table entry,
+               ``tables[b, step * P + p]``, into a double buffer in VMEM,
+               the next step's blocks while this one computes
+  live steps   ``live_steps(...)``: a step is walked while it starts at or
+               before the row's last query and its first table entry is
+               not the null block (a released slot reads as empty); the
+               engine's ``kv_blocks`` counter reads the same function
   outputs      per-split partial (acc, m, l) — online-softmax state — reduced
-               in a cheap second stage (split-K over the sequence dimension,
-               so long contexts parallelize across the grid instead of
-               serializing one slot's whole table on one core).
+               in a cheap second stage (split-K over the sequence steps)
 
 GQA is handled by packing the G query heads of a kv head (times the Sq query
 positions — Sq > 1 for speculative verify and chunked prefill) into the row
-axis of a single (rows, block_size) score tile, so KV is fetched once per
+axis of a single lane-dense (rows, 128) score tile, so KV is fetched once per
 kv head, never repeated.  Per-slot length masking (``kpos <= index[b] + t``)
 and sliding windows are applied in-kernel.
 
 int8 KV residency: when the pool carries per-(block, position, kv-head)
 scales (``PagedKVCache.k_scale``/``v_scale``, see serving/kv_cache.py), the
 kernel fetches int8 K/V blocks and dequantizes them in registers inside the
-inner loop — no dequantized copy of the cache ever exists, so the ~4x
-byte saving is real end to end.
+inner loop — no dequantized copy of the cache ever exists.  The scales of
+each row's table are gathered lane-dense before the kernel (the chip's
+compiler refuses a DMA of a scale block narrower than 128 lanes).
 
 Also here:
 
@@ -51,10 +56,12 @@ from __future__ import annotations
 import contextlib
 import dataclasses
 import functools
+import math
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -71,10 +78,9 @@ NEG_INF = -2.0e38
 class FlashDecodeSpec:
     """One decode-kernel design point (the analogue of TpuGemmSpec).
 
-    num_splits     split-K factor over the block-table columns: each split
-                   produces partial (acc, m, l) reduced in stage 2.  1 = no
-                   split (short contexts); long tables want the sequence
-                   walk spread across the grid.
+    num_splits     split-K factor over the kernel's steps: each split is
+                   one more grid program per slot and produces partial
+                   (acc, m, l) reduced in stage 2.  1 = no split.
     cols_per_iter  table columns the *fallback* path gathers per
                    ``while_loop`` iteration — its chunk/overshoot trade-off
                    (a bigger chunk amortizes iteration overhead but gathers
@@ -105,68 +111,148 @@ class FlashDecodeSpec:
 # the Pallas kernel
 # ---------------------------------------------------------------------------
 
+STEP_TOKENS = 128   # key positions per kernel step: one lane-dense score tile
+
+
+def blocks_per_step(block_size: int, max_blocks: int) -> int:
+    """Pool blocks one kernel step fetches: enough to cover ``STEP_TOKENS``
+    key positions when the block size divides it, cut to a divisor of the
+    table width so that every block a step fetches is a table column; else
+    one block."""
+    if STEP_TOKENS % block_size:
+        return 1
+    return math.gcd(STEP_TOKENS // block_size, max_blocks)
+
+
+def live_steps(index, sq: int, tables, block_size: int):
+    """Per row, the kernel steps that hold keys the row attends.
+
+    Step g covers table columns ``[g * P, (g + 1) * P)`` with ``P =
+    blocks_per_step(...)``.  It is live while its first key position is at
+    most the row's last query position (``index + sq - 1``) and its first
+    table entry is not ``NULL_BLOCK``: a released slot's row is all null
+    while its length stays stale until the slot is reset.  The walk stops at
+    the first dead step.  Takes numpy arrays (the engine's ``kv_blocks``
+    counter) and jax arrays (the kernel's trip counts) alike.
+    """
+    P = blocks_per_step(block_size, tables.shape[1])
+    first = tables[:, ::P]                                 # (B, steps)
+    start = np.arange(first.shape[1]) * (P * block_size)
+    live = (index[:, None] + sq > start) & (first != NULL_BLOCK)
+    return live.astype(np.int32).cumprod(axis=1).sum(axis=1)
+
+
+def _unrolled(n: int, body) -> None:
+    """``for i in range(n): body(i)``, traced once and unrolled when the
+    kernel is lowered: the chip runs the same straight-line code, and each
+    step program that holds the kernel traces it in a fraction of the time
+    (a warm engine traces every step it loads from the compile cache)."""
+    def run(i, carry):
+        body(i)
+        return carry
+
+    jax.lax.fori_loop(0, n, run, 0, unroll=True)
+
+
 def _decode_kernel(
-    bt_ref, idx_ref,                       # scalar-prefetch: tables, index
-    q_ref, k_ref, v_ref, *rest,
-    cols_per_split: int, block_size: int, sq: int, scale: float,
-    window: Optional[int], seq_cap: int, quantized: bool,
+    bt_ref, idx_ref, n_ref,                # scalar-prefetch: tables, index,
+    q_ref, k_hbm, v_hbm, *rest,            # live steps per row
+    steps_per_split: int, blocks: int, block_size: int, sq: int,
+    scale: float, window: Optional[int], seq_cap: int, quantized: bool,
 ):
     if quantized:
-        ks_ref, vs_ref, acc_out, m_out, l_out, acc_ref, m_ref, l_ref = rest
-    else:
-        acc_out, m_out, l_out, acc_ref, m_ref, l_ref = rest
+        ks_ref, vs_ref, *rest = rest
+    acc_out, m_out, l_out, kbuf, vbuf, sem, m_ref, l_ref = rest
     b = pl.program_id(0)
     s = pl.program_id(1)
-    j = pl.program_id(2)
-    n_heads, rows = q_ref.shape[1], q_ref.shape[2]
+    n_heads, rows, D = q_ref.shape[1], q_ref.shape[2], q_ref.shape[3]
+    tokens = blocks * block_size
+    lo = s * steps_per_split
+    hi = jnp.minimum(lo + steps_per_split, n_ref[b])
 
-    @pl.when(j == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
+    def fetch(g, slot):
+        """Start the DMAs of step g's K and V blocks, by table entry, into
+        one half of the double buffer; all signal that half's semaphore."""
+        def block(p):
+            blk = bt_ref[b, g * blocks + p]
+            for src, dst in ((k_hbm, kbuf), (v_hbm, vbuf)):
+                pltpu.make_async_copy(src.at[blk], dst.at[slot, p],
+                                      sem.at[slot]).start()
+
+        _unrolled(blocks, block)
+
+    def wait(slot):
+        """Wait for one half of the double buffer: a wait counts bytes, so
+        one descriptor the size of the half stands for its blocks' DMAs."""
+        for src, dst in ((k_hbm, kbuf), (v_hbm, vbuf)):
+            pltpu.make_async_copy(src.at[pl.ds(0, blocks)], dst.at[slot],
+                                  sem.at[slot]).wait()
+
+    acc_out[...] = jnp.zeros_like(acc_out)     # accumulated in place
+    m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+    l_ref[...] = jnp.zeros_like(l_ref)
+
+    @pl.when(lo < hi)
+    def _first():
+        fetch(lo, 0)
 
     # Row r packs (group g, query offset t) = (r // sq, r % sq); padding rows
     # past G * Sq carry zero queries and are sliced off after the combine.
-    # The mask is the same for every kv head of the block.
-    col = s * cols_per_split + j
-    t = jax.lax.broadcasted_iota(jnp.int32, (rows, block_size), 0) % sq
+    # The mask is the same for every kv head of the step.
+    t = jax.lax.broadcasted_iota(jnp.int32, (rows, tokens), 0) % sq
     qpos = idx_ref[b] + t
-    kpos = col * block_size + jax.lax.broadcasted_iota(
-        jnp.int32, (rows, block_size), 1)
-    mask = (kpos <= qpos) & (kpos < seq_cap)
-    if window is not None:
-        mask &= (qpos - kpos) < window
+    kcol = jax.lax.broadcasted_iota(jnp.int32, (rows, tokens), 1)
 
-    for h in range(n_heads):                                # static unroll
-        q = q_ref[0, h].astype(jnp.float32) * scale         # (rows, D)
-        k = k_ref[0, h].astype(jnp.float32)                 # (block_size, D)
-        v = v_ref[0, h].astype(jnp.float32)
-        scores = jax.lax.dot(q, k.T, preferred_element_type=jnp.float32)
-        if quantized:
-            # int8 codes times per-position scales: the scale of key i
-            # multiplies score column i (and value i weighs p column i), so
-            # the (1, block_size) scale row broadcasts over the rows.
-            scores = scores * ks_ref[0, h:h + 1, :]
-        scores = jnp.where(mask, scores, NEG_INF)
+    def step(g, carry):
+        slot = (g - lo) % 2
 
-        m_prev = m_ref[h]                                   # (rows, 1)
-        m_new = jnp.maximum(m_prev, jnp.max(scores, axis=-1, keepdims=True))
-        p = jnp.exp(scores - m_new)
-        alpha = jnp.exp(m_prev - m_new)
-        l_ref[h] = l_ref[h] * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        m_ref[h] = m_new
-        if quantized:
-            p = p * vs_ref[0, h:h + 1, :]
-        acc_ref[h] = acc_ref[h] * alpha + jax.lax.dot(
-            p, v, preferred_element_type=jnp.float32)
+        @pl.when(g + 1 < hi)
+        def _prefetch():
+            fetch(g + 1, 1 - slot)
 
-    @pl.when(j == cols_per_split - 1)
-    def _flush():
-        acc_out[0, 0] = acc_ref[...]
-        for h in range(n_heads):
-            m_out[0, 0, h] = m_ref[h][:, 0]
-            l_out[0, 0, h] = l_ref[h][:, 0]
+        wait(slot)
+        keys = pl.ds(g * tokens, tokens)       # this step's scale columns
+        kpos = g * tokens + kcol
+        mask = (kpos <= qpos) & (kpos < seq_cap)
+        if window is not None:
+            mask &= (qpos - kpos) < window
+
+        def head(h):
+            q = q_ref[0, h].astype(jnp.float32) * scale     # (rows, D)
+            k = kbuf[slot, :, h].astype(jnp.float32).reshape(tokens, D)
+            v = vbuf[slot, :, h].astype(jnp.float32).reshape(tokens, D)
+            scores = jax.lax.dot_general(
+                q, k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)         # (rows, tokens)
+            if quantized:
+                # int8 codes times per-position scales: the scale of key i
+                # multiplies score column i (and value i weighs p column
+                # i), so the (1, tokens) scale row broadcasts over the rows.
+                scores = scores * ks_ref[0, pl.ds(h, 1), keys]
+            scores = jnp.where(mask, scores, NEG_INF)
+
+            m_prev = m_ref[h]                               # (rows, 1)
+            m_new = jnp.maximum(m_prev,
+                                jnp.max(scores, axis=-1, keepdims=True))
+            p = jnp.exp(scores - m_new)
+            alpha = jnp.exp(m_prev - m_new)
+            l_ref[h] = l_ref[h] * alpha + jnp.sum(p, axis=-1, keepdims=True)
+            m_ref[h] = m_new
+            if quantized:
+                p = p * vs_ref[0, pl.ds(h, 1), keys]
+            acc_out[0, 0, h] = acc_out[0, 0, h] * alpha + jax.lax.dot(
+                p, v, preferred_element_type=jnp.float32)
+
+        _unrolled(n_heads, head)
+        return carry
+
+    jax.lax.fori_loop(lo, hi, step, 0)
+
+    def flush(h):
+        m_out[0, 0, h] = m_ref[h][:, 0]
+        l_out[0, 0, h] = l_ref[h][:, 0]
+
+    _unrolled(n_heads, flush)
 
 
 def _combine_splits(acc, m, l):
@@ -217,11 +303,12 @@ def flash_decode_attention(
 ) -> jax.Array:
     """Decode attention over the paged pool via the Pallas kernel.
 
-    Every block the kernel touches keeps its last two dims equal to whole
-    array dims, so the TPU's (8, 128) tiling rule holds for any kv-head
-    count, block size and split factor: K/V come in as (1, Hkv, bs, D) pool
-    blocks, scales as (1, Hkv, bs), and the per-split partials leave as
-    (1, 1, Hkv, rows, D) and (1, 1, Hkv, rows).
+    The pools stay in HBM in their ``(num_blocks, Hkv, bs, D)`` layout; each
+    (row, split) program DMAs its live steps' blocks, by table entry, into
+    a double buffer in VMEM.  Every block the grid pipelines (q, the int8
+    scale rows, the partials) keeps its last two dims equal to whole array
+    dims, so the TPU's (8, 128) tiling rule holds for any kv-head count and
+    split factor.
     """
     spec = spec or FlashDecodeSpec()
     B, Sq, Hq, D = q.shape
@@ -229,65 +316,73 @@ def flash_decode_attention(
     groups = Hq // Hkv
     max_blocks = block_tables.shape[1]
     seq_cap = max_blocks * bs
+    P = blocks_per_step(bs, max_blocks)
+    n_steps = max_blocks // P
 
-    splits = max(1, min(spec.num_splits, max_blocks))
-    cps = -(-max_blocks // splits)
     bt = block_tables.astype(jnp.int32)
-    pad_cols = splits * cps - max_blocks
-    if pad_cols:
-        bt = jnp.pad(bt, ((0, 0), (0, pad_cols)),
-                     constant_values=NULL_BLOCK)
     idx = jnp.asarray(index, jnp.int32)
     if idx.ndim == 0:
         idx = jnp.broadcast_to(idx, (B,))
+    n_live = live_steps(idx, Sq, bt, bs)
+
+    splits = max(1, min(spec.num_splits, n_steps))
+    sps = -(-n_steps // splits)
+    pad_cols = splits * sps * P - max_blocks
+    if pad_cols:
+        bt = jnp.pad(bt, ((0, 0), (0, pad_cols)),
+                     constant_values=NULL_BLOCK)
 
     qr, rows, rows_p = _pack_q(q, groups, Hkv)
     quantized = cache.k_scale is not None
 
-    def qmap(b, s, j, bt, idx):
+    def qmap(b, s, bt, idx, n):
         return (b, 0, 0, 0)
 
-    def kvmap(b, s, j, bt, idx, cps=cps):
-        return (bt[b, s * cps + j], 0, 0, 0)
+    def rowmap(b, s, bt, idx, n):
+        return (b, 0, 0)
 
-    def smap(b, s, j, bt, idx, cps=cps):
-        return (bt[b, s * cps + j], 0, 0)
-
-    in_specs = [
-        pl.BlockSpec((1, Hkv, rows_p, D), qmap),
-        pl.BlockSpec((1, Hkv, bs, D), kvmap),
-        pl.BlockSpec((1, Hkv, bs, D), kvmap),
-    ]
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
+    in_specs = [pl.BlockSpec((1, Hkv, rows_p, D), qmap), hbm, hbm]
     operands = [qr, cache.k, cache.v]
+    scratch = [pltpu.VMEM((2, P, Hkv, bs, D), cache.k.dtype),
+               pltpu.VMEM((2, P, Hkv, bs, D), cache.v.dtype)]
     if quantized:
-        in_specs += [pl.BlockSpec((1, Hkv, bs), smap),
-                     pl.BlockSpec((1, Hkv, bs), smap)]
-        operands += [cache.k_scale, cache.v_scale]
+        # The chip's compiler refuses a DMA of a (Hkv, bs) slice whose last
+        # dim is under 128 lanes, so the scales of each row's table are
+        # gathered here, lane-dense, and read whole per row.
+        def rows_of(scale):
+            r = scale[bt]                                  # (B, C, Hkv, bs)
+            return r.transpose(0, 2, 1, 3).reshape(B, Hkv, -1)
 
-    def out_map4(b, s, j, bt, idx):
+        smap = pl.BlockSpec((1, Hkv, bt.shape[1] * bs), rowmap)
+        in_specs += [smap, smap]
+        operands += [rows_of(cache.k_scale), rows_of(cache.v_scale)]
+    scratch += [
+        pltpu.SemaphoreType.DMA((2,)),
+        pltpu.VMEM((Hkv, rows_p, 1), jnp.float32),
+        pltpu.VMEM((Hkv, rows_p, 1), jnp.float32),
+    ]
+
+    def out_map4(b, s, bt, idx, n):
         return (b, s, 0, 0)
 
-    def out_map5(b, s, j, bt, idx):
+    def out_map5(b, s, bt, idx, n):
         return (b, s, 0, 0, 0)
 
     kernel = functools.partial(
-        _decode_kernel, cols_per_split=cps, block_size=bs, sq=Sq,
+        _decode_kernel, steps_per_split=sps, blocks=P, block_size=bs, sq=Sq,
         scale=D ** -0.5, window=window, seq_cap=seq_cap, quantized=quantized,
     )
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(B, splits, cps),
+        num_scalar_prefetch=3,
+        grid=(B, splits),
         in_specs=in_specs,
         out_specs=[
             pl.BlockSpec((1, 1, Hkv, rows_p, D), out_map5),
             pl.BlockSpec((1, 1, Hkv, rows_p), out_map4),
             pl.BlockSpec((1, 1, Hkv, rows_p), out_map4),
         ],
-        scratch_shapes=[
-            pltpu.VMEM((Hkv, rows_p, D), jnp.float32),
-            pltpu.VMEM((Hkv, rows_p, 1), jnp.float32),
-            pltpu.VMEM((Hkv, rows_p, 1), jnp.float32),
-        ],
+        scratch_shapes=scratch,
     )
     acc, m, l = pl.pallas_call(
         kernel,
@@ -298,7 +393,7 @@ def flash_decode_attention(
             jax.ShapeDtypeStruct((B, splits, Hkv, rows_p), jnp.float32),
         ],
         interpret=interpret,
-    )(bt, idx, *operands)
+    )(bt, idx, n_live, *operands)
     out = _combine_splits(acc, m, l)
     return _unpack_out(out, B, Sq, Hq, D, groups, rows).astype(q.dtype)
 

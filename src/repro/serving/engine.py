@@ -43,6 +43,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.dataflow import GemmShape
+from repro.kernels.flash_decode import blocks_per_step, live_steps
 from repro.models import model as M
 from repro.obs import Histogram, MfuMeter, NullTracer, Tracer
 from repro.obs import percentile as _obs_percentile
@@ -1066,6 +1067,22 @@ class Engine:
             caches=caches, lengths=jnp.asarray(lengths))
         self.metrics.swap_time_s += time.monotonic() - t0
 
+    def _kv_blocks(self) -> int:
+        """Pool blocks the flash-decode kernel fetches in a decode step,
+        over every slot (``kv_blocks`` on the tick's ``engine.stage``
+        span): each row walks its live 128-token steps
+        (``flash_decode.live_steps``) from its device length, which is one
+        behind the request's length once it decodes (the newest token's KV
+        goes in on the step that feeds it) and the prefilled prompt before.
+        A slot with no request has a null table row and walks nothing."""
+        idx = np.zeros((self.slots,), np.int32)
+        for r in self.scheduler.slots:
+            if r is not None:
+                idx[r.slot] = r.length - (1 if r.out_tokens else 0)
+        steps = live_steps(idx, 1, self.tables.table, self.block_size)
+        return int(steps.sum()) * blocks_per_step(self.block_size,
+                                                  self.max_blocks_per_slot)
+
     def _sync_tables(self) -> None:
         if self.tables.dirty:
             self.state = self.state._replace(block_tables=self.tables.array())
@@ -1238,7 +1255,7 @@ class Engine:
         """Admit, then execute one scheduler action.  Returns False when no
         work remains."""
         tr = self.tracer
-        tr.poll_profiler()
+        profiling = tr.poll_profiler()
         tr.begin(self._ev_tick)
         tr.begin(self._ev_admit)
         admitted = self._admit()
@@ -1340,7 +1357,9 @@ class Engine:
             active = np.zeros((self.slots,), bool)
             active[[r.slot for r in reqs]] = True
             samp = self._sampling_args(reqs)
-            tr.end(self._ev_stage)
+            # What the flash-decode kernel will fetch, for the profile only.
+            tr.end(self._ev_stage,
+                   {"kv_blocks": self._kv_blocks()} if profiling else None)
             t_dec = time.monotonic()
             tr.begin(self._ev_dispatch,
                      {"rows": len(reqs), "ctx_tokens": ctx_tokens})

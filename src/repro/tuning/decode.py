@@ -5,9 +5,9 @@ legal design points, rank (analytic model or wall clock), persist the winner.
 This module gives the paged flash-decode kernel (kernels/flash_decode.py) the
 same treatment for its two knobs:
 
-  num_splits     split-K factor over the block-table columns (the Pallas
-                 kernel's sequence-dimension parallelism / combine-overhead
-                 trade);
+  num_splits     split-K factor over the kernel's 128-token steps: each
+                 split is one more (row, split) program and one more
+                 partial for the combine;
   cols_per_iter  table columns per ``while_loop`` chunk of the bounded
                  pure-JAX fallback (iteration overhead vs gather overshoot).
 
@@ -16,11 +16,11 @@ Winners land in the same ``TuneCache`` registry as GeMM tiles under an
 REPRO_TUNE_CACHE file carries a deployment's full configuration — GeMM tiles
 and decode design points — exactly like the paper's generated CSR image.
 
-The analytic model is deliberately coarse (decode attention is bandwidth-
-bound, not MAC-bound): costs are in "block-visit" units with fixed launch /
-combine / iteration overheads, enough to rank the knobs deterministically on
-any host.  ``mode="wallclock"`` times the real dispatch path instead — the
-Pallas kernel on TPU, the bounded fallback elsewhere.
+The analytic model is deliberately coarse: costs are in MAC-ish units with
+fixed per-step / per-program / combine / iteration overheads, enough to rank
+the knobs deterministically on any host.  ``mode="wallclock"`` times the
+real dispatch path instead — the Pallas kernel on TPU, the bounded fallback
+elsewhere.
 """
 
 from __future__ import annotations
@@ -28,7 +28,7 @@ from __future__ import annotations
 import time
 from typing import List, NamedTuple, Optional
 
-from repro.kernels.flash_decode import FlashDecodeSpec
+from repro.kernels.flash_decode import FlashDecodeSpec, blocks_per_step
 from repro.tuning.autotuner import (
     Autotuner,
     TuneResult,
@@ -37,8 +37,10 @@ from repro.tuning.autotuner import (
 )
 from repro.tuning.cache import CacheEntry
 
-# Coarse cost-model constants (dimensionless "block-visit" units).
-_SPLIT_OVERHEAD = 1000.0   # per-split launch + partial (acc, m, l) write
+# Coarse cost-model constants (dimensionless MAC-ish units).
+_STEP_OVERHEAD = 1000.0    # per kernel step: DMA issue and wait, loop, mask
+_PROGRAM_OVERHEAD = 4000.0  # per (row, split) program: grid step, a first
+                            # fetch nothing overlaps, partial (acc, m, l)
 _COMBINE_PER_ELEM = 4.0    # stage-2 rescale/accumulate per partial element
 _ITER_OVERHEAD = 4000.0    # while_loop iteration dispatch (fallback path)
 _MAX_SPLITS = 16
@@ -81,7 +83,9 @@ def enumerate_decode_specs(shape: DecodeShape) -> List[FlashDecodeSpec]:
     """Legal (num_splits, cols_per_iter) design points, default included,
     deterministic order (ascending splits, then cols) — same contract as
     ``candidates.enumerate_tiles``."""
-    splits = _pow2s(min(_MAX_SPLITS, shape.max_blocks))
+    steps = shape.max_blocks // blocks_per_step(shape.block_size,
+                                                shape.max_blocks)
+    splits = _pow2s(min(_MAX_SPLITS, steps))
     cols_cap = max(1, min(shape.max_blocks,
                           _MAX_CHUNK_TOKENS // max(1, shape.block_size)))
     cols = _pow2s(cols_cap)
@@ -101,21 +105,28 @@ def enumerate_decode_specs(shape: DecodeShape) -> List[FlashDecodeSpec]:
 
 
 def predict_decode_cost(spec: FlashDecodeSpec, shape: DecodeShape) -> float:
-    """Rank a candidate: split-path latency + fallback-path cost.
+    """Rank a candidate: kernel cost + fallback-path cost.
 
     The two knobs are independent (each term consumes one), so ranking the
-    sum tunes both jointly.  Per kv head: every visited pool block costs
-    ``block_size * rows * head_dim * 2`` MAC-ish units (QK^T + PV); splits
-    shorten the serial column walk at ``_SPLIT_OVERHEAD`` + combine cost
-    each; fallback chunks amortize ``_ITER_OVERHEAD`` against an expected
-    half-chunk gather overshoot past the live length.
+    sum tunes both jointly.  Per kv head, at the full table (every step
+    live, as the wall-clock timing runs it): the kernel takes
+    ``max_blocks / P`` steps of ``P`` blocks per row (``blocks_per_step``),
+    each ``P * block_size * rows * head_dim * 2`` MAC-ish units (QK^T + PV)
+    plus ``_STEP_OVERHEAD``.  Every (row, split) program runs in turn on
+    the one TensorCore, so splits shorten nothing: each adds
+    ``_PROGRAM_OVERHEAD`` and a partial for the combine.  Fallback chunks
+    amortize ``_ITER_OVERHEAD`` against an expected half-chunk gather
+    overshoot past the live length.
     """
     rows = max(8, shape.groups * shape.sq)
     block_cost = float(shape.block_size * rows * shape.head_dim * 2)
-    splits = max(1, min(spec.num_splits, shape.max_blocks))
-    serial_cols = -(-shape.max_blocks // splits)
-    split_cost = serial_cols * block_cost + splits * (
-        _SPLIT_OVERHEAD + _COMBINE_PER_ELEM * rows * shape.head_dim)
+    P = blocks_per_step(shape.block_size, shape.max_blocks)
+    steps = shape.max_blocks // P
+    splits = max(1, min(spec.num_splits, steps))
+    split_cost = shape.slots * (
+        steps * (P * block_cost + _STEP_OVERHEAD)
+        + splits * (_PROGRAM_OVERHEAD
+                    + _COMBINE_PER_ELEM * rows * shape.head_dim))
     cols = max(1, min(spec.cols_per_iter, shape.max_blocks))
     iters = -(-shape.max_blocks // cols)
     ref_cost = iters * _ITER_OVERHEAD + (cols / 2.0) * block_cost

@@ -66,25 +66,98 @@ def _oracle(q, cache, bt, idx, window=None):
     return decode_attention(q, k, v, index=idx, window=window)
 
 
-@pytest.mark.parametrize("sq,window,splits", [
-    (1, None, 1),     # plain decode
-    (1, None, 4),     # split-K (uneven: 6 cols over 4 splits, padded tail)
-    (3, None, 2),     # Sq > 1 (speculative verify width), split
-    (1, 6, 1),        # sliding window
-    (3, 6, 4),        # everything at once
+# Rows of the live-step walk: keys visible to each row's last query, the
+# empty row (null table) and a released row (null table, stale length).
+WALK_KEYS = [0, 1, 127, 128, 129, None]      # None: the full table
+WALK_TOKENS, WALK_STALE = 768, 300
+
+
+def _walk_pool(bs, sq, kv_precision):
+    """Seven rows over a 768-token table: ragged lengths around the
+    kernel's 128-token step, a full row, an empty row and a released row
+    whose length is stale.  Returns the pool, table, query, first query
+    positions and the rows that hold keys."""
+    max_blocks = WALK_TOKENS // bs
+    keys = [WALK_TOKENS if n is None else n and max(n, sq) for n in WALK_KEYS]
+    B = len(keys) + 1
+    rng = np.random.default_rng(7)
+    num_blocks = 1 + B * max_blocks
+    cache = kvc.init_paged_kv(num_blocks, bs, HKV, D, jnp.float32,
+                              kv_precision=kv_precision)
+    alloc = kvc.BlockAllocator(num_blocks, bs)
+    tables = kvc.BlockTables(B, max_blocks)
+    for r, n in enumerate(keys):
+        tables.ensure(r, n, alloc)
+    tables.ensure(B - 1, WALK_STALE, alloc)
+    k_new = jnp.asarray(rng.normal(size=(B, WALK_TOKENS, HKV, D)),
+                        jnp.float32)
+    v_new = jnp.asarray(rng.normal(size=(B, WALK_TOKENS, HKV, D)),
+                        jnp.float32)
+    cache = kvc.write_kv(cache, tables.array(), k_new, v_new, 0)
+    tables.release(B - 1, alloc)
+    q = jnp.asarray(rng.normal(size=(B, sq, HKV * GROUPS, D)), jnp.float32)
+    idx = np.array([max(n - sq, 0) for n in keys] + [WALK_STALE], np.int32)
+    live = [r for r, n in enumerate(keys) if n]
+    return cache, tables.array(), q, jnp.asarray(idx), live
+
+
+@pytest.mark.parametrize("bs,sq,window,kv_precision,splits", [
+    (BS, 1, None, "float", 1),      # plain decode, 32 blocks a step
+    (BS, 1, None, "float", 4),      # split-K (6 steps over 4 splits)
+    (BS, 3, None, "float", 2),      # Sq > 1 (speculative verify width)
+    (BS, 1, 6, "float", 1),         # sliding window
+    (BS, 3, 6, "float", 4),         # everything at once
+    (16, 1, None, "float", 1),      # 8 blocks per step
+    (16, 1, None, "float", 4),      # split over the 6 steps
+    (16, 64, None, "float", 1),     # a 64-query prefill chunk
+    (16, 1, 512, "float", 2),       # window 512
+    (16, 1, None, "int8", 1),       # int8 pool with scales
+    (128, 1, None, "float", 1),     # one 128-token block per step
+    (128, 64, 512, "int8", 3),      # everything at once
 ])
-def test_flash_kernel_matches_oracle(sq, window, splits):
+def test_flash_kernel_matches_oracle(bs, sq, window, kv_precision, splits):
     """The Pallas kernel (interpret mode) reproduces gather_kv +
-    decode_attention across ragged lengths, GQA packing, windows, Sq > 1,
-    and split-K — the exact combinations the serving step dispatches."""
-    cache, bt = _make_pool()
-    q, idx = _query(sq)
-    want = _oracle(q, cache, bt, idx, window=window)
-    got = fd.flash_decode_attention(
+    decode_attention on every row with keys: GQA packing, windows, Sq > 1,
+    split-K, int8 scales, and lengths on each side of a 128-key step and
+    at the full table.  The empty row and the released row (null table,
+    stale length) read alike, as rows with no key."""
+    cache, bt, q, idx, live = _walk_pool(bs, sq, kv_precision)
+    got = np.asarray(fd.flash_decode_attention(
         q, cache, bt, idx, window=window,
-        spec=fd.FlashDecodeSpec(num_splits=splits), interpret=True)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                               rtol=1e-5, atol=1e-5)
+        spec=fd.FlashDecodeSpec(num_splits=splits), interpret=True))
+    want = np.asarray(_oracle(q, cache, bt, idx, window=window))
+    np.testing.assert_allclose(got[live], want[live], rtol=1e-5, atol=1e-5)
+    empty, released = got[0], got[-1]
+    np.testing.assert_array_equal(released, empty)
+    np.testing.assert_array_equal(empty, np.zeros_like(empty))
+
+
+@pytest.mark.parametrize("bs,max_blocks,steps", [
+    (16, 48, 8), (16, 4, 4), (16, 12, 4), (128, 6, 1), (48, 16, 1),
+    (4, 6, 2),
+])
+def test_live_steps_counts_the_walk(bs, max_blocks, steps):
+    """The step count the kernel walks and the engine's kv_blocks counter
+    reads: blocks per step cover 128 keys and divide the table; a step is
+    live while it starts at or before the last query and its first entry
+    is not the null block; numpy and jax agree."""
+    assert fd.blocks_per_step(bs, max_blocks) == steps
+    tokens = steps * bs
+    n_steps = max_blocks // steps
+    rows = np.arange(1, 1 + 4 * max_blocks, dtype=np.int32).reshape(
+        4, max_blocks)
+    rows[1, :] = kvc.NULL_BLOCK                  # released
+    rows[2, steps:] = kvc.NULL_BLOCK             # table ends after step 0
+    idx = np.array([0, 5 * tokens, 3 * tokens, tokens * n_steps - 1],
+                   np.int32)
+    want = np.array([1, 0, 1, n_steps])
+    np.testing.assert_array_equal(fd.live_steps(idx, 1, rows, bs), want)
+    np.testing.assert_array_equal(
+        np.asarray(fd.live_steps(jnp.asarray(idx), 1, jnp.asarray(rows), bs)),
+        want)
+    # a query block reaching into the next step makes it live
+    assert fd.live_steps(np.array([tokens - 1], np.int32), 2,
+                         rows[3:], bs)[0] == min(2, n_steps)
 
 
 @pytest.mark.parametrize("cols", [1, 3, 8])

@@ -446,6 +446,28 @@ TICK_ORDER = ["engine.admit", "engine.schedule", "engine.stage",
               "engine.commit"]
 
 
+def _kv_blocks(eng):
+    """Pool blocks the flash-decode kernel fetches in a decode step: per
+    slot, whole steps of ``blocks_per_step`` table columns while a step
+    starts at or before the row's last key and its first entry is not the
+    null block, each step clipped at the row's table."""
+    from repro.kernels.flash_decode import blocks_per_step
+    from repro.serving.kv_cache import NULL_BLOCK
+
+    P = blocks_per_step(eng.block_size, eng.max_blocks_per_slot)
+    total = 0
+    for slot, row in enumerate(eng.tables.table):
+        r = eng.scheduler.slots[slot]
+        keys = 0 if r is None else (
+            r.length if r.out_tokens else r.prefilled + 1)
+        col = 0
+        while (col < len(row) and col * eng.block_size < keys
+               and row[col] != NULL_BLOCK):
+            total += min(P, len(row) - col)
+            col += P
+    return total
+
+
 def _recording_actions(eng):
     """Wrap the scheduler's next_action to log what each tick ran, as the
     dispatch span's metadata should carry it ({} for a tick that ran no
@@ -535,6 +557,41 @@ def test_engine_spans_reach_the_profiler(profiled_run):
     admits = [s[3] for s in spans if s[0] == "engine.admit"]
     assert sum(d["admitted"] for d in admits) == 3
     assert admits[0]["queued"] == 1              # 2 slots, 3 requests
+
+
+def test_decode_stage_counts_the_blocks_the_kernel_walks(tmp_path):
+    """kv_blocks, on the engine.stage span of each decode tick, is what the
+    flash-decode kernel fetches in that step: 8 blocks of 4 tokens per
+    32-key step here, so rows crossing 32 and 64 keys add a step, and an
+    idle slot with a null table adds none."""
+    import jax
+
+    from repro.kernels.flash_decode import blocks_per_step
+
+    cfg = configs.get_smoke(ARCH)
+    eng = Engine(cfg, slots=3, max_seq=96, block_size=4, max_chunk=16)
+    assert blocks_per_step(eng.block_size, eng.max_blocks_per_slot) == 8
+    eng.warmup()
+    want, run = [], eng._run_compiled
+
+    def run_compiled(key, fn, *args):
+        if key == "decode":
+            want.append(_kv_blocks(eng))
+        return run(key, fn, *args)
+
+    eng._run_compiled = run_compiled
+    rng = np.random.default_rng(5)
+    for n, new in ((29, 40), (6, 4)):
+        eng.submit(rng.integers(0, cfg.vocab, size=n), max_new=new)
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        eng.run()
+    finally:
+        jax.profiler.stop_trace()
+    got = [s[3]["kv_blocks"] for s in _host_spans(str(tmp_path))
+           if s[0] == "engine.stage" and "kv_blocks" in s[3]]
+    assert got == want
+    assert {8, 16, 24} <= set(got) and max(got) == 24
 
 
 def test_no_annotation_without_a_profile(traced_run, monkeypatch):

@@ -9,7 +9,9 @@ kernel of the main path at gemma3-1b widths (decode M=8, chunk M=64, the
 flash-decode cases take the smallest and largest split factor the decode
 tuner can bind (1 and 16; the split axis is outside every block's last two
 dims, so those between lay out alike), MQA and the GQA layout (8 kv heads,
-D=128), float and int8 KV pools, in the decode and prefill-chunk steps.
+D=128), float and int8 KV pools, in the decode and prefill-chunk steps; at
+mistral-nemo-12b's decode and 256-token chunk shapes they also check that
+the chip benchmark still tells the flash-decode kernel by its operands.
 
 Nothing runs: a pass here is a compile, not a chip run.  Only one process
 may load the TPU library at a time, so the topology is described inside a
@@ -120,8 +122,8 @@ def test_flash_attention_window_compiles(one_chip):
                          ids=["decode", "chunk64"])
 def test_flash_decode_compiles(one_chip, slots, sq, splits, hkv, groups, d,
                                kv_precision):
-    """64 table columns of 16-token blocks (max_seq 1024) per slot: the
-    serving shape whose analytic tuner winner is num_splits=16, in the
+    """64 table columns of 16-token blocks (max_seq 1024) per slot, walked
+    in eight 128-key steps, unsplit or split as far as they go, in the
     decode step (8 slots, one query each) and the prefill-chunk step (one
     slot, 64 queries)."""
     bs, max_blocks = 16, 64
@@ -140,3 +142,54 @@ def test_flash_decode_compiles(one_chip, slots, sq, splits, hkv, groups, d,
              (jnp.bfloat16, (slots, sq, hkv * groups, d)),
              (jnp.int32, (slots, max_blocks)), (jnp.int32, (slots,)),
              *[(x.dtype, x.shape) for x in leaves])
+
+
+def _kernel_of():
+    """benchmarks/chip/benchlib/trace.kernel_of, loaded by path: how the
+    chip benchmark tells the flash-decode kernel in a profile."""
+    import importlib.util
+    import os
+    import sys
+
+    path = os.path.join(os.path.dirname(__file__), "..", "benchmarks",
+                        "chip", "benchlib", "trace.py")
+    spec = importlib.util.spec_from_file_location("_bench_trace", path)
+    mod = importlib.util.module_from_spec(spec)
+    sys.modules[spec.name] = mod        # its dataclasses look it up there
+    spec.loader.exec_module(mod)
+    return mod.kernel_of
+
+
+@pytest.mark.parametrize("slots,sq", [(32, 1), (1, 256)],
+                         ids=["decode", "chunk256"])
+def test_flash_decode_compiles_at_nemo_shapes(one_chip, slots, sq):
+    """mistral-nemo-12b's serving shapes: 8 kv heads of 4 query heads at
+    D=128, 16-token blocks, 256 table columns (max_seq 4096); the decode
+    step (32 slots, one query each) and a 256-token prefill chunk.  The
+    compiled kernel still reads as "flash_decode" to the chip benchmark,
+    whose roofline metric would otherwise fall silent."""
+    hkv, groups, d, bs, max_blocks = 8, 4, 128, 16, 256
+    nb = 1 + 32 * max_blocks
+    pool = jax.eval_shape(lambda: kvc.init_paged_kv(
+        nb, bs, hkv, d, jnp.bfloat16))
+    leaves, treedef = jax.tree_util.tree_flatten(pool)
+
+    def step(q, bt, idx, *leaves):
+        cache = jax.tree_util.tree_unflatten(treedef, leaves)
+        return fd.flash_decode_attention(q, cache, bt, idx)
+
+    compiled = _compile(one_chip, step,
+                        (jnp.bfloat16, (slots, sq, hkv * groups, d)),
+                        (jnp.int32, (slots, max_blocks)),
+                        (jnp.int32, (slots,)),
+                        *[(x.dtype, x.shape) for x in leaves])
+    # The profile names an operation by its HLO text with operand shapes.
+    from jax._src.lib import xla_client
+
+    opts = xla_client._xla.HloPrintOptions.short_parsable()
+    opts.print_operand_shape = True
+    [module] = compiled.runtime_executable().hlo_modules()
+    kernel_of = _kernel_of()
+    calls = [line for line in module.to_string(opts).splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert [kernel_of(line) for line in calls] == ["flash_decode"]

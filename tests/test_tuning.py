@@ -18,6 +18,7 @@ from repro.core.generator import (
 )
 from repro.core.workloads import bert_base, resnet18, vit_b_16
 from repro.kernels import ops, ref
+from repro.kernels.flash_decode import FlashDecodeSpec
 from repro.kernels.registry import make_kernel, register_kernel, registered_kernels
 from repro import tuning
 
@@ -269,6 +270,55 @@ def test_wallclock_mode_interpret(tmp_path):
     out = ops.gemm(a, b, spec=res.spec, backend="interpret")
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref.gemm_ref(a, b)),
                                rtol=1e-6)
+
+
+# mistral-nemo-12b's decode attention as the chip benchmark serves it: 32
+# slots, 8 kv heads of 4 query heads, D=128, 16-token blocks, 256 columns.
+NEMO_DECODE = tuning.DecodeShape(slots=32, kv_heads=8, groups=4,
+                                 head_dim=128, sq=1, block_size=16,
+                                 max_blocks=256)
+
+
+@pytest.mark.parametrize("shape,steps", [
+    (NEMO_DECODE, 32),                                     # 8 blocks a step
+    (NEMO_DECODE._replace(block_size=128, max_blocks=32), 32),  # 1 a step
+    (NEMO_DECODE._replace(max_blocks=4), 1),               # one 64-key step
+])
+def test_decode_specs_split_the_kernel_steps(shape, steps):
+    """Split candidates are powers of two up to the kernel's step count
+    (128 keys a step) and 16, crossed with every fallback chunk width;
+    on one TensorCore every split only adds a program and a partial, so
+    the cost rises with the split factor at any chunk width."""
+    specs = tuning.enumerate_decode_specs(shape)
+    splits = sorted({s.num_splits for s in specs})
+    assert splits == [2 ** i for i in range(min(steps, 16).bit_length())]
+    for cols in {s.cols_per_iter for s in specs}:
+        costs = [tuning.predict_decode_cost(
+            FlashDecodeSpec(num_splits=n, cols_per_iter=cols), shape)
+            for n in splits]
+        assert costs == sorted(costs) and len(set(costs)) == len(costs)
+
+
+def test_decode_cost_prices_the_full_table_in_128_key_steps():
+    """The kernel term is slots x steps of 128 keys plus a per-step cost,
+    with each split's program and combine on top: doubling the table adds
+    exactly the steps' cost, whatever the split factor."""
+    spec = FlashDecodeSpec(num_splits=4, cols_per_iter=8)
+    half = NEMO_DECODE._replace(max_blocks=128)
+    step = 128 * 8 * 128 * 2 + 1000.0          # keys x rows x D x 2 + fixed
+    # the fallback term of 8-column chunks: iterations x 4000
+    fallback = (256 - 128) // 8 * 4000.0
+    grown = (tuning.predict_decode_cost(spec, NEMO_DECODE)
+             - tuning.predict_decode_cost(spec, half))
+    assert grown == 32 * 16 * step + fallback
+
+
+def test_decode_tuner_binds_one_split_at_nemo_shape(tmp_path):
+    """The analytic winner at Nemo's decode shape: one split (the v5e has
+    one TensorCore, so splits buy nothing) and 8-column fallback chunks."""
+    r = tuning.tune_decode(NEMO_DECODE, "bfloat16",
+                           tuner=_tuner(tmp_path, persist=False))
+    assert (r.spec.num_splits, r.spec.cols_per_iter) == (1, 8)
 
 
 @pytest.mark.parametrize("kind", ["gemm", "decode"])
