@@ -55,7 +55,8 @@ class Context:
     """What the metric readers (metrics/<name>.py) read."""
 
     cell: dict
-    dims: cells.ModelDims
+    arch: object                  # the configuration's arch/*.py module
+    dims: object                  # its arch.dims(raw)
     window: drive.Window
     peaks: work.Peaks
     setup_s: float
@@ -184,7 +185,7 @@ def run(args, root: str = cells.ROOT, chip_dir: str = cells.CHIP_DIR,
     spec = cells.load_traffic(cell["traffic"], chip_dir)
     arrivals = cells.traffic_kind(spec["kind"], chip_dir).generate(
         spec, args.seed, float(args.seconds), conf.dims.vocab)
-    cfg = system.arch_config(conf)
+    cfg = conf.arch.program_config(conf)
     phase("import_s")
     params = system.make_params(args.seed, conf, cfg)
     phase("weights_s")
@@ -223,7 +224,7 @@ def run(args, root: str = cells.ROOT, chip_dir: str = cells.CHIP_DIR,
         from benchlib import trace as T
 
         trace_red = T.reduce(T.load_dir(trace_dir), win)
-    ctx = Context(cell=cell, dims=conf.dims, window=win,
+    ctx = Context(cell=cell, arch=conf.arch, dims=conf.dims, window=win,
                   peaks=work.peaks_for(dev.device_kind) if require_chip
                   else work.PEAKS["TPU v5 lite"],
                   setup_s=setup_s, trace=trace_red)
@@ -244,10 +245,9 @@ def run(args, root: str = cells.ROOT, chip_dir: str = cells.CHIP_DIR,
             if r.submitted_t is not None and win.t_start <= r.due < win.t_end]
     del driver, engine, sample
     gc.collect()
-    from benchlib import reference
 
     t_ref = time.perf_counter()
-    all_gaps = reference.served_gaps(conf.dims, args.seed,
+    all_gaps = conf.arch.served_gaps(conf.dims, args.seed,
                                      [(p, s) for p, s, _ in check_in],
                                      control=args.control)
     ref_s = time.perf_counter() - t_ref

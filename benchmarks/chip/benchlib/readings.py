@@ -3,6 +3,7 @@
 Each function takes the run's Context and returns a number, or None where
 the run has nothing to read (no traced step of that kind, no request due):
 a share of a peak or a roofline is never reported as 0 for want of data.
+The work counts are the configuration's architecture module's (ctx.arch).
 """
 
 from __future__ import annotations
@@ -65,8 +66,8 @@ def prefill_mfu(ctx) -> Optional[float]:
     ts = _ticks(ctx, "prefill")
     if not ts:
         return None
-    flops = sum(work.prefill_chunk_flops(ctx.dims, t.tick.start, t.tick.chunk)
-                for t in ts)
+    flops = sum(ctx.arch.prefill_chunk_flops(ctx.dims, t.tick.start,
+                                             t.tick.chunk) for t in ts)
     return 100.0 * flops / (sum(t.program_s for t in ts) * ctx.peaks.flops)
 
 
@@ -74,15 +75,15 @@ def decode_mfu(ctx) -> Optional[float]:
     ts = _ticks(ctx, "decode")
     if not ts:
         return None
-    flops = sum(work.decode_flops(ctx.dims, t.tick.contexts) for t in ts)
+    flops = sum(ctx.arch.decode_flops(ctx.dims, t.tick.contexts) for t in ts)
     return 100.0 * flops / (sum(t.program_s for t in ts) * ctx.peaks.flops)
 
 
 def _step_gemms(ctx, tick):
     if tick.kind == "prefill":
-        return work.step_gemms(ctx.dims, tick.chunk, 1)
+        return ctx.arch.step_gemms(ctx.dims, tick.chunk, 1)
     rows = len(tick.contexts)
-    return work.step_gemms(ctx.dims, rows, rows)
+    return ctx.arch.step_gemms(ctx.dims, rows, rows)
 
 
 def gemm_roofline(ctx, kinds) -> Optional[float]:
@@ -98,6 +99,6 @@ def flash_decode_roofline(ctx) -> Optional[float]:
     ts = [t for t in _ticks(ctx, "decode") if t.decode_kernel_s > 0]
     if not ts:
         return None
-    least = sum(work.decode_attn_least_s(ctx.dims, t.tick.contexts, ctx.peaks)
-                for t in ts)
+    least = sum(ctx.arch.decode_attn_least_s(ctx.dims, t.tick.contexts,
+                                             ctx.peaks) for t in ts)
     return 100.0 * least / sum(t.decode_kernel_s for t in ts)

@@ -1,7 +1,9 @@
 """The system under test: the repository's serving Engine, built the way
 ``repro.launch.serve`` builds it, with the benchmark's own seeded weights.
 
-This is the only module of the benchmark that imports the program.
+This is the only module of the benchmark that imports the program.  An
+architecture module (arch/*.py) reaches the program only through
+`program_config`.
 """
 
 from __future__ import annotations
@@ -10,26 +12,19 @@ import dataclasses
 import functools
 
 import jax
-import jax.numpy as jnp
 
 from benchlib import weights as W
 from benchlib.cells import Config, CellError
 
 
-def arch_config(conf: Config):
-    """The program's ArchConfig for `conf`: the repository's published
-    configuration with the depth (and the published norm epsilon and rope
-    base) of the file; every width is checked against the file."""
+def program_config(conf: Config, fields: dict, want: dict):
+    """The program's ArchConfig for `conf`: the repository's configuration
+    `conf.repo_config` with `fields` (the depth and the published values
+    of the file) put in, then checked field by field against `want`, the
+    file's widths and features as the architecture module names them."""
     from repro import configs
 
-    m = conf.dims
-    cfg = dataclasses.replace(configs.get(conf.repo_config), n_layers=m.n_layers, norm_eps=m.norm_eps,
-                              rope_theta=m.rope_theta, dtype=m.dtype)
-    want = dict(d_model=m.d_model, n_heads=m.n_heads, n_kv_heads=m.n_kv_heads,
-                resolved_head_dim=m.head_dim, d_ff=m.d_ff, vocab=m.vocab,
-                qk_norm=m.qk_norm, tie_embeddings=False, qkv_bias=False,
-                family="dense", mlp_variant="swiglu", norm="rms",
-                post_block_norm=False, local_window=None, moe=None)
+    cfg = dataclasses.replace(configs.get(conf.repo_config), **fields)
     got = {k: getattr(cfg, k) for k in want}
     if got != want:
         diff = {k: (got[k], want[k]) for k in want if got[k] != want[k]}
@@ -39,44 +34,14 @@ def arch_config(conf: Config):
     return cfg
 
 
-def _program_tree(key, m, cfg):
-    """The program's parameter pytree (models/model.py layout: layers
-    stacked per scanned group, sub-layer i of group g is layer g*G + i)."""
-    dt = jnp.dtype(m.dtype)
-    G, n_groups = cfg.group_size, cfg.n_groups
-    shapes = W.layer_shapes(m)
-
-    def stacked(i, name):
-        return jnp.stack([W.layer_tensor(key, g * G + i, name, shapes[name], dt)
-                          for g in range(n_groups)])
-
-    blocks = {}
-    for i in range(G):
-        mixer = {n: stacked(i, n) for n in ("wq", "wk", "wv", "wo")}
-        if m.qk_norm:
-            mixer["q_norm"] = stacked(i, "q_norm")
-            mixer["k_norm"] = stacked(i, "k_norm")
-        blocks[f"sub{i}"] = {
-            "norm1": stacked(i, "attn_norm"),
-            "mixer": mixer,
-            "norm2": stacked(i, "mlp_norm"),
-            "ffn": {n: stacked(i, n) for n in ("w_gate", "w_up", "w_down")},
-        }
-    gs = W.global_shapes(m)
-    return {
-        "embed": W.global_tensor(key, "embed", gs["embed"], dt),
-        "final_norm": W.global_tensor(key, "final_norm", gs["final_norm"], dt),
-        "head": W.global_tensor(key, "head", gs["head"], dt),
-        "blocks": blocks,
-    }
-
-
 def make_params(seed: int, conf: Config, cfg):
     """Every weight, on the device, in the served dtype, from one jitted
-    call; its tree is checked against the program's own init."""
+    call of the architecture's `program_tree`; its tree is checked against
+    the program's own init."""
     from repro.models import model as M
 
-    build = jax.jit(functools.partial(_program_tree, m=conf.dims, cfg=cfg))
+    build = jax.jit(functools.partial(conf.arch.program_tree, m=conf.dims,
+                                      cfg=cfg))
     want = jax.eval_shape(lambda: M.init_model(jax.random.PRNGKey(0), cfg))
     got = jax.eval_shape(build, W.base_key(0))
     if jax.tree_util.tree_structure(got) != jax.tree_util.tree_structure(want):

@@ -1,29 +1,23 @@
-"""Seeded weights of a dense GQA decoder, made by the benchmark itself.
+"""Seeded weights, made by the benchmark itself, for every architecture.
 
 Every tensor is a pure function of (seed, layer, tensor name), so the
 program's copy (one jitted call on the device, in the served dtype) and the
 reference's copy (made again layer by layer after the window) are the same
-numbers without either reading the other's arrays.
+numbers without either reading the other's arrays.  A tensor's key folds
+into the seed's key 1000 + its layer and then its name's position in the
+architecture module's LAYER_TENSORS (GLOBAL_TENSORS for a tensor of no
+layer, folded in directly).
 
-Tensors per layer, with the shapes of the published checkpoints (x @ w):
-
-  attn_norm (d,)   wq (d, Hq*D)   wk, wv (d, Hkv*D)   wo (Hq*D, d)
-  q_norm, k_norm (D,)            [qk-norm models only]
-  mlp_norm (d,)    w_gate, w_up (d, F)   w_down (F, d)
-
-plus embed (V, d), final_norm (d,) and an untied head (d, V).  Norm weights
-are 1 + 0.1 N(0, 1), so a path that drops a norm weight is caught;
-projections are N(0, 1/fan_in); the embedding is N(0, 0.02^2).
+Norm weights are 1 + 0.1 N(0, 1), so a path that drops a norm weight is
+caught; the embedding is N(0, 0.02^2); every other tensor is a projection,
+N(0, 1/fan_in) with fan_in its first dimension, unless the module's
+`init(name)` gives a draw of its own.
 """
 
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-
-LAYER_TENSORS = ("attn_norm", "wq", "wk", "wv", "wo", "q_norm", "k_norm",
-                 "mlp_norm", "w_gate", "w_up", "w_down")
-GLOBAL_TENSORS = ("embed", "final_norm", "head")
 
 
 def base_key(seed: int):
@@ -34,24 +28,11 @@ def base_key(seed: int):
     return jax.random.fold_in(jax.random.PRNGKey(lo), hi)
 
 
-def layer_shapes(m) -> dict:
-    """name -> shape of one layer's tensors for model dims `m`
-    (a benchlib.cells.ModelDims)."""
-    d, D, F = m.d_model, m.head_dim, m.d_ff
-    shapes = {
-        "attn_norm": (d,), "wq": (d, m.n_heads * D),
-        "wk": (d, m.n_kv_heads * D), "wv": (d, m.n_kv_heads * D),
-        "wo": (m.n_heads * D, d), "mlp_norm": (d,),
-        "w_gate": (d, F), "w_up": (d, F), "w_down": (F, d),
-    }
-    if m.qk_norm:
-        shapes["q_norm"] = (D,)
-        shapes["k_norm"] = (D,)
-    return shapes
-
-
-def _make(key, name: str, shape, dtype):
-    if name.endswith("norm"):
+def _make(key, name: str, shape, dtype, init=None):
+    draw = init(name) if init is not None else None
+    if draw is not None:
+        w = draw(key, shape)
+    elif name.endswith("norm"):
         w = 1.0 + 0.1 * jax.random.normal(key, shape, jnp.float32)
     elif name == "embed":
         w = 0.02 * jax.random.normal(key, shape, jnp.float32)
@@ -60,17 +41,17 @@ def _make(key, name: str, shape, dtype):
     return w.astype(dtype)
 
 
-def layer_tensor(key, layer: int, name: str, shape, dtype=jnp.bfloat16):
+def layer_tensor(key, names, layer: int, name: str, shape,
+                 dtype=jnp.bfloat16, init=None):
+    """Tensor `name` of layer `layer`; `names` is the module's
+    LAYER_TENSORS and `init` its optional init."""
     k = jax.random.fold_in(jax.random.fold_in(key, 1000 + layer),
-                           LAYER_TENSORS.index(name))
-    return _make(k, name, shape, dtype)
+                           names.index(name))
+    return _make(k, name, shape, dtype, init)
 
 
-def global_tensor(key, name: str, shape, dtype=jnp.bfloat16):
-    return _make(jax.random.fold_in(key, GLOBAL_TENSORS.index(name)),
-                 name, shape, dtype)
-
-
-def global_shapes(m) -> dict:
-    return {"embed": (m.vocab, m.d_model), "final_norm": (m.d_model,),
-            "head": (m.d_model, m.vocab)}
+def global_tensor(key, names, name: str, shape, dtype=jnp.bfloat16,
+                  init=None):
+    """Tensor `name` of no layer; `names` is the module's GLOBAL_TENSORS."""
+    return _make(jax.random.fold_in(key, names.index(name)),
+                 name, shape, dtype, init)

@@ -59,7 +59,7 @@ def main(argv=None) -> int:
     spec = cells.load_traffic(cell["traffic"])
     if args.schedule_seed is not None:
         spec = dict(spec, schedule_seed=args.schedule_seed)
-    cfg = system.arch_config(conf)
+    cfg = conf.arch.program_config(conf)
     seeds = [int(s) for s in args.seeds.split(",")]
     params = system.make_params(seeds[0], conf, cfg)
     warm = system.make_engine(conf, cfg, params)
@@ -77,8 +77,8 @@ def main(argv=None) -> int:
             arrivals = kind.generate(s, seed, args.seconds, conf.dims.vocab)
             win = drive.Driver(engine, arrivals, system.request_spec).run(
                 warm_in_s=float(s["warm_in_s"]), seconds=args.seconds)
-            ctx = harness.Context(cell=cell, dims=conf.dims, window=win,
-                                  peaks=None, setup_s=0.0)
+            ctx = harness.Context(cell=cell, arch=conf.arch, dims=conf.dims,
+                                  window=win, peaks=None, setup_s=0.0)
             mid = (win.t_start + win.t_end) / 2
             due = [r for r in win.records if win.t_start <= r.due < win.t_end]
             half = [stats.percentile(stats.ttft_samples(win.records, a, b), 50)

@@ -25,11 +25,10 @@ def test_depth_alone_is_cut(name):
 
 @pytest.mark.parametrize("name", NAMES)
 def test_program_config_matches_the_file(name):
-    from benchlib import system
     from repro import configs
 
     conf = cells.load_config(name)
-    cfg = system.arch_config(conf)
+    cfg = conf.arch.program_config(conf)
     full = configs.get(conf.repo_config)
     for k in ("d_model", "n_heads", "n_kv_heads", "d_ff", "vocab",
               "resolved_head_dim", "qk_norm"):

@@ -6,10 +6,11 @@ import numpy as np
 
 from benchlib import cells, harness, reference
 
-DIMS = cells.ModelDims(name="tiny", n_layers=2, d_model=64, n_heads=4,
-                       n_kv_heads=2, head_dim=16, d_ff=128, vocab=256,
-                       norm_eps=1e-5, rope_theta=1e6, qk_norm=False,
-                       dtype="bfloat16")
+DENSE = cells.arch_module("mistral")
+DIMS = DENSE.Dims(name="tiny", n_layers=2, d_model=64, n_heads=4,
+                  n_kv_heads=2, head_dim=16, d_ff=128, vocab=256,
+                  norm_eps=1e-5, rope_theta=1e6, qk_norm=False,
+                  dtype="bfloat16")
 
 
 def _requests(rng, served):
@@ -19,10 +20,10 @@ def _requests(rng, served):
 
 def test_served_lengths_in_one_bucket_compile_once():
     rng = np.random.default_rng(0)
-    reference.served_gaps(DIMS, 3, _requests(rng, [37]), control="int8")
+    DENSE.served_gaps(DIMS, 3, _requests(rng, [37]), control="int8")
     compiles = harness.CompileCounter()
-    got = reference.served_gaps(DIMS, 3, _requests(rng, [90, 5]),
-                                control="int8")
+    got = DENSE.served_gaps(DIMS, 3, _requests(rng, [90, 5]),
+                            control="int8")
     assert compiles.n == 0
     assert [g.size for g in got["served"]] == [90, 5]
     assert [g.size for g in got["int8"]] == [90, 5]

@@ -77,7 +77,7 @@ def test_shares_stay_under_100(recorded):
     ev, win = recorded
 
     class Ctx:
-        dims = conftest_dims()
+        arch, dims = conftest_arch()
         peaks = work.PEAKS["TPU v5 lite"]
         trace = trace.reduce(ev, win)
 
@@ -91,6 +91,26 @@ def test_shares_stay_under_100(recorded):
         1e3 * sum(t.span_s - t.busy_s for t in Ctx.trace.ticks) / 6)
 
 
-def conftest_dims():
+def conftest_arch():
     from benchlib import cells
-    return cells.load_config("mistral-nemo-12b-d8").dims
+    conf = cells.load_config("mistral-nemo-12b-d8")
+    return conf.arch, conf.dims
+
+
+def test_readings_are_pinned(recorded):
+    """The work counts behind each share read on the recorded ticks, as
+    they read before the counts moved into arch/dense_gqa.py."""
+    ev, win = recorded
+
+    class Ctx:
+        arch, dims = conftest_arch()
+        peaks = work.PEAKS["TPU v5 lite"]
+        trace = trace.reduce(ev, win)
+
+    assert readings.decode_mfu(Ctx) == 0.8729904837155593
+    assert readings.prefill_mfu(Ctx) == 2.0934230960574345
+    assert readings.flash_decode_roofline(Ctx) == 3.293768358566829
+    assert readings.gemm_roofline(Ctx, ("prefill",)) == 16.949313408526706
+    assert readings.gemm_roofline(Ctx, ("decode",)) == 44.54020926712777
+    assert readings.gemm_roofline(Ctx, ("prefill", "decode")) == \
+        24.48756168349558

@@ -1,11 +1,14 @@
-"""Operation and byte counts against hand counts for both configurations."""
+"""Operation and byte counts against hand counts for both configurations:
+the dense module's (arch/dense_gqa.py) and work.py's GeMM roofline."""
 
 import pytest
 
 from benchlib import cells, work
 
-NEMO = cells.load_config("mistral-nemo-12b-d8").dims
+NEMO_CONF = cells.load_config("mistral-nemo-12b-d8")
+NEMO = NEMO_CONF.dims
 QWEN = cells.load_config("qwen3-14b-d8").dims
+DENSE = NEMO_CONF.arch
 V5E = work.PEAKS["TPU v5 lite"]
 
 # Hand counts from the published widths (8 layers):
@@ -32,8 +35,8 @@ def test_decode_flops(name):
     m, layer, head = HAND[name]
     attn_per_key = 4 * m.n_heads * 128 * 8
     want = 2 * (8 * layer + head) * 2 + attn_per_key * (100 + 200)
-    assert work.decode_flops(m, [100, 200]) == want
-    assert work.decode_flops(m, []) == 0
+    assert DENSE.decode_flops(m, [100, 200]) == want
+    assert DENSE.decode_flops(m, []) == 0
 
 
 @pytest.mark.parametrize("name", sorted(HAND))
@@ -42,13 +45,13 @@ def test_prefill_chunk_flops(name):
     # Tokens at positions 1000..1511 attend over 1001..1512 keys.
     keys = sum(range(1001, 1513))
     want = 2 * 8 * layer * 512 + 4 * m.n_heads * 128 * 8 * keys + 2 * head
-    assert work.prefill_chunk_flops(m, 1000, 512) == want
+    assert DENSE.prefill_chunk_flops(m, 1000, 512) == want
 
 
 @pytest.mark.parametrize("name", sorted(HAND))
 def test_step_gemms_cover_the_weights(name):
     m, layer, head = HAND[name]
-    shapes = work.step_gemms(m, 512, 1)
+    shapes = DENSE.step_gemms(m, 512, 1)
     assert len(shapes) == 7 * 8 + 1
     assert sum(k * n for _, k, n in shapes) == 8 * layer + head
     assert shapes[-1] == (1, 5120, m.vocab)
@@ -70,7 +73,7 @@ def test_decode_attention_bytes():
     kv = 2 * (1000 + 3000) * 8 * 128
     qo = 2 * 2 * 32 * 128
     want = 2 * (kv + qo) * 8 / 819e9
-    assert work.decode_attn_least_s(NEMO, [1000, 3000], V5E) == \
+    assert DENSE.decode_attn_least_s(NEMO, [1000, 3000], V5E) == \
         pytest.approx(want)
 
 
