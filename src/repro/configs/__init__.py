@@ -23,6 +23,7 @@ _ARCH_MODULES = {
     "arctic-480b": "repro.configs.arctic_480b",
     "paligemma-3b": "repro.configs.paligemma_3b",
     "jamba-1.5-large-398b": "repro.configs.jamba_1_5_large",
+    "granite-4.0-h-small": "repro.configs.granite_4_0_h_small",
     "xlstm-1.3b": "repro.configs.xlstm_1_3b",
     # The paper's own transformer benchmark backbones (Table 2):
     "bert-base": "repro.configs.bert_base",

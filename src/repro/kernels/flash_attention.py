@@ -81,11 +81,12 @@ def flash_attention(
     block_q: int = 512,
     block_kv: int = 512,
     interpret: bool = False,
+    scale: Optional[float] = None,          # softmax scale; None: D ** -0.5
 ) -> jax.Array:
     B, Sq, Hq, D = q.shape
     Skv, Hkv = k.shape[1], k.shape[2]
     groups = Hq // Hkv
-    scale = D ** -0.5
+    scale = D ** -0.5 if scale is None else scale
 
     block_q = min(block_q, Sq)
     block_kv = min(block_kv, Skv)

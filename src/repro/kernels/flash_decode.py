@@ -300,8 +300,10 @@ def flash_decode_attention(
     window: Optional[int] = None,
     spec: Optional[FlashDecodeSpec] = None,
     interpret: bool = False,
+    scale: Optional[float] = None,
 ) -> jax.Array:
-    """Decode attention over the paged pool via the Pallas kernel.
+    """Decode attention over the paged pool via the Pallas kernel; `scale`
+    is the softmax scale (None: D ** -0.5).
 
     The pools stay in HBM in their ``(num_blocks, Hkv, bs, D)`` layout; each
     (row, split) program DMAs its live steps' blocks, by table entry, into
@@ -371,7 +373,8 @@ def flash_decode_attention(
 
     kernel = functools.partial(
         _decode_kernel, steps_per_split=sps, blocks=P, block_size=bs, sq=Sq,
-        scale=D ** -0.5, window=window, seq_cap=seq_cap, quantized=quantized,
+        scale=D ** -0.5 if scale is None else scale, window=window,
+        seq_cap=seq_cap, quantized=quantized,
     )
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
@@ -410,6 +413,7 @@ def ref_paged_decode(
     *,
     window: Optional[int] = None,
     cols_per_iter: int = 8,
+    scale: Optional[float] = None,
 ) -> jax.Array:
     """Online-softmax decode over block-table column chunks, bounded at run
     time to the max active length across slots.
@@ -436,7 +440,8 @@ def ref_paged_decode(
     if idx.ndim == 0:
         idx = jnp.broadcast_to(idx, (B,))
 
-    qf = (q.astype(jnp.float32) * (D ** -0.5)).reshape(B, Sq, Hkv, groups, D)
+    scale = D ** -0.5 if scale is None else scale
+    qf = (q.astype(jnp.float32) * scale).reshape(B, Sq, Hkv, groups, D)
     qf = qf.transpose(0, 2, 3, 1, 4)                       # (B, H, G, Sq, D)
     qpos = idx[:, None] + jnp.arange(Sq, dtype=jnp.int32)[None, :]  # (B, Sq)
     # Tokens any slot can attend this step; the loop stops past it.
@@ -546,7 +551,7 @@ def _resolve_backend(backend: Optional[str]) -> str:
 
 
 def _gather_decode(q, cache, block_tables, index, *, window=None,
-                   prefix_len: int = 0):
+                   prefix_len: int = 0, scale=None):
     """The legacy path: materialize the slot views, dense softmax over the
     full table extent.  Kept as the benchmark baseline and the
     ``prefix_len`` fallback (bidirectional prefixes never page in practice —
@@ -555,7 +560,7 @@ def _gather_decode(q, cache, block_tables, index, *, window=None,
 
     k, v = gather_kv(cache, block_tables)
     return decode_attention(q, k, v, index=index, window=window,
-                            prefix_len=prefix_len)
+                            prefix_len=prefix_len, scale=scale)
 
 
 def paged_decode_attention(
@@ -568,24 +573,27 @@ def paged_decode_attention(
     prefix_len: int = 0,
     backend: Optional[str] = None,
     spec: Optional[FlashDecodeSpec] = None,
+    scale: Optional[float] = None,
 ) -> jax.Array:
     """Decode attention over a paged KV cache — the dispatch entry the model
     layer calls.  Equivalent to ``gather_kv`` + ``decode_attention`` for
     every backend (tested in tests/test_flash_decode.py); they differ only
-    in how much pool they touch."""
+    in how much pool they touch.  `scale` is the softmax scale (None:
+    D ** -0.5)."""
     if prefix_len:
         return _gather_decode(q, cache, block_tables, index, window=window,
-                              prefix_len=prefix_len)
+                              prefix_len=prefix_len, scale=scale)
     b = _resolve_backend(backend)
     spec = spec or _DECODE_SPEC or FlashDecodeSpec()
     if b == "gather":
-        return _gather_decode(q, cache, block_tables, index, window=window)
+        return _gather_decode(q, cache, block_tables, index, window=window,
+                              scale=scale)
     if b == "blocked":
         return ref_paged_decode(q, cache, block_tables, index, window=window,
-                                cols_per_iter=spec.cols_per_iter)
+                                cols_per_iter=spec.cols_per_iter, scale=scale)
     return flash_decode_attention(q, cache, block_tables, index,
                                   window=window, spec=spec,
-                                  interpret=(b == "interpret"))
+                                  interpret=(b == "interpret"), scale=scale)
 
 
 def make_flash_decode(spec: FlashDecodeSpec, *, interpret: bool = False):

@@ -43,13 +43,21 @@ def preset_config(arch: str, preset: str):
         )
         if cfg.family == "hybrid":
             kw["attn_every"] = 4
+            kw["attn_offset"] = None
             kw["group_size"] = 4
+        if cfg.mamba and cfg.mamba.mamba2:
+            kw["mamba"] = dataclasses.replace(
+                cfg.mamba, n_heads=2 * 512 // cfg.mamba.head_dim)
+        if cfg.embedding_multiplier == cfg.d_model ** 0.5:
+            kw["embedding_multiplier"] = 512 ** 0.5     # Gemma's sqrt(d)
         if cfg.family == "ssm":
             kw["slstm_every"] = 4
             kw["group_size"] = 4
             kw["d_ff"] = 0
         if cfg.moe:
-            kw["moe"] = dataclasses.replace(cfg.moe, num_experts=8, d_ff_expert=1024)
+            kw["moe"] = dataclasses.replace(cfg.moe, num_experts=8, d_ff_expert=1024,
+                                            top_k=min(cfg.moe.top_k, 8),
+                                            first_expert=0, experts_held=None)
         if cfg.local_ratio:
             kw["group_size"] = cfg.local_ratio + 1
             kw["n_layers"] = 2 * (cfg.local_ratio + 1)

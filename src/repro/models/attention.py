@@ -88,11 +88,13 @@ def blockwise_attention(
     prefix_len: int = 0,
     block_kv: int = 1024,
     softcap: Optional[float] = None,
+    scale: Optional[float] = None,
 ) -> jax.Array:
     """Rematerialized flash-style attention: the KV-block scan's residuals
     are never saved for backward (jax.checkpoint below) — without this, a
     4k-token training step keeps O(S^2 / block) probability tensors alive
-    per layer and blows per-device HBM.
+    per layer and blows per-device HBM.  `scale` is the softmax scale
+    (None: D ** -0.5).
 
     On TPU the fused Pallas kernel (kernels/flash_attention.py) takes over
     whenever its feature set suffices — it keeps scores/probabilities in
@@ -105,19 +107,21 @@ def blockwise_attention(
             and plain_offset and not prefix_len and softcap is None):
         from repro.kernels.flash_attention import flash_attention
 
-        f = functools.partial(flash_attention, causal=causal, window=window)
+        f = functools.partial(flash_attention, causal=causal, window=window,
+                              scale=scale)
         return jax.checkpoint(lambda a, b, c: f(a, b, c))(q, k, v)
 
     f = functools.partial(
         _blockwise_attention,
         causal=causal, window=window, prefix_len=prefix_len,
-        block_kv=block_kv, softcap=softcap,
+        block_kv=block_kv, softcap=softcap, scale=scale,
     )
     return jax.checkpoint(f)(q, k, v, q_offset)
 
 
 def _blockwise_attention(
     q, k, v, q_offset, *, causal, window, prefix_len, block_kv, softcap,
+    scale=None,
 ) -> jax.Array:
     """Online-softmax attention scanning KV blocks.
 
@@ -128,7 +132,7 @@ def _blockwise_attention(
     B, Sq, Hq, D = q.shape
     Skv, Hkv = k.shape[1], k.shape[2]
     groups = Hq // Hkv
-    scale = D ** -0.5
+    scale = D ** -0.5 if scale is None else scale
 
     block_kv = min(block_kv, Skv)
     n_blocks = -(-Skv // block_kv)
@@ -200,6 +204,7 @@ def decode_attention(
     index: jax.Array,
     window: Optional[int] = None,
     prefix_len: int = 0,
+    scale: Optional[float] = None,
 ) -> jax.Array:
     """Query-over-whole-cache attention, no KV-block scan.
 
@@ -217,7 +222,8 @@ def decode_attention(
     B, Sq, Hq, D = q.shape
     Skv, Hkv = k.shape[1], k.shape[2]
     groups = Hq // Hkv
-    qf = (q * jnp.asarray(D ** -0.5, q.dtype)).reshape(B, Sq, Hkv, groups, D)
+    scale = D ** -0.5 if scale is None else scale
+    qf = (q * jnp.asarray(scale, q.dtype)).reshape(B, Sq, Hkv, groups, D)
     s = jnp.einsum(
         "bqhgd,bkhd->bhgqk", qf, k, preferred_element_type=jnp.float32
     )  # (B, Hkv, G, Sq, Skv)
@@ -264,7 +270,9 @@ def attention(
     """
     cross = kv_src is not None
     src = kv_src if cross else x
-    q, k, v = _project_qkv(x, src, p, cfg, positions, rope=not cross)
+    q, k, v = _project_qkv(x, src, p, cfg, positions,
+                           rope=not (cross or cfg.nope))
+    scale = cfg.attention_multiplier       # None: head_dim ** -0.5
 
     if cache is not None and not cross:
         from repro.serving import kv_cache as paged
@@ -280,7 +288,7 @@ def attention(
             new_cache = paged.write_kv(cache, block_tables, k, v, cache_index)
             out = _fd.paged_decode_attention(
                 q, new_cache, block_tables, cache_index,
-                window=window, prefix_len=prefix_len,
+                window=window, prefix_len=prefix_len, scale=scale,
             )
         else:
             # Dense decode: append this step's k/v then attend over the cache.
@@ -289,7 +297,7 @@ def attention(
             new_cache = KVCache(k_cache, v_cache)
             out = decode_attention(
                 q, k_cache, v_cache, index=cache_index,
-                window=window, prefix_len=prefix_len,
+                window=window, prefix_len=prefix_len, scale=scale,
             )
     else:
         new_cache = None
@@ -299,7 +307,7 @@ def attention(
             new_cache = cache
         out = blockwise_attention(
             q, k, v, causal=causal and not cross, window=window,
-            prefix_len=prefix_len, softcap=cfg.logit_softcap,
+            prefix_len=prefix_len, softcap=cfg.logit_softcap, scale=scale,
         )
 
     B, Sq = x.shape[:2]

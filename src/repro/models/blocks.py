@@ -36,6 +36,12 @@ MIXER_SCOPE = {"attn": "attn", "attn_local": "attn", "mamba": "mamba",
                "mlstm": "mlstm", "slstm": "slstm"}
 
 
+def _residual(x, h, cfg):
+    """x + residual_multiplier * h (granite's 0.22; 1 elsewhere)."""
+    r = cfg.residual_multiplier
+    return x + (h if r == 1.0 else h * jnp.asarray(r, h.dtype))
+
+
 def _layer_uses_moe(cfg, layer_idx: int) -> bool:
     return cfg.moe is not None and (layer_idx + 1) % cfg.moe_every == 0
 
@@ -121,7 +127,7 @@ def apply_block(
                                            collect_states=collect_states)
         if cfg.post_block_norm:
             h = _norm(h, p["post_norm1"], cfg)
-        x = x + h
+        x = _residual(x, h, cfg)
 
     if "cross" in p:
         with jax.named_scope("attn"):
@@ -131,7 +137,7 @@ def apply_block(
                 kv_src=encoder_out if cross_cache is None else h,  # decode
                 cache=cross_cache, cache_index=None,
             )
-            x = x + h
+            x = _residual(x, h, cfg)
 
     if "ffn" in p:
         moe = "router" in p["ffn"]
@@ -143,7 +149,7 @@ def apply_block(
                 h = layers.mlp(h, p["ffn"], cfg.mlp_variant)
             if cfg.post_block_norm:
                 h = _norm(h, p["post_norm2"], cfg)
-            x = x + h
+            x = _residual(x, h, cfg)
     return x, new_cache
 
 

@@ -23,6 +23,28 @@ class MoEConfig:
     capacity_factor: float = 1.25
     # Snowflake Arctic: dense FFN residual in parallel with the MoE FFN.
     dense_residual: bool = False
+    # Granite 4.0-H: a shared SwiGLU expert of this width beside the routed
+    # ones, computed for every token (0: none).
+    shared_d_ff: int = 0
+    # Expert parallelism: this chip holds experts first_expert ..
+    # first_expert + experts_held - 1 of the router's num_experts (None: all)
+    # and computes only their part of the layer; the router still scores
+    # every expert and keeps its top_k.
+    first_expert: int = 0
+    experts_held: Optional[int] = None
+    # No routed (token, expert) pair is ever dropped: every held expert has
+    # room for every token (capacity_factor is then unused).
+    drop_free: bool = False
+
+    @property
+    def held(self) -> int:
+        return self.num_experts if self.experts_held is None else self.experts_held
+
+    def __post_init__(self):
+        if not 0 <= self.first_expert <= self.first_expert + self.held <= self.num_experts:
+            raise ValueError(
+                f"experts {self.first_expert}..{self.first_expert + self.held - 1} "
+                f"are not among the router's {self.num_experts}")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -31,9 +53,27 @@ class MambaConfig:
     d_conv: int = 4
     expand: int = 2
     dt_rank: Optional[int] = None  # default ceil(d_model / 16)
+    # Mamba-2 (SSD) when n_heads > 0: d_inner = expand * d_model splits into
+    # n_heads heads of head_dim, each with one scalar decay; B and C are
+    # shared by the heads of each of n_groups groups; prefill runs the
+    # chunked (SSD) form in chunks of chunk_size tokens.
+    n_heads: int = 0
+    head_dim: int = 64
+    n_groups: int = 1
+    chunk_size: int = 256
 
     def resolved_dt_rank(self, d_model: int) -> int:
         return self.dt_rank or -(-d_model // 16)
+
+    @property
+    def mamba2(self) -> bool:
+        return self.n_heads > 0
+
+    def conv_dim(self, d_model: int) -> int:
+        """Channels of the causal conv: x alone (Mamba-1), or x, B and C
+        (Mamba-2)."""
+        di = self.expand * d_model
+        return di + 2 * self.n_groups * self.d_state if self.mamba2 else di
 
 
 @dataclasses.dataclass(frozen=True)
@@ -64,8 +104,11 @@ class ArchConfig:
     moe: Optional[MoEConfig] = None
     moe_every: int = 1
 
-    # hybrid (jamba): one attention layer per `attn_every` layers, rest Mamba
+    # hybrid (jamba): one attention layer per `attn_every` layers, rest Mamba;
+    # attention sits at offset `attn_offset` of each period (None: last, as in
+    # Jamba; granite 4.0-H: 5 of every 10)
     attn_every: int = 0
+    attn_offset: Optional[int] = None
     mamba: Optional[MambaConfig] = None
 
     # ssm (xlstm): mLSTM blocks with one sLSTM per `slstm_every`
@@ -84,6 +127,16 @@ class ArchConfig:
     post_block_norm: bool = False           # gemma-style post norms
     tie_embeddings: bool = False
 
+    # multipliers (granite): x = embed[tok] * embedding_multiplier; every
+    # residual add is x + residual_multiplier * sublayer(x); the softmax scale
+    # is attention_multiplier (None: head_dim ** -0.5); logits are divided by
+    # logits_scaling.  `nope` drops the rotary embedding (no positions).
+    embedding_multiplier: float = 1.0
+    attention_multiplier: Optional[float] = None
+    residual_multiplier: float = 1.0
+    logits_scaling: float = 1.0
+    nope: bool = False
+
     # numerics
     dtype: str = "bfloat16"
     remat: bool = True                      # activation checkpointing per group
@@ -96,6 +149,8 @@ class ArchConfig:
             raise ValueError("n_heads must be divisible by n_kv_heads")
         if self.family == "hybrid" and not (self.attn_every and self.mamba):
             raise ValueError("hybrid needs attn_every and mamba config")
+        if self.attn_offset is not None and not 0 <= self.attn_offset < self.attn_every:
+            raise ValueError("attn_offset must lie in [0, attn_every)")
         if self.local_ratio and not self.local_window:
             raise ValueError("local_ratio needs local_window")
 
@@ -131,8 +186,10 @@ class ArchConfig:
                 else:
                     kinds.append("mlstm")
             elif self.family == "hybrid":
-                # Jamba: attention once per attn_every, rest Mamba.
-                kinds.append("attn" if (i + 1) % self.attn_every == 0 else "mamba")
+                # Attention once per attn_every (at attn_offset), rest Mamba.
+                off = (self.attn_every - 1 if self.attn_offset is None
+                       else self.attn_offset)
+                kinds.append("attn" if i % self.attn_every == off else "mamba")
             elif self.local_ratio:
                 # Gemma3: local_ratio local layers then one global.
                 kinds.append(
@@ -153,7 +210,8 @@ class ArchConfig:
         if self.moe:
             moe = (
                 d * self.moe.num_experts
-                + self.moe.num_experts * 3 * d * self.moe.d_ff_expert
+                + self.moe.held * 3 * d * self.moe.d_ff_expert
+                + 3 * d * self.moe.shared_d_ff
             )
             if self.moe.dense_residual:
                 moe += ffn
@@ -165,7 +223,13 @@ class ArchConfig:
 
         mixer = {}
         mixer["attn"] = mixer["attn_local"] = attn
-        if self.mamba:
+        if self.mamba and self.mamba.mamba2:
+            mc = self.mamba
+            di, H = mc.expand * d, mc.n_heads
+            cd = mc.conv_dim(d)
+            mixer["mamba"] = (
+                d * (di + cd + H) + mc.d_conv * cd + cd + 3 * H + di + di * d)
+        elif self.mamba:
             di = self.mamba.expand * d
             dtr = self.mamba.resolved_dt_rank(d)
             mixer["mamba"] = (
@@ -193,7 +257,8 @@ class ArchConfig:
         if not self.moe:
             return self.param_count()
         total = self.param_count()
-        expert_p = self.moe.num_experts * 3 * self.d_model * self.moe.d_ff_expert
-        active_p = self.moe.top_k * 3 * self.d_model * self.moe.d_ff_expert
+        expert_p = self.moe.held * 3 * self.d_model * self.moe.d_ff_expert
+        active_p = (min(self.moe.top_k, self.moe.held)
+                    * 3 * self.d_model * self.moe.d_ff_expert)
         n_moe_layers = self.n_layers // self.moe_every
         return total - n_moe_layers * (expert_p - active_p)

@@ -60,8 +60,12 @@ def init_embedding(key, vocab: int, d: int, dtype) -> jax.Array:
     return (jax.random.normal(key, (vocab, d), jnp.float32) * 0.02).astype(dtype)
 
 
-def embed(tokens: jax.Array, table: jax.Array) -> jax.Array:
+def embed(tokens: jax.Array, table: jax.Array,
+          multiplier: float = 1.0) -> jax.Array:
+    """table[tokens] * multiplier (Gemma's sqrt(d), granite's 12)."""
     x = jnp.take(table, tokens, axis=0)
+    if multiplier != 1.0:
+        x = x * jnp.asarray(multiplier, x.dtype)
     return shard(x, "batch", "seq", "embed")
 
 

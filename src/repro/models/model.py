@@ -101,10 +101,7 @@ def forward(
     vocab is ~TBs and is never needed — only the next-token logits are)."""
     tokens = batch["tokens"]
     B, S = tokens.shape
-    x = layers.embed(tokens, params["embed"])
-    if cfg.tie_embeddings:
-        # Gemma-style embedding scaling balances tied input/output tables.
-        x = x * jnp.asarray(cfg.d_model ** 0.5, x.dtype)
+    x = layers.embed(tokens, params["embed"], cfg.embedding_multiplier)
 
     prefix_len = 0
     encoder_out = None
@@ -143,12 +140,14 @@ def _unembed(x, params, cfg):
     # in w8a8 mode would re-quantize the (vocab x d) table every decode step.
     if "head_q" in params:
         logits = layers.dense(x, params["head_q"])
-        return shard(logits, "batch", "seq", "vocab")
-    if cfg.tie_embeddings:
+        logits = shard(logits, "batch", "seq", "vocab")
+    elif cfg.tie_embeddings:
         logits = layers.unembed(x, params["embed"])
     else:
         logits = layers.dense(x, params["head"])
         logits = shard(logits, "batch", "seq", "vocab")
+    if cfg.logits_scaling != 1.0:
+        logits = logits / jnp.asarray(cfg.logits_scaling, logits.dtype)
     return logits
 
 
@@ -156,9 +155,7 @@ def trunk(params, cfg: ArchConfig, batch: Dict[str, jax.Array]) -> jax.Array:
     """Final hidden states (B, S, d) before the unembedding."""
     tokens = batch["tokens"]
     B, S = tokens.shape
-    x = layers.embed(tokens, params["embed"])
-    if cfg.tie_embeddings:
-        x = x * jnp.asarray(cfg.d_model ** 0.5, x.dtype)
+    x = layers.embed(tokens, params["embed"], cfg.embedding_multiplier)
     prefix_len = 0
     encoder_out = None
     positions = jnp.arange(S)
@@ -348,10 +345,7 @@ def _trunk_step(params, cfg, x, positions, caches, cache_index, block_tables,
 
 def _embed_tokens(params, cfg, tokens):
     with jax.named_scope("embed"):
-        x = layers.embed(tokens, params["embed"])
-        if cfg.tie_embeddings:
-            x = x * jnp.asarray(cfg.d_model ** 0.5, x.dtype)
-        return shard(x, "batch", "seq", "embed")
+        return layers.embed(tokens, params["embed"], cfg.embedding_multiplier)
 
 
 def paged_decode_step(

@@ -3,12 +3,19 @@
 Dispatch strategy (compile-friendly at 128 experts x 1M tokens):
   * router logits -> top_k -> softmax over the selected experts,
   * position-in-expert via a cumulative sum over the one-hot assignment,
-  * tokens scattered into a (E, capacity, d) buffer (drops beyond capacity),
+  * tokens scattered into a (E, capacity, d) buffer (drops beyond capacity;
+    `drop_free` gives every expert room for every token, so none drops),
   * expert FFNs run as one batched einsum over the expert dimension (sharded
     expert-parallel on the "model" mesh axis),
   * results gathered back and combined with routing weights.
 
-Arctic's dense-residual variant runs a small dense FFN in parallel and sums.
+Arctic's dense-residual variant runs a small dense FFN in parallel and sums;
+Granite's shared expert (`shared_d_ff`) is the same, at its own width.
+
+Under expert parallelism a chip holds only experts first_expert ..
+first_expert + experts_held - 1: the router still scores all of them and
+keeps its top_k, and the layer computes the part of the result that the
+held experts give (pairs routed elsewhere add nothing here).
 """
 
 from __future__ import annotations
@@ -25,23 +32,30 @@ from repro.parallel.logical import shard
 def init_moe(key, cfg):
     mc = cfg.moe
     d, E, ffe = cfg.d_model, mc.num_experts, mc.d_ff_expert
+    Eh = mc.held
     ks = jax.random.split(key, 5)
     dt = cfg.jax_dtype
     scale = d ** -0.5
     p = {
         "router": layers._init_dense(ks[0], d, E, jnp.float32),
-        "w_gate": (jax.random.normal(ks[1], (E, d, ffe)) * scale).astype(dt),
-        "w_up": (jax.random.normal(ks[2], (E, d, ffe)) * scale).astype(dt),
-        "w_down": (jax.random.normal(ks[3], (E, ffe, d)) * ffe ** -0.5).astype(dt),
+        "w_gate": (jax.random.normal(ks[1], (Eh, d, ffe)) * scale).astype(dt),
+        "w_up": (jax.random.normal(ks[2], (Eh, d, ffe)) * scale).astype(dt),
+        "w_down": (jax.random.normal(ks[3], (Eh, ffe, d)) * ffe ** -0.5).astype(dt),
     }
     if mc.dense_residual:
         p["dense"] = layers.init_mlp(ks[4], d, cfg.d_ff, "swiglu", dt)
+    if mc.shared_d_ff:
+        p["shared"] = layers.init_mlp(jax.random.fold_in(key, 5), d,
+                                      mc.shared_d_ff, "swiglu", dt)
     return p
 
 
 def _capacity(tokens: int, cfg) -> int:
     mc = cfg.moe
-    c = int(tokens * mc.top_k / mc.num_experts * mc.capacity_factor)
+    if mc.drop_free:
+        c = tokens            # top_k picks distinct experts: <= 1 pair a token
+    else:
+        c = int(tokens * mc.top_k / mc.num_experts * mc.capacity_factor)
     return max(8, -(-c // 8) * 8)
 
 
@@ -49,6 +63,7 @@ def moe_block(x: jax.Array, p, cfg, *, quant: Optional[str] = None) -> jax.Array
     B, S, d = x.shape
     mc = cfg.moe
     E, k = mc.num_experts, mc.top_k
+    first, Eh = mc.first_expert, mc.held
     T = B * S
     C = _capacity(T, cfg)
 
@@ -57,16 +72,19 @@ def moe_block(x: jax.Array, p, cfg, *, quant: Optional[str] = None) -> jax.Array
     gate_vals, expert_idx = jax.lax.top_k(logits, k)                 # (T, k)
     weights = jax.nn.softmax(gate_vals, axis=-1)                     # (T, k)
 
-    # Flatten (token, slot) pairs; earlier tokens win capacity slots.
-    flat_e = expert_idx.reshape(T * k)                               # (T*k,)
-    oh = jax.nn.one_hot(flat_e, E, dtype=jnp.int32)                  # (T*k, E)
+    # Flatten (token, slot) pairs; earlier tokens win capacity slots.  Pairs
+    # routed to experts this chip does not hold take no slot.
+    local = expert_idx.reshape(T * k) - first                        # (T*k,)
+    held = (local >= 0) & (local < Eh)
+    flat_e = jnp.where(held, local, 0)
+    oh = jax.nn.one_hot(flat_e, Eh, dtype=jnp.int32) * held[:, None]  # (T*k, Eh)
     pos = jnp.cumsum(oh, axis=0) - oh                                # pre-count
     pos_in_e = jnp.sum(pos * oh, axis=-1)                            # (T*k,)
-    keep = pos_in_e < C
+    keep = held & (pos_in_e < C)
     # Dropped pairs go to a sacrificial slot C (buffer has C+1 rows).
     slot = jnp.where(keep, pos_in_e, C)
 
-    buf = jnp.zeros((E, C + 1, d), x.dtype)
+    buf = jnp.zeros((Eh, C + 1, d), x.dtype)
     token_ids = jnp.repeat(jnp.arange(T), k)
     buf = buf.at[flat_e, slot].add(x2[token_ids])
     buf = shard(buf, "expert", None, None)[:, :C]                    # (E, C, d)
@@ -90,6 +108,8 @@ def moe_block(x: jax.Array, p, cfg, *, quant: Optional[str] = None) -> jax.Array
 
     if mc.dense_residual:
         y = y + layers.mlp(x, p["dense"], "swiglu", quant=quant)
+    if mc.shared_d_ff:
+        y = y + layers.mlp(x, p["shared"], "swiglu", quant=quant)
     return shard(y, "batch", "seq", "embed")
 
 

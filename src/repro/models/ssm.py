@@ -29,6 +29,8 @@ class MambaState(NamedTuple):
 
 
 def init_mamba(key, cfg):
+    if cfg.mamba.mamba2:
+        return init_mamba2(key, cfg)
     d = cfg.d_model
     mc = cfg.mamba
     di = mc.expand * d
@@ -88,7 +90,12 @@ def mamba_block(
     ``collect_states`` (requires a carried state) returns a MambaState with
     an extra position axis — h/conv *after every token* (B, S, ...) — so
     speculative verification can restore the state at any accepted position.
+
+    A Mamba-2 config (``cfg.mamba.n_heads > 0``) runs ``mamba2_block``.
     """
+    if cfg.mamba.mamba2:
+        return mamba2_block(x, p, cfg, state=state,
+                            collect_states=collect_states)
     B, S, d = x.shape
     mc = cfg.mamba
     di = mc.expand * d
@@ -171,12 +178,170 @@ def mamba_block(
     return shard(out, "batch", "seq", "embed"), (per_pos if collect_states else new_state)
 
 
-def init_mamba_state(cfg, batch: int) -> MambaState:
+def init_mamba_state(cfg, batch: int):
+    if cfg.mamba.mamba2:
+        return init_mamba2_state(cfg, batch)
     mc = cfg.mamba
     di = mc.expand * cfg.d_model
     return MambaState(
         h=jnp.zeros((batch, di, mc.d_state), jnp.float32),
         conv=jnp.zeros((batch, mc.d_conv - 1, di), cfg.jax_dtype),
+    )
+
+
+# ---------------------------------------------------------------------------
+# Mamba-2 (SSD: multi-head, one scalar decay per head, shared B/C)
+# ---------------------------------------------------------------------------
+
+class Mamba2State(NamedTuple):
+    h: jax.Array          # (B, n_heads, head_dim, d_state) SSM state, f32
+    conv: jax.Array       # (B, d_conv - 1, conv_dim) causal-conv tail of xBC
+
+
+def init_mamba2(key, cfg):
+    """Mamba-2 mixer weights (x @ w): in_proj (d, d_inner + conv_dim +
+    n_heads) giving z, xBC and dt; a depthwise conv over xBC with bias; per
+    head dt_bias, A_log and D; the gated norm's weight; out_proj."""
+    d = cfg.d_model
+    mc = cfg.mamba
+    di, H = mc.expand * d, mc.n_heads
+    if H * mc.head_dim != di:
+        raise ValueError(f"Mamba-2 heads {H} x {mc.head_dim} != d_inner {di}")
+    cd = mc.conv_dim(d)
+    ks = jax.random.split(key, 5)
+    dt = cfg.jax_dtype
+    # Mamba-2's init: dt ~ log-uniform on [0.001, 0.1] stored as its inverse
+    # softplus, A ~ U(1, 16) stored as log.
+    dt0 = jnp.exp(jax.random.uniform(ks[2], (H,), jnp.float32,
+                                     jnp.log(1e-3), jnp.log(1e-1)))
+    return {
+        "w_in": layers._init_dense(ks[0], d, di + cd + H, dt),
+        "conv_w": (jax.random.normal(ks[1], (mc.d_conv, cd)) * 0.1).astype(dt),
+        "conv_b": jnp.zeros((cd,), dt),
+        "dt_bias": dt0 + jnp.log(-jnp.expm1(-dt0)),
+        "A_log": jnp.log(jax.random.uniform(ks[3], (H,), jnp.float32, 1.0, 16.0)),
+        "D": jnp.ones((H,), jnp.float32),
+        "norm": layers.init_rmsnorm(di, dt),
+        "w_out": layers._init_dense(ks[4], di, d, dt),
+    }
+
+
+def _ssd_chunk(h, x, Bm, Cm, dt, A):
+    """One SSD chunk of L tokens, resuming from state h (B, H, P, N).
+    x (B, L, H, P), Bm/Cm (B, L, G, N), dt (B, L, H), A (H,), all f32.
+
+    With a_t = dt_t A and cumulative decay c_t = a_1 + .. + a_t in the chunk:
+      y_t = sum_{s<=t} exp(c_t - c_s) dt_s (C_t . B_s) x_s + exp(c_t) h C_t
+      h'  = exp(c_L) h + sum_s exp(c_L - c_s) dt_s x_s (x) B_s
+    which is the recurrence h <- exp(dt A) h + dt x (x) B, y = h C, over the
+    chunk.  Returns (h', y (B, L, H, P))."""
+    Bsz, L, H, P = x.shape
+    G = Bm.shape[2]
+    J = H // G
+    c = jnp.cumsum(dt * A, axis=1)                               # (B, L, H)
+    seg = c[:, :, None, :] - c[:, None, :, :]                    # (B, t, s, H)
+    causal = jnp.tril(jnp.ones((L, L), bool))[None, :, :, None]
+    decay = jnp.exp(jnp.where(causal, seg, -jnp.inf))
+    cb = jnp.einsum("btgn,bsgn->bgts", Cm, Bm)                   # (B, G, t, s)
+    w = (decay * dt[:, None, :, :]).reshape(Bsz, L, L, G, J)
+    w = w * jnp.moveaxis(cb, 1, 3)[..., None]                    # (B, t, s, G, J)
+    xg = x.reshape(Bsz, L, G, J, P)
+    y = jnp.einsum("btsgj,bsgjp->btgjp", w, xg)
+    hg = h.reshape(Bsz, G, J, P, -1)
+    y = y + jnp.einsum("bgjpn,btgn->btgjp", hg, Cm) * jnp.exp(
+        c).reshape(Bsz, L, G, J)[..., None]
+    tail = (jnp.exp(c[:, -1:] - c) * dt).reshape(Bsz, L, G, J)   # (B, s, G, J)
+    h_new = (jnp.exp(c[:, -1])[:, :, None, None] * h
+             + jnp.einsum("bsgj,bsgjp,bsgn->bgjpn", tail, xg, Bm
+                          ).reshape(h.shape))
+    return h_new, y.reshape(Bsz, L, H, P)
+
+
+def mamba2_block(
+    x: jax.Array,
+    p,
+    cfg,
+    *,
+    state: Optional[Mamba2State] = None,
+    collect_states: bool = False,
+) -> Tuple[jax.Array, Optional[Mamba2State]]:
+    """Mamba-2 mixer, x: (B, S, d) -> (B, S, d), with mamba_block's contract:
+    no state -> prefill from scratch (no state returned); S == 1 with a
+    state -> the O(1) decode update; S > 1 with a state -> advance it through
+    SSD chunks of min(chunk_size, S) tokens (conv tail and h both resume).
+
+      z, xBC, dt = x in_proj;  xBC = silu(conv(xBC) + conv_b) = (x, B, C)
+      dt = softplus(dt + dt_bias);  A = -exp(A_log)          (per head)
+      h <- exp(dt A) h + dt x (x) B;  y = h C + D x          (per head)
+      out = (rms(y * silu(z)) * norm) out_proj     (rms per group of heads)
+    """
+    if collect_states:
+        raise NotImplementedError(
+            "Mamba-2 layers keep no per-position states: speculative "
+            "verification through a Mamba-2 layer is not implemented")
+    Bsz, S, d = x.shape
+    mc = cfg.mamba
+    di, H, P, N, G = (mc.expand * d, mc.n_heads, mc.head_dim, mc.d_state,
+                      mc.n_groups)
+    cd = mc.conv_dim(d)
+    zxbcdt = layers.dense(x, p["w_in"])
+    z, xbc, dt = jnp.split(zxbcdt, [di, di + cd], axis=-1)
+    dc = mc.d_conv
+    tail = (state.conv if state is not None
+            else jnp.zeros((Bsz, dc - 1, cd), xbc.dtype))
+    xp = jnp.concatenate([tail.astype(xbc.dtype), xbc], axis=1)  # (B, S+dc-1, cd)
+    w = p["conv_w"].astype(jnp.float32)
+    xc = sum(xp[:, i: i + S].astype(jnp.float32) * w[i] for i in range(dc))
+    xc = jax.nn.silu(xc + p["conv_b"].astype(jnp.float32))
+    xs, Bm, Cm = jnp.split(xc, [di, di + G * N], axis=-1)
+    xs = shard(xs.reshape(Bsz, S, H, P), "batch", "seq", "heads", None)
+    Bm = Bm.reshape(Bsz, S, G, N)
+    Cm = Cm.reshape(Bsz, S, G, N)
+    dt = jax.nn.softplus(dt.astype(jnp.float32) + p["dt_bias"])  # (B, S, H)
+    A = -jnp.exp(p["A_log"])
+    h0 = (state.h if state is not None
+          else jnp.zeros((Bsz, H, P, N), jnp.float32))
+
+    if state is not None and S == 1:
+        # --- decode: O(1) update ------------------------------------------
+        J = H // G
+        Bh = jnp.repeat(Bm[:, 0], J, axis=1)                        # (B, H, N)
+        Ch = jnp.repeat(Cm[:, 0], J, axis=1)
+        h = (jnp.exp(dt[:, 0] * A)[:, :, None, None] * h0
+             + (dt[:, 0, :, None] * xs[:, 0])[..., None] * Bh[:, :, None, :])
+        y = jnp.einsum("bhpn,bhn->bhp", h, Ch)[:, None]             # (B, 1, H, P)
+    else:
+        # --- prefill: SSD over chunks -------------------------------------
+        L = min(mc.chunk_size, S)
+        while S % L:
+            L //= 2
+        nc = S // L
+        resh = lambda t: jnp.moveaxis(                               # noqa: E731
+            t.reshape(Bsz, nc, L, *t.shape[2:]), 1, 0)
+
+        def body(hc, xs_c):
+            return _ssd_chunk(hc, *xs_c, A)
+
+        h, ys = jax.lax.scan(jax.checkpoint(body), h0,
+                             (resh(xs), resh(Bm), resh(Cm), resh(dt)))
+        y = jnp.moveaxis(ys, 0, 1).reshape(Bsz, S, H, P)
+    y = y + p["D"][:, None] * xs
+    g = y.reshape(Bsz, S, G, di // G) * jax.nn.silu(
+        z.astype(jnp.float32)).reshape(Bsz, S, G, di // G)
+    g = g * jax.lax.rsqrt(jnp.mean(g * g, axis=-1, keepdims=True) + cfg.norm_eps)
+    g = g.reshape(Bsz, S, di) * p["norm"].astype(jnp.float32)
+    out = layers.dense(g.astype(x.dtype), p["w_out"])
+    new_state = (Mamba2State(h=h, conv=xp[:, S:].astype(tail.dtype))
+                 if state is not None else None)
+    return shard(out, "batch", "seq", "embed"), new_state
+
+
+def init_mamba2_state(cfg, batch: int) -> Mamba2State:
+    mc = cfg.mamba
+    return Mamba2State(
+        h=jnp.zeros((batch, mc.n_heads, mc.head_dim, mc.d_state), jnp.float32),
+        conv=jnp.zeros((batch, mc.d_conv - 1, mc.conv_dim(cfg.d_model)),
+                       cfg.jax_dtype),
     )
 
 

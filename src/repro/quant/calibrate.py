@@ -222,9 +222,7 @@ def calibrate(
         for batch in batches:
             tokens = _tokens_of(batch)
             B, S = tokens.shape
-            x = layers.embed(tokens, params["embed"])
-            if cfg.tie_embeddings:
-                x = x * jnp.asarray(cfg.d_model ** 0.5, x.dtype)
+            x = layers.embed(tokens, params["embed"], cfg.embedding_multiplier)
             positions = jnp.arange(S)
             for g in range(cfg.n_groups):
                 gp = jax.tree_util.tree_map(lambda a: a[g], params["blocks"])

@@ -73,7 +73,8 @@ def serving_gemm_shapes(cfg, *, slots: int, chunks: Optional[List[int]] = None
     wo (hq*hd, d)) and — for dense-FFN archs — the two FFN matmuls over
     `slots` token rows, plus the vocab head.  Chunked prefill runs the same
     projections over `C` rows per bucket size C (batch 1), so those M-dims
-    are warmed too.  MoE expert matmuls (einsum over stacked expert weights)
+    are warmed too, as are a MoE's shared expert and Mamba-2's in/out
+    projections.  MoE expert matmuls (einsum over stacked expert weights)
     and SSM scans do not route through spec-dispatched ops.gemm, so they are
     not warmed here.
     """
@@ -93,6 +94,15 @@ def serving_gemm_shapes(cfg, *, slots: int, chunks: Optional[List[int]] = None
             shapes += [
                 GemmShape(m, d, ff),         # FFN up (and swiglu gate)
                 GemmShape(m, ff, d),         # FFN down
+            ]
+        elif cfg.moe.shared_d_ff:        # the MoE's shared expert
+            shapes += [GemmShape(m, d, cfg.moe.shared_d_ff),
+                       GemmShape(m, cfg.moe.shared_d_ff, d)]
+        if cfg.mamba is not None and cfg.mamba.mamba2:
+            di = cfg.mamba.expand * d
+            shapes += [                      # Mamba-2 in and out projections
+                GemmShape(m, d, di + cfg.mamba.conv_dim(d) + cfg.mamba.n_heads),
+                GemmShape(m, di, d),
             ]
         shapes.append(GemmShape(m, d, vocab))  # LM head
     seen, out = set(), []
@@ -572,6 +582,12 @@ class Engine:
         self._ev_preempt = tc("preempt")
         self._ev_restore = tc("restore")
         self._account_kv_pools()
+        # Recurrent (SSM/xLSTM) state bytes one slot holds over all layers:
+        # a decode step reads and writes them for each active row.
+        self._state_bytes_per_slot = sum(
+            leaf.nbytes // slots for leaf in jax.tree_util.tree_leaves(
+                [c for c in self.state.caches
+                 if not isinstance(c, kvc.PagedKVCache)]))
 
         # The decode state (KV pools included) is *donated* to every step:
         # XLA updates the pools in place instead of copying them per tick.
@@ -1361,8 +1377,10 @@ class Engine:
             tr.end(self._ev_stage,
                    {"kv_blocks": self._kv_blocks()} if profiling else None)
             t_dec = time.monotonic()
-            tr.begin(self._ev_dispatch,
-                     {"rows": len(reqs), "ctx_tokens": ctx_tokens})
+            meta = {"rows": len(reqs), "ctx_tokens": ctx_tokens}
+            if self._state_bytes_per_slot:
+                meta["state_bytes"] = len(reqs) * self._state_bytes_per_slot
+            tr.begin(self._ev_dispatch, meta)
             if self._flow:
                 for r in reqs:
                     tr.flow_step(self._ev_flow, r.trace_id)

@@ -193,3 +193,32 @@ def test_flash_decode_compiles_at_nemo_shapes(one_chip, slots, sq):
     calls = [line for line in module.to_string(opts).splitlines()
              if 'custom_call_target="tpu_custom_call"' in line]
     assert [kernel_of(line) for line in calls] == ["flash_decode"]
+
+
+@pytest.mark.parametrize("rows,seq", [(64, 1), (1, 256)],
+                         ids=["decode_64_rows", "chunk_256"])
+def test_mamba2_mixer_compiles_at_granite_widths(one_chip, rows, seq):
+    """granite-4.0-h-small's Mamba-2 mixer at its published widths (128
+    heads of 64, state 128, d_inner 8192): the O(1) decode update over the
+    cell's 64 slots and the SSD prefill of one 256-token chunk, with its
+    projections on the GeMM kernel."""
+    from repro import configs
+    from repro.models import ssm
+
+    cfg = configs.get("granite-4.0-h-small")
+    params = jax.eval_shape(lambda: ssm.init_mamba2(jax.random.PRNGKey(0),
+                                                     cfg))
+    state = jax.eval_shape(lambda: ssm.init_mamba2_state(cfg, rows))
+    leaves, tree = jax.tree_util.tree_flatten((params, state))
+
+    def step(x, *flat):
+        p, st = jax.tree_util.tree_unflatten(tree, flat)
+        return ssm.mamba2_block(x, p, cfg, state=st)
+
+    prev = ops.get_default_backend()
+    ops.set_default_backend("pallas")
+    try:
+        _compile(one_chip, step, (jnp.bfloat16, (rows, seq, cfg.d_model)),
+                 *[(a.dtype, a.shape) for a in leaves])
+    finally:
+        ops.set_default_backend(prev)
