@@ -301,9 +301,13 @@ def flash_decode_attention(
     spec: Optional[FlashDecodeSpec] = None,
     interpret: bool = False,
     scale: Optional[float] = None,
+    block_base=0,
 ) -> jax.Array:
     """Decode attention over the paged pool via the Pallas kernel; `scale`
-    is the softmax scale (None: D ** -0.5).
+    is the softmax scale (None: D ** -0.5).  ``block_base`` is the pool row
+    of this layer's block 0 when the pool merges several layers' pools
+    (serving/kv_cache.py ``write_kv``); live steps are counted on the table
+    before that offset, so a null entry still reads as empty.
 
     The pools stay in HBM in their ``(num_blocks, Hkv, bs, D)`` layout; each
     (row, split) program DMAs its live steps' blocks, by table entry, into
@@ -326,6 +330,7 @@ def flash_decode_attention(
     if idx.ndim == 0:
         idx = jnp.broadcast_to(idx, (B,))
     n_live = live_steps(idx, Sq, bt, bs)
+    bt = bt + block_base
 
     splits = max(1, min(spec.num_splits, n_steps))
     sps = -(-n_steps // splits)
@@ -574,26 +579,28 @@ def paged_decode_attention(
     backend: Optional[str] = None,
     spec: Optional[FlashDecodeSpec] = None,
     scale: Optional[float] = None,
+    block_base=0,
 ) -> jax.Array:
     """Decode attention over a paged KV cache — the dispatch entry the model
     layer calls.  Equivalent to ``gather_kv`` + ``decode_attention`` for
     every backend (tested in tests/test_flash_decode.py); they differ only
     in how much pool they touch.  `scale` is the softmax scale (None:
-    D ** -0.5)."""
-    if prefix_len:
-        return _gather_decode(q, cache, block_tables, index, window=window,
-                              prefix_len=prefix_len, scale=scale)
-    b = _resolve_backend(backend)
+    D ** -0.5); ``block_base`` is the pool row of this layer's block 0 (see
+    ``flash_decode_attention``)."""
+    b = "gather" if prefix_len else _resolve_backend(backend)
     spec = spec or _DECODE_SPEC or FlashDecodeSpec()
     if b == "gather":
-        return _gather_decode(q, cache, block_tables, index, window=window,
+        return _gather_decode(q, cache, block_tables + block_base, index,
+                              window=window, prefix_len=prefix_len,
                               scale=scale)
     if b == "blocked":
-        return ref_paged_decode(q, cache, block_tables, index, window=window,
+        return ref_paged_decode(q, cache, block_tables + block_base, index,
+                                window=window,
                                 cols_per_iter=spec.cols_per_iter, scale=scale)
     return flash_decode_attention(q, cache, block_tables, index,
                                   window=window, spec=spec,
-                                  interpret=(b == "interpret"), scale=scale)
+                                  interpret=(b == "interpret"), scale=scale,
+                                  block_base=block_base)
 
 
 def make_flash_decode(spec: FlashDecodeSpec, *, interpret: bool = False):

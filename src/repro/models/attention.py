@@ -258,6 +258,7 @@ def attention(
     cache: Optional[KVCache] = None,
     cache_index: Optional[jax.Array] = None,
     block_tables: Optional[jax.Array] = None,
+    block_base=0,
 ):
     """Full attention sublayer.  Returns (out, new_cache).
 
@@ -266,7 +267,9 @@ def attention(
     (B, S_max, Hkv, D); x is (B, 1, d) and cache_index the scalar write
     position.  Paged decode/prefill: cache is a PagedKVCache pool,
     block_tables (B, max_blocks) addresses it, and cache_index is the (B,)
-    per-slot first-token position (x may carry S > 1 chunk tokens).
+    per-slot first-token position (x may carry S > 1 chunk tokens);
+    block_base is the pool row of this layer's block 0 when the pool merges
+    several layers' pools (serving/kv_cache.py ``write_kv``).
     """
     cross = kv_src is not None
     src = kv_src if cross else x
@@ -278,17 +281,19 @@ def attention(
         from repro.serving import kv_cache as paged
 
         if isinstance(cache, paged.PagedKVCache):
-            # Paged decode: scatter this step's k/v through the block table,
+            # Paged decode: write this step's k/v through the block table,
             # then attend over the pool directly (block-table walk) — the
             # gather/blocked/flash backend choice lives in
             # kernels/flash_decode.py and binds at trace time.
             from repro.kernels import flash_decode as _fd
 
             assert block_tables is not None, "paged cache needs block_tables"
-            new_cache = paged.write_kv(cache, block_tables, k, v, cache_index)
+            new_cache = paged.write_kv(cache, block_tables, k, v, cache_index,
+                                       block_base=block_base)
             out = _fd.paged_decode_attention(
                 q, new_cache, block_tables, cache_index,
                 window=window, prefix_len=prefix_len, scale=scale,
+                block_base=block_base,
             )
         else:
             # Dense decode: append this step's k/v then attend over the cache.

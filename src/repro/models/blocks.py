@@ -91,6 +91,7 @@ def apply_block(
     encoder_out: Optional[jax.Array] = None,
     cross_cache: Optional[attn_lib.KVCache] = None,
     block_tables: Optional[jax.Array] = None,
+    block_base=0,
     collect_states: bool = False,
 ) -> Tuple[jax.Array, Any]:
     """Returns (x, new_mixer_cache).  cache is the mixer state (KV / SSM).
@@ -115,6 +116,7 @@ def apply_block(
                 h, p["mixer"], cfg, positions=positions, causal=causal,
                 window=window, prefix_len=prefix_len, cache=cache,
                 cache_index=cache_index, block_tables=block_tables,
+                block_base=block_base,
             )
         elif kind == "mamba":
             h, new_cache = ssm.mamba_block(h, p["mixer"], cfg, state=cache,
@@ -168,9 +170,11 @@ def init_group(key, cfg, *, cross_attention: bool = False):
 def apply_group(
     x, gp, cfg, *, positions, causal=True, prefix_len=0,
     caches=None, cache_index=None, encoder_out=None, cross_caches=None,
-    block_tables=None, collect_states=False,
+    block_tables=None, block_base=0, collect_states=False,
 ):
-    """Apply one group of cfg.group_size blocks; returns (x, new_caches)."""
+    """Apply one group of cfg.group_size blocks; returns (x, new_caches).
+    ``block_base`` is the row of this group's block 0 in pools that merge
+    every group's pool (model.py ``_trunk_step``)."""
     kinds = cfg.layer_kinds()
     new_caches = []
     for i, kind in enumerate(kinds):
@@ -181,7 +185,7 @@ def apply_group(
             cache_index=cache_index,
             encoder_out=encoder_out,
             cross_cache=None if cross_caches is None else cross_caches[i],
-            block_tables=block_tables,
+            block_tables=block_tables, block_base=block_base,
             collect_states=collect_states,
         )
         new_caches.append(nc)

@@ -329,18 +329,52 @@ def init_paged_decode_state(
 
 def _trunk_step(params, cfg, x, positions, caches, cache_index, block_tables,
                 collect_states=False):
-    """Scan the block groups in decode mode; returns (hidden, new_caches)."""
+    """Scan the block groups in decode mode; returns (hidden, new_caches).
 
-    def body(h, xs):
-        gp, gcache = xs
+    Paged KV pools ride in the scan's carry, recurrent and dense caches in
+    its xs/ys.  Each stacked ``(G, nb, H, bs, D)`` pool is seen as one
+    ``(G * nb, H, bs, D)`` pool (merging the leading dims is a bitcast), so
+    group g's block b is row ``g * nb + b`` and every layer writes its new
+    tokens in place.  Scanned as xs/ys, each pool would be sliced out and
+    written back whole on every step, and the donated state could not
+    alias it."""
+    from repro.serving.kv_cache import PagedKVCache
+
+    paged = [isinstance(c, PagedKVCache) for c in caches]
+    G = cfg.n_groups
+    pools = tuple(
+        jax.tree_util.tree_map(lambda a: a.reshape((-1,) + a.shape[2:]), c)
+        for c, p in zip(caches, paged) if p)
+    nb = pools[0].num_blocks // G if pools else 0
+
+    def split(cs):
+        """Per-kind caches -> (pools, the rest with None in pools' places)."""
+        return (tuple(c for c, p in zip(cs, paged) if p),
+                tuple(None if p else c for c, p in zip(cs, paged)))
+
+    def join(pools, rest):
+        it = iter(pools)
+        return tuple(next(it) if p else c for c, p in zip(rest, paged))
+
+    def body(carry, xs):
+        h, pools = carry
+        g, gp, gcache = xs
         h, new_caches = blocks.apply_group(
             h, gp, cfg, positions=positions, causal=True,
-            caches=gcache, cache_index=cache_index, block_tables=block_tables,
+            caches=join(pools, gcache), cache_index=cache_index,
+            block_tables=block_tables, block_base=g * nb,
             collect_states=collect_states,
         )
-        return h, new_caches
+        pools, rest = split(new_caches)
+        return (h, pools), rest
 
-    return jax.lax.scan(body, x, (params["blocks"], caches))
+    (x, pools), rest = jax.lax.scan(
+        body, (x, pools),
+        (jnp.arange(G, dtype=jnp.int32), params["blocks"], split(caches)[1]))
+    pools = tuple(
+        jax.tree_util.tree_map(lambda a: a.reshape((G, -1) + a.shape[1:]), c)
+        for c in pools)
+    return x, join(pools, rest)
 
 
 def _embed_tokens(params, cfg, tokens):

@@ -23,10 +23,13 @@ it, and writes from idle slots or masked positions land there.  It is never
 handed out by the allocator, so garbage in it is never attended (the causal
 length mask excludes every position a table does not really cover).
 
-The device side is pure array math (`write_kv` / `gather_kv`), jit-safe and
-scanned over layer groups; the host side (`BlockAllocator`, `BlockTables`)
-makes allocation decisions between steps, exactly like the paper's RISC-V
-core programs the streamer strides between GeMM calls.
+The device side is pure array math (`write_kv` / `gather_kv`), jit-safe; in
+the serving steps each kind's per-group pools are merged into one pool along
+the block axis (group g's block b at row g * num_blocks + b) and carried
+through the layer scan, so each layer writes its tokens in place.  The host
+side (`BlockAllocator`, `BlockTables`) makes allocation decisions between
+steps, exactly like the paper's RISC-V core programs the streamer strides
+between GeMM calls.
 """
 
 from __future__ import annotations
@@ -104,58 +107,82 @@ def quantize_kv_tokens(x: jax.Array) -> Tuple[jax.Array, jax.Array]:
     return q, scale
 
 
-def _block_positions(block_tables: jax.Array, start, S: int, block_size: int
-                     ) -> Tuple[jax.Array, jax.Array]:
-    """Pool (block, offset) write indices for S tokens starting at `start`
-    per slot.
-
-    block_tables: (B, max_blocks); start: scalar or (B,).  Returns two (B, S)
-    arrays: the pool block id and the position inside it.  Positions beyond
-    a slot's table capacity resolve to the null block — without the explicit
-    mask, take_along_axis would clamp to the table's *last* entry and
-    silently overwrite a live block.
-    """
-    start = jnp.asarray(start, jnp.int32)
-    if start.ndim == 0:
-        start = jnp.broadcast_to(start, (block_tables.shape[0],))
-    pos = start[:, None] + jnp.arange(S, dtype=jnp.int32)[None, :]   # (B, S)
-    pos = jnp.maximum(pos, 0)
-    table_cap = block_tables.shape[1] * block_size
-    blk = jnp.take_along_axis(
-        block_tables, jnp.minimum(pos, table_cap - 1) // block_size, axis=1)
-    blk = jnp.where(pos < table_cap, blk, NULL_BLOCK)
-    return blk, pos % block_size
-
-
 def write_kv(
     cache: PagedKVCache,
     block_tables: jax.Array,
     k_new: jax.Array,
     v_new: jax.Array,
     start,
+    *,
+    block_base=0,
 ) -> PagedKVCache:
-    """Scatter S new tokens per slot into the pool at positions start..start+S-1.
+    """Write S new tokens per slot into the pool at positions
+    start..start+S-1 (start >= 0), in place.
 
-    k_new/v_new: (B, S, H, D).  Distinct live slots own distinct blocks, so
-    real writes never collide; idle-slot writes collapse onto the null block.
+    k_new/v_new: (B, S, H, D); start: scalar or (B,).  The pool may merge
+    several layers' pools along its leading axis: ``block_base`` is the row
+    of this layer's block 0, so table entry ``blk`` addresses row
+    ``block_base + blk`` and no other layer's rows are touched.
+
+    Each pool block a slot's new positions fall in is one run: one for a
+    decode token, at most ``ceil(S / block_size) + 1`` for a chunk.  A loop
+    over the runs reads the block, merges the run's tokens in by position
+    and writes the block back whole with ``dynamic_update_slice``, in the
+    pool's own layout, so a step touches only those blocks.  (A scatter
+    that indexed blocks and positions on either side of the head axis made
+    the compiler relayout the whole pool and back on every step.)
+    Positions past a slot's table capacity, and idle slots' all-null rows,
+    land in the null block; distinct live slots own distinct blocks, so
+    real writes never collide.
     """
-    H, D = cache.k.shape[1], cache.k.shape[3]
-    blk, off = _block_positions(block_tables, start, k_new.shape[1],
-                                cache.block_size)
-    blk, off = blk.reshape(-1), off.reshape(-1)
-    # Advanced indices split by a slice: the updates are (B * S, H[, D]).
-    k_scale, v_scale = cache.k_scale, cache.v_scale
+    B, S = k_new.shape[:2]
+    bs = cache.block_size
+    width = block_tables.shape[1]
+    R = (S + bs - 2) // bs + 1
+    start = jnp.asarray(start, jnp.int32)
+    if start.ndim == 0:
+        start = jnp.broadcast_to(start, (B,))
+    col = start[:, None] // bs + jnp.arange(R, dtype=jnp.int32)[None, :]
+    blk = jnp.take_along_axis(block_tables, jnp.minimum(col, width - 1),
+                              axis=1)
+    rows = jnp.where(col < width, blk, NULL_BLOCK) + block_base     # (B, R)
+    # Token i of the slot's S lands at run r, offset t when
+    # (col * bs + t) - start == i.
+    i = (col[..., None] * bs + jnp.arange(bs, dtype=jnp.int32)
+         - start[:, None, None])                                   # (B, R, bs)
+    valid = (i >= 0) & (i < S)
+    take = jnp.clip(i, 0, S - 1).reshape(B, R * bs)
+
+    def runs(x, dtype):
+        """(B, S, H, ...) new tokens -> (B * R, H, bs, ...) run blocks."""
+        x = jnp.take_along_axis(
+            x.astype(dtype),
+            take.reshape((B, R * bs) + (1,) * (x.ndim - 2)), axis=1)
+        x = x.reshape((B * R, bs) + x.shape[2:])
+        return jnp.moveaxis(x, 1, 2)
+
     if cache.quantized:
         # Quantize on write (per token x kv-head); the pool never sees floats.
         k_new, ks = quantize_kv_tokens(k_new)
         v_new, vs = quantize_kv_tokens(v_new)
-        k_scale = k_scale.at[blk, :, off].set(ks.reshape(-1, H), mode="drop")
-        v_scale = v_scale.at[blk, :, off].set(vs.reshape(-1, H), mode="drop")
-    k_pool = cache.k.at[blk, :, off].set(
-        k_new.astype(cache.k.dtype).reshape(-1, H, D), mode="drop")
-    v_pool = cache.v.at[blk, :, off].set(
-        v_new.astype(cache.v.dtype).reshape(-1, H, D), mode="drop")
-    return PagedKVCache(k=k_pool, v=v_pool, k_scale=k_scale, v_scale=v_scale)
+        news = (k_new, v_new, ks, vs)
+    else:
+        news = (k_new, v_new)
+    pools = tuple(cache)[:len(news)]
+    blocks = tuple(runs(x, p.dtype) for x, p in zip(news, pools))
+    rows, valid = rows.reshape(-1), valid.reshape(B * R, 1, bs)
+
+    def run(n, pools):
+        out = []
+        for pool, new in zip(pools, blocks):
+            at = (rows[n],) + (0,) * (pool.ndim - 1)
+            old = jax.lax.dynamic_slice(pool, at, (1,) + pool.shape[1:])[0]
+            keep = valid[n].reshape((1, bs) + (1,) * (pool.ndim - 3))
+            out.append(jax.lax.dynamic_update_slice(
+                pool, jnp.where(keep, new[n], old)[None], at))
+        return tuple(out)
+
+    return PagedKVCache(*jax.lax.fori_loop(0, B * R, run, pools))
 
 
 def copy_blocks(

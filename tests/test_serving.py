@@ -280,6 +280,83 @@ def test_scheduler_fifo_admission_blocks_behind_head():
     assert sched.queue[0] is big and len(sched.queue) == 2
 
 
+def _scatter_kv(cache, block_tables, k_new, v_new, start):
+    """The scatter ``write_kv`` replaced: (block, offset) index arrays on
+    either side of the head axis, positions past a table's capacity on the
+    null block.  The reference its in-place write must match bit for bit."""
+    B, S, H, D = k_new.shape
+    bs = cache.block_size
+    pos = jnp.broadcast_to(jnp.asarray(start, jnp.int32), (B,))[:, None] \
+        + jnp.arange(S, dtype=jnp.int32)[None, :]
+    cap = block_tables.shape[1] * bs
+    blk = jnp.take_along_axis(block_tables, jnp.minimum(pos, cap - 1) // bs,
+                              axis=1)
+    blk = jnp.where(pos < cap, blk, kvc.NULL_BLOCK).reshape(-1)
+    off = (pos % bs).reshape(-1)
+    k_scale, v_scale = cache.k_scale, cache.v_scale
+    if cache.quantized:
+        k_new, ks = kvc.quantize_kv_tokens(k_new)
+        v_new, vs = kvc.quantize_kv_tokens(v_new)
+        k_scale = k_scale.at[blk, :, off].set(ks.reshape(-1, H))
+        v_scale = v_scale.at[blk, :, off].set(vs.reshape(-1, H))
+    return kvc.PagedKVCache(
+        k=cache.k.at[blk, :, off].set(
+            k_new.astype(cache.k.dtype).reshape(-1, H, D)),
+        v=cache.v.at[blk, :, off].set(
+            v_new.astype(cache.v.dtype).reshape(-1, H, D)),
+        k_scale=k_scale, v_scale=v_scale)
+
+
+@pytest.mark.parametrize("kv_precision", ["float", "int8"])
+@pytest.mark.parametrize("rows,tokens,start", [
+    (32, 1, None), (1, 256, 0), (1, 256, 37)],
+    ids=["decode32", "chunk256_at_block", "chunk256_mid_block"])
+def test_write_kv_in_place_matches_scatter(rows, tokens, start, kv_precision):
+    """``write_kv`` into two layers' pools merged along the block axis gives
+    each layer exactly the old scatter's pool, bit for bit, and leaves the
+    other layer's rows as they were.  Decode: 32 rows at their own
+    positions, three idle rows on all-null tables and one row past its
+    table's capacity, all landing in that layer's null block; chunks of 256
+    tokens starting at a block boundary and mid-block."""
+    G, nb, bs, H, D = 2, 65, 16, 2, 8
+    width = 2 if rows > 1 else 20
+    rng = np.random.default_rng(rows + (start or 0))
+    pool = kvc.init_paged_kv(G * nb, bs, H, D, jnp.bfloat16,
+                             kv_precision=kv_precision)
+    pool = kvc.PagedKVCache(*[
+        None if a is None else jnp.asarray(
+            rng.integers(-100, 100, size=a.shape) if a.dtype == jnp.int8
+            else rng.uniform(0.5, 2.0, size=a.shape), a.dtype)
+        for a in pool])
+    owned = rng.permutation(np.arange(1, nb))[:rows * width]
+    tables = owned.reshape(rows, width).astype(np.int32)
+    if start is None:
+        starts = rng.integers(0, width * bs, size=rows).astype(np.int32)
+        tables[[5, 17, 30]] = kvc.NULL_BLOCK          # idle rows
+        starts[9] = width * bs + 5                    # past capacity
+    else:
+        starts = np.full((rows,), start, np.int32)
+    k_new = jnp.asarray(rng.normal(size=(rows, tokens, H, D)), jnp.float32)
+    v_new = jnp.asarray(rng.normal(size=(rows, tokens, H, D)), jnp.float32)
+
+    write = jax.jit(lambda c, bt, k, v, s, base: kvc.write_kv(
+        c, bt, k, v, s, block_base=base))
+    scatter = jax.jit(_scatter_kv)
+    for g in range(G):
+        got = write(pool, tables, k_new, v_new, starts, jnp.int32(g * nb))
+        layer = jax.tree_util.tree_map(lambda a: a[g * nb:(g + 1) * nb], pool)
+        want = scatter(layer, tables, k_new, v_new, starts)
+        for a, w, before in zip(got, want, pool):
+            if a is None:
+                continue
+            a, before = np.asarray(a), np.asarray(before)
+            np.testing.assert_array_equal(a[g * nb:(g + 1) * nb],
+                                          np.asarray(w))
+            rest = np.ones(G * nb, bool)
+            rest[g * nb:(g + 1) * nb] = False
+            np.testing.assert_array_equal(a[rest], before[rest])
+
+
 def test_block_allocator_reservations():
     alloc = kvc.BlockAllocator(num_blocks=8, block_size=4)
     assert alloc.available == 7

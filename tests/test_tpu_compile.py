@@ -12,11 +12,15 @@ dims, so those between lay out alike), MQA and the GQA layout (8 kv heads,
 D=128), float and int8 KV pools, in the decode and prefill-chunk steps; at
 mistral-nemo-12b's decode and 256-token chunk shapes they also check that
 the chip benchmark still tells the flash-decode kernel by its operands.
+Two cases compile mistral-nemo-12b's whole donated decode and chunk steps
+at depth 8 and check that no instruction makes a whole KV pool.
 
 Nothing runs: a pass here is a compile, not a chip run.  Only one process
 may load the TPU library at a time, so the topology is described inside a
 module fixture (never at import) and every case compiles in this process.
 """
+
+import re
 
 import jax
 import jax.numpy as jnp
@@ -183,16 +187,118 @@ def test_flash_decode_compiles_at_nemo_shapes(one_chip, slots, sq):
                         (jnp.int32, (slots, max_blocks)),
                         (jnp.int32, (slots,)),
                         *[(x.dtype, x.shape) for x in leaves])
-    # The profile names an operation by its HLO text with operand shapes.
+    assert _kernels(compiled) == ["flash_decode"]
+
+
+def _kernels(compiled):
+    """What the chip benchmark reads each Pallas call of a compiled program
+    as: the profile names an operation by its HLO text with operand
+    shapes."""
     from jax._src.lib import xla_client
 
     opts = xla_client._xla.HloPrintOptions.short_parsable()
     opts.print_operand_shape = True
     [module] = compiled.runtime_executable().hlo_modules()
     kernel_of = _kernel_of()
-    calls = [line for line in module.to_string(opts).splitlines()
-             if 'custom_call_target="tpu_custom_call"' in line]
-    assert [kernel_of(line) for line in calls] == ["flash_decode"]
+    return [kernel_of(line) for line in module.to_string(opts).splitlines()
+            if 'custom_call_target="tpu_custom_call"' in line]
+
+
+def _whole_pool_ops(hlo: str, pool_shapes):
+    """Instructions of a compiled program that make a whole pool: those
+    outside fused computations whose output holds one of ``pool_shapes``,
+    other than a parameter, a tuple or its element, a bitcast, a while
+    loop's carry, or a fusion whose only pool-shaped work is
+    dynamic-update-slice (written in place into its operand's buffer).  A
+    copy, an allocation, a relayout or a scatter of a pool is listed."""
+    shapes = tuple(f"[{','.join(map(str, s))}]" for s in pool_shapes)
+    comps, name = {}, None
+    for line in hlo.splitlines():
+        if line.startswith(("%", "ENTRY")) and line.endswith("{"):
+            name = line.split()[1 if line.startswith("ENTRY") else 0]
+            comps[name] = []
+        elif line.startswith("  ") and name:
+            comps[name].append(line.strip().removeprefix("ROOT "))
+
+    def pooled(op):
+        """(opcode, called computation or None) of an instruction whose
+        output holds a pool, else None."""
+        rhs = op.partition(" = ")[2]
+        code = re.search(r"\s([a-z][a-z0-9-]*)\(", rhs)
+        if not any(s in rhs[:code.start()] for s in shapes):
+            return None
+        called = re.search(r"calls=(%[^,\s]+)", rhs)
+        return code.group(1), called and called.group(1)
+
+    fused = set(re.findall(r"calls=(%[^,\s]+)", hlo))
+    in_place = {"parameter", "tuple", "get-tuple-element", "bitcast",
+                "while"}
+    bad = []
+    for name, ops in comps.items():
+        if name in fused:
+            continue
+        for op in ops:
+            kind = pooled(op)
+            if kind is None or kind[0] in in_place:
+                continue
+            if kind[0] == "fusion" and all(
+                    p is None or p[0] in in_place | {"dynamic-update-slice"}
+                    for p in map(pooled, comps[kind[1]])):
+                continue
+            bad.append(op.split(" = ")[0] + " " + kind[0])
+    return bad
+
+
+@pytest.mark.parametrize("step", ["decode", "chunk256"])
+def test_serving_steps_update_kv_pools_in_place(one_chip, step):
+    """mistral-nemo-12b at depth 8 (two scanned groups of four layers), the
+    chip benchmark's engine: 32 slots, 4097 blocks of 16 tokens, the state
+    donated.  The compiled decode step and 256-token chunk step make no
+    whole KV pool: every layer writes its new tokens into the pool in place
+    (no copy, allocation, relayout or scatter of a pool), so the step's
+    temporaries hold no second pool set (4.84 GB when the pools were
+    scanned), and the benchmark still reads each layer's attention kernel as
+    flash_decode."""
+    import dataclasses
+
+    from repro import configs
+    from repro.launch import steps
+    from repro.models import model as M
+
+    cfg = dataclasses.replace(configs.get("mistral-nemo-12b"), n_layers=8)
+    slots, nb, bs, width = 32, 4097, 16, 256
+    params = jax.eval_shape(lambda: M.init_model(jax.random.PRNGKey(0), cfg))
+    state = jax.eval_shape(lambda: M.init_paged_decode_state(
+        cfg, slots, num_blocks=nb, block_size=bs, max_blocks_per_slot=width))
+    if step == "decode":
+        make, extra = steps.make_paged_serve_step, [
+            (jnp.int32, (slots, 1)), (jnp.bool_, (slots,))]
+    else:
+        make, extra = steps.make_prefill_chunk_step, [
+            (jnp.int32, (1, 256)), (jnp.int32, ())]
+    leaves, tree = jax.tree_util.tree_flatten((params, state))
+    n_params = len(jax.tree_util.tree_leaves(params))
+
+    def run(*flat):
+        p, st = jax.tree_util.tree_unflatten(tree, flat[:len(leaves)])
+        return make(cfg)(p, st, *flat[len(leaves):])
+
+    args = [jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+            for a in leaves] + [
+        jax.ShapeDtypeStruct(s, d, sharding=one_chip) for d, s in extra]
+    prev = ops.get_default_backend()
+    ops.set_default_backend("pallas")
+    try:
+        with fd.decode_backend("flash"):
+            compiled = jax.jit(run, donate_argnums=tuple(
+                range(n_params, len(leaves)))).lower(*args).compile()
+    finally:
+        ops.set_default_backend(prev)
+    G, H, D = cfg.n_groups, cfg.n_kv_heads, cfg.resolved_head_dim
+    pools = [(nb, H, bs, D), (G, nb, H, bs, D), (G * nb, H, bs, D)]
+    assert _whole_pool_ops(compiled.as_text(), pools) == []
+    assert _kernels(compiled).count("flash_decode") == cfg.group_size
+    assert compiled.memory_analysis().temp_size_in_bytes < 2.84e9
 
 
 @pytest.mark.parametrize("rows,seq", [(64, 1), (1, 256)],
