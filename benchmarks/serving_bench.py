@@ -1,18 +1,12 @@
-"""Serving engine benchmark: chunked prefill vs token-by-token, engine
-decode throughput, and float vs w8a8 (int8-resident) decode throughput.
+"""Serving engine benchmark: engine decode throughput, float vs w8a8
+(int8-resident) decode throughput, and the int8 quality delta.
 
 Paper artifact: none directly — this measures the serving-path analogues of
 the paper's mechanisms (EXPERIMENTS.md §Serving, §Quantization).  The
-headline rows are the wall-clock prefill speedup of the engine's chunked
-prefill over the legacy token-by-token loop (acceptance bar >= 2x at prompt
-64 on the dense smoke arch) and the w8a8-vs-float decode-throughput delta
-(the paper's int8 deployment precision carried through the serving stack).
+headline row is the w8a8-vs-float decode-throughput delta (the paper's int8
+deployment precision carried through the serving stack).
 
 Output rows (CSV via benchmarks/run.py):
-  serving/prefill_speedup_p64   chunked-vs-token-by-token wall-clock ratio
-                                (derived column = 2.0, the acceptance bar)
-  serving/prefill_ms_p64        chunked prefill wall-clock, ms (derived =
-                                the token-by-token baseline's ms)
   serving/decode_tok_s          float decode throughput, tokens/s
   serving/decode_tok_s_w8a8     w8a8 decode throughput, tokens/s (derived =
                                 the float row: the delta the gate requires)
@@ -42,7 +36,6 @@ import os
 import numpy as np
 
 from repro import configs
-from repro.launch.serve import compare_prefill
 from repro.serving.engine import Engine
 from repro.tuning import env_truthy
 
@@ -90,10 +83,6 @@ def run():
     prompts = [rng.integers(0, cfg.vocab, size=PROMPT_LEN).astype(np.int32)
                for _ in range(SLOTS)]
 
-    t_legacy, t_chunked = compare_prefill(
-        cfg, None, prompts, slots=SLOTS, max_seq=max_seq, block_size=16,
-        max_chunk=MAX_CHUNK, iters=ITERS)
-
     # float vs w8a8 decode throughput, engines interleaved per iteration so
     # host load spikes hit both alike
     f_eng = Engine(cfg, slots=SLOTS, max_seq=max_seq, block_size=16,
@@ -113,12 +102,7 @@ def run():
     savings = (1.0 - q_eng.metrics.weight_bytes
                / max(q_eng.metrics.weight_bytes_float, 1))
 
-    p = PROMPT_LEN
     return [
-        {"name": f"serving/prefill_speedup_p{p}",
-         "value": round(t_legacy / t_chunked, 2), "derived": 2.0},
-        {"name": f"serving/prefill_ms_p{p}",
-         "value": round(t_chunked * 1e3, 1), "derived": round(t_legacy * 1e3, 1)},
         {"name": "serving/decode_tok_s",
          "value": round(f_best, 1), "derived": ""},
         {"name": "serving/decode_tok_s_w8a8",

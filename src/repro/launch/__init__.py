@@ -18,16 +18,14 @@ _LAZY = {
     # steps: the one-definition step builders (dry-run and real launchers)
     "make_train_step": ("repro.launch.steps", "make_train_step"),
     "make_prefill_step": ("repro.launch.steps", "make_prefill_step"),
-    "make_serve_step": ("repro.launch.steps", "make_serve_step"),
     "make_paged_serve_step": ("repro.launch.steps", "make_paged_serve_step"),
     "make_prefill_chunk_step": ("repro.launch.steps", "make_prefill_chunk_step"),
     "input_specs": ("repro.launch.steps", "input_specs"),
     "optimizer_config": ("repro.launch.steps", "optimizer_config"),
-    # serve: engine facade + comparison harness
+    # serve: engine facade and the cluster launcher
     "Engine": ("repro.launch.serve", "Engine"),
     "autotune_for_serving": ("repro.launch.serve", "autotune_for_serving"),
     "serving_gemm_shapes": ("repro.launch.serve", "serving_gemm_shapes"),
-    "compare_prefill": ("repro.launch.serve", "compare_prefill"),
     "serve_cluster": ("repro.launch.serve", "serve_cluster"),
     # meshes
     "make_local_mesh": ("repro.launch.mesh", "make_local_mesh"),

@@ -95,8 +95,10 @@ def main():
         import repro.launch.dryrun as dr
 
         dump = args.hlo or f"/tmp/hlo_{args.arch}_{args.shape}_{args.mesh}.txt"
-        dr.run_cell(args.arch, args.shape, args.mesh, verbose=True,
-                    dump_hlo=dump)
+        res = dr.run_cell(args.arch, args.shape, args.mesh, verbose=True,
+                          dump_hlo=dump)
+        if "not_served" in res:
+            return
         text = open(dump).read()
     rows, count = attribute(text, args.top)
     print("\ntop collective sources (bytes/device x trips):")

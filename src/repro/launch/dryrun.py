@@ -35,6 +35,18 @@ def run_cell(arch: str, shape_name: str, mesh_kind: str, verbose: bool = True,
              dump_hlo: str | None = None):
     cfg = configs.get(arch)
     shape = configs.SHAPES[shape_name]
+    if shape["kind"] == "decode":
+        # Decode cells lower the paged step that serving runs, over the
+        # state an Engine of global_batch slots at seq_len would hold.
+        try:
+            d_struct = steps_lib.paged_state_struct(
+                cfg, shape["global_batch"], shape["seq_len"])
+        except NotImplementedError as e:
+            if verbose:
+                print(f"== {arch} x {shape_name} x {mesh_kind}: not served: "
+                      f"{e} ==")
+            return {"arch": arch, "shape": shape_name, "mesh": mesh_kind,
+                    "not_served": str(e)}
     multi = mesh_kind == "multi"
     mesh = mesh_lib.make_production_mesh(multi_pod=multi)
     chips = mesh.size
@@ -74,18 +86,15 @@ def run_cell(arch: str, shape_name: str, mesh_kind: str, verbose: bool = True,
                     p_struct, specs["batch"]
                 )
         else:  # decode
-            d_struct = steps_lib.decode_state_struct(
-                cfg, p_struct, shape["global_batch"], specs["max_seq"]
-            )
             d_shard = shard_lib.cache_sharding(d_struct, mesh, plan)
-            tok = specs["tokens"]
-            t_shard = shard_lib.batch_sharding({"t": tok}, mesh, plan)["t"]
-            step = steps_lib.make_serve_step(cfg)
+            tok, act = specs["tokens"], specs["active"]
+            t_shard = shard_lib.batch_sharding((tok, act), mesh, plan)
+            step = steps_lib.make_paged_serve_step(cfg)
             with mesh:
                 lowered = jax.jit(
-                    step, in_shardings=(p_shard, d_shard, t_shard),
+                    step, in_shardings=(p_shard, d_shard, *t_shard),
                     donate_argnums=(1,),
-                ).lower(p_struct, d_struct, tok)
+                ).lower(p_struct, d_struct, tok, act)
         t_lower = time.time() - t0
         t0 = time.time()
         compiled = lowered.compile()
@@ -155,7 +164,7 @@ def main():
                     continue
             try:
                 res = run_cell(arch, shape, mk)
-                if args.out:
+                if args.out and "not_served" not in res:
                     os.makedirs(args.out, exist_ok=True)
                     fn = os.path.join(args.out, f"{arch}__{shape}__{mk}.json")
                     with open(fn, "w") as f:

@@ -4,20 +4,16 @@ The engine maps the paper's three utilization mechanisms onto the request
 path — warmup (autotune + AOT compile) as configuration pre-loading, chunked
 prefill interleaved with decode as input pre-fetching with output buffering,
 and the paged KV cache as programmable strided memory access.  See
-EXPERIMENTS.md §Serving for the mechanism table and measured speedups.
+EXPERIMENTS.md §Serving for the mechanism table.
 
   PYTHONPATH=src python -m repro.launch.serve --arch gemma3-1b --requests 8 \
-      --autotune --compare-prefill
+      --autotune
 
 ``--size full`` serves the arch's published config (``configs.get``) instead
 of its reduced CPU preset; run it on the accelerator:
 
   PYTHONPATH=src python -m repro.launch.serve --arch gemma3-1b --size full \
       --autotune --prompt-len 512 --gen-len 32
-
-``--compare-prefill`` additionally times the legacy token-by-token prefill
-loop (decode steps over a padded batch) against the engine's chunked prefill
-on the same prompts and prints the wall-clock speedup.
 
 ``--precision w8a8`` serves through the paper's int8 deployment datapath:
 warmup calibrates/quantizes the weights int8-resident and compiles int8
@@ -29,16 +25,10 @@ from __future__ import annotations
 import argparse
 import json
 import time
-from typing import List
-
-import jax
-import jax.numpy as jnp
 import numpy as np
 
 from repro import configs
-from repro.launch import steps as steps_lib
 from repro.launch.compile_cache import enable_compile_cache
-from repro.models import model as M
 from repro.serving.engine import (  # re-exported for back-compat
     Engine,
     autotune_for_serving,
@@ -47,7 +37,7 @@ from repro.serving.engine import (  # re-exported for back-compat
 from repro.serving.request import PRIORITIES, RequestSpec, SamplingParams
 
 __all__ = ["Engine", "autotune_for_serving", "serving_gemm_shapes",
-           "token_by_token_prefill", "serve_cluster", "main"]
+           "serve_cluster", "main"]
 
 
 def _parse_class_mix(spec: str):
@@ -71,90 +61,6 @@ def _sampling_from_args(args) -> SamplingParams:
     return SamplingParams(temperature=args.temperature, top_k=args.top_k,
                           top_p=args.top_p,
                           seed=args.seed if args.seed >= 0 else None)
-
-
-def warm_token_by_token(cfg, params, slots: int, max_seq: int):
-    """Compile the baseline's decode step and build its initial state
-    *before* any timed region — the same footing the engine gets from
-    Engine.warmup().  Returns (jitted step, initial decode state) to pass
-    into token_by_token_prefill."""
-    serve_step = jax.jit(steps_lib.make_serve_step(cfg))
-    state = M.init_decode_state(params, cfg, slots, max_seq)
-    out, _ = serve_step(params, state, jnp.zeros((slots, 1), jnp.int32))
-    jax.block_until_ready(out)
-    return serve_step, state
-
-
-def token_by_token_prefill(cfg, params, prompts: List[np.ndarray], *,
-                           max_seq: int, warmed=None):
-    """The pre-engine prefill path, kept as the comparison baseline: pad all
-    prompts to the batch max and feed them through the decode step one token
-    at a time (short prompts burn dead steps on their padding positions).
-
-    Pass `warmed` from warm_token_by_token() when timing this, so the
-    measurement is steady-state dispatch — not the jit trace+compile or the
-    dense cache allocation.  Returns (last logits, state, step call count).
-    """
-    slots = len(prompts)
-    if warmed is None:
-        warmed = warm_token_by_token(cfg, params, slots, max_seq)
-    serve_step, state = warmed
-    maxlen = max(len(p) for p in prompts)
-    padded = np.zeros((slots, maxlen), np.int32)
-    for i, p in enumerate(prompts):
-        padded[i, :len(p)] = p
-    last = None
-    for t in range(maxlen):
-        last, state = serve_step(params, state, jnp.asarray(padded[:, t:t + 1]))
-    jax.block_until_ready(last)
-    return last, state, maxlen
-
-
-def compare_prefill(cfg, params, prompts: List[np.ndarray], *, slots: int,
-                    max_seq: int, block_size: int = 16, num_blocks=None,
-                    max_chunk: int = 64, iters: int = 3):
-    """Time legacy token-by-token prefill vs the engine's chunked prefill on
-    the same prompts; returns (t_legacy_s, t_chunked_s).
-
-    Both paths are pre-compiled (warm_token_by_token / Engine.warmup) and
-    the iterations *interleave* legacy/chunked runs, each side reported as
-    its best-of-`iters` — so shared-host load spikes hit both paths alike
-    and the ratio measures steady-state step-count/batching effects.
-    Engine iterations after the first refill previously-used slots —
-    steady-state serving, slot resets included.  The one comparison harness
-    behind both the ``--compare-prefill`` CLI flag and
-    benchmarks/serving_bench.py.
-    """
-    if params is None:
-        params = M.init_model(jax.random.PRNGKey(0), cfg)
-    warmed = warm_token_by_token(cfg, params, slots, max_seq)
-    eng = Engine(cfg, params=params, slots=slots, max_seq=max_seq,
-                 block_size=block_size, num_blocks=num_blocks,
-                 max_chunk=max_chunk)
-    eng.warmup()
-
-    def legacy():
-        token_by_token_prefill(cfg, params, prompts[:slots],
-                               max_seq=max_seq, warmed=warmed)
-
-    def chunked():
-        # max_new=1: the first token falls out of the final chunk, so each
-        # run is pure prefill.
-        for p in prompts[:slots]:
-            eng.submit(RequestSpec(prompt=p, max_new=1))
-        eng.run()
-
-    t_legacy, t_chunked = float("inf"), float("inf")
-    for _ in range(iters):
-        t_legacy = min(t_legacy, _timed(legacy))
-        t_chunked = min(t_chunked, _timed(chunked))
-    return t_legacy, t_chunked
-
-
-def _timed(fn) -> float:
-    t0 = time.perf_counter()
-    fn()
-    return time.perf_counter() - t0
 
 
 def _build_recorder(args, *, metadata=None):
@@ -301,8 +207,6 @@ def main(argv=None):
                     help="KV pool residency: int8 keeps the paged pool "
                          "int8-resident (per-block scales, in-kernel "
                          "dequant) — ~half the pool bytes per token")
-    ap.add_argument("--compare-prefill", action="store_true",
-                    help="time legacy token-by-token prefill vs the engine")
     ap.add_argument("--replicas", type=int, default=1,
                     help=">1 serves through repro.cluster: a replica pool "
                          "behind an async router")
@@ -443,14 +347,6 @@ def main(argv=None):
         with open(args.metrics_json, "w") as f:
             json.dump(eng.metrics.as_dict(), f, indent=2)
         print(f"metrics: {args.metrics_json}")
-
-    if args.compare_prefill:
-        t_legacy, t_chunked = compare_prefill(
-            cfg, eng.params, prompts, slots=slots, max_seq=max_seq,
-            block_size=args.block_size, num_blocks=args.kv_blocks or None,
-            max_chunk=args.chunk)
-        print(f"prefill: token-by-token {t_legacy*1e3:.0f}ms vs chunked "
-              f"{t_chunked*1e3:.0f}ms -> {t_legacy / t_chunked:.1f}x speedup")
     return gen
 
 

@@ -1,5 +1,5 @@
-"""Step builders: train_step / prefill_step / serve_step, plus the
-ShapeDtypeStruct input_specs for every (arch x shape) dry-run cell.
+"""Step builders: train_step / prefill_step / the paged serving steps, plus
+the ShapeDtypeStruct input_specs for every (arch x shape) dry-run cell.
 
 These are the functions the dry-run lowers and the real launchers execute —
 one definition, both uses.
@@ -48,13 +48,6 @@ def make_prefill_step(cfg: ArchConfig):
         return M.forward(params, cfg, batch, last_only=True)
 
     return prefill_step
-
-
-def make_serve_step(cfg: ArchConfig):
-    def serve_step(params, state: M.DecodeState, tokens):
-        return M.decode_step(params, cfg, state, tokens)
-
-    return serve_step
 
 
 def make_paged_serve_step(cfg: ArchConfig):
@@ -151,15 +144,19 @@ def opt_state_struct(cfg: ArchConfig, p_struct, opt_cfg: AdamWConfig):
     ))
 
 
-def decode_state_struct(cfg: ArchConfig, p_struct, batch: int, max_seq: int):
-    def build():
-        params = jax.tree_util.tree_map(lambda s: jnp.zeros(s.shape, s.dtype), p_struct)
-        enc = None
-        if cfg.family == "encdec":
-            enc = jnp.zeros((batch, cfg.encoder_seq, cfg.d_model), cfg.jax_dtype)
-        return M.init_decode_state(params, cfg, batch, max_seq, encoder_out=enc)
+def paged_state_struct(cfg: ArchConfig, slots: int, max_seq: int,
+                       block_size: int = 16):
+    """The decode state an Engine of ``slots`` slots at ``max_seq`` holds:
+    ``block_size``-token blocks and the engine's default (worst-case) pool.
+    Raises ``init_paged_decode_state``'s NotImplementedError for a family
+    paged serving refuses."""
+    from repro.serving import kv_cache as kvc
 
-    return jax.eval_shape(build)
+    return jax.eval_shape(lambda: M.init_paged_decode_state(
+        cfg, slots,
+        num_blocks=kvc.default_pool_blocks(slots, max_seq, block_size),
+        block_size=block_size,
+        max_blocks_per_slot=kvc.blocks_for(max_seq, block_size)))
 
 
 def input_specs(cfg: ArchConfig, shape: Dict[str, Any]) -> Dict[str, Any]:
@@ -167,7 +164,8 @@ def input_specs(cfg: ArchConfig, shape: Dict[str, Any]) -> Dict[str, Any]:
 
     train  -> {"batch": {tokens, labels, ...}}
     prefill-> {"batch": {tokens, ...}}
-    decode -> {"tokens": (B,1), "max_seq": S}  (DecodeState built separately)
+    decode -> {"tokens": (B,1), "active": (B,), "max_seq": S}  (the paged
+              state comes from paged_state_struct)
     """
     kind, S, B = shape["kind"], shape["seq_len"], shape["global_batch"]
     if kind == "train":
@@ -181,5 +179,6 @@ def input_specs(cfg: ArchConfig, shape: Dict[str, Any]) -> Dict[str, Any]:
     if kind == "prefill":
         return {"batch": {"tokens": S32((B, S)), **_batch_extras(cfg, B)}}
     if kind == "decode":
-        return {"tokens": S32((B, 1)), "max_seq": S}
+        return {"tokens": S32((B, 1)),
+                "active": jax.ShapeDtypeStruct((B,), jnp.bool_), "max_seq": S}
     raise ValueError(kind)

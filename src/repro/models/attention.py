@@ -1,5 +1,5 @@
 """Attention: GQA/MQA/MHA with qk-norm, sliding windows, cross-attention,
-KV-cache decode, and a memory-bounded blockwise (flash-style) prefill path.
+paged-KV decode, and a memory-bounded blockwise (flash-style) prefill path.
 
 The blockwise path scans over KV blocks with an online softmax so 32k-token
 prefill never materializes the full (S, S) score matrix — required for the
@@ -9,7 +9,7 @@ prefill never materializes the full (S, S) score matrix — required for the
 from __future__ import annotations
 
 import functools
-from typing import NamedTuple, Optional
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -18,13 +18,6 @@ from repro.models import layers
 from repro.parallel.logical import shard
 
 NEG_INF = -2.0e38
-
-
-class KVCache(NamedTuple):
-    """Per-layer-kind decode cache: k/v (B, S_max, H_kv, D), f32 position."""
-
-    k: jax.Array
-    v: jax.Array
 
 
 def init_attention(key, cfg, *, cross: bool = False):
@@ -212,12 +205,8 @@ def decode_attention(
     at the same position, classic lock-step decode) or a (B,) vector (paged
     serving: every slot at its own length).  Sq may be > 1 (chunked prefill:
     query t sits at position index + t and attends causally up to itself).
-
-    With the cache sequence-sharded on the model axis, the score einsum and
-    the weighted sum stay fully local per shard; only the softmax statistics
-    (B, H) reduce across shards.  The scan-based path would dynamic-slice
-    the sharded cache and all-gather every block (measured 86 GB/device/token
-    on dbrx decode_32k — EXPERIMENTS.md §Perf).
+    The paged ``gather`` backend (kernels/flash_decode.py) and the kernel
+    tests' reference run it over slot views of the pool.
     """
     B, Sq, Hq, D = q.shape
     Skv, Hkv = k.shape[1], k.shape[2]
@@ -255,7 +244,7 @@ def attention(
     window: Optional[int] = None,
     prefix_len: int = 0,
     kv_src: Optional[jax.Array] = None,
-    cache: Optional[KVCache] = None,
+    cache=None,
     cache_index: Optional[jax.Array] = None,
     block_tables: Optional[jax.Array] = None,
     block_base=0,
@@ -263,13 +252,11 @@ def attention(
     """Full attention sublayer.  Returns (out, new_cache).
 
     Prefill / training: cache is None -> blockwise attention over x itself
-    (or kv_src for cross-attention).  Dense decode: cache holds
-    (B, S_max, Hkv, D); x is (B, 1, d) and cache_index the scalar write
-    position.  Paged decode/prefill: cache is a PagedKVCache pool,
-    block_tables (B, max_blocks) addresses it, and cache_index is the (B,)
-    per-slot first-token position (x may carry S > 1 chunk tokens);
-    block_base is the pool row of this layer's block 0 when the pool merges
-    several layers' pools (serving/kv_cache.py ``write_kv``).
+    (or kv_src for cross-attention).  Paged decode/prefill: cache is a
+    PagedKVCache pool, block_tables (B, max_blocks) addresses it, and
+    cache_index is the (B,) per-slot first-token position (x may carry S > 1
+    chunk tokens); block_base is the pool row of this layer's block 0 when
+    the pool merges several layers' pools (serving/kv_cache.py ``write_kv``).
     """
     cross = kv_src is not None
     src = kv_src if cross else x
@@ -277,39 +264,24 @@ def attention(
                            rope=not (cross or cfg.nope))
     scale = cfg.attention_multiplier       # None: head_dim ** -0.5
 
-    if cache is not None and not cross:
+    if cache is not None:
+        # Paged decode: write this step's k/v through the block table, then
+        # attend over the pool directly (block-table walk) — the
+        # gather/blocked/flash backend choice lives in
+        # kernels/flash_decode.py and binds at trace time.
+        from repro.kernels import flash_decode as _fd
         from repro.serving import kv_cache as paged
 
-        if isinstance(cache, paged.PagedKVCache):
-            # Paged decode: write this step's k/v through the block table,
-            # then attend over the pool directly (block-table walk) — the
-            # gather/blocked/flash backend choice lives in
-            # kernels/flash_decode.py and binds at trace time.
-            from repro.kernels import flash_decode as _fd
-
-            assert block_tables is not None, "paged cache needs block_tables"
-            new_cache = paged.write_kv(cache, block_tables, k, v, cache_index,
-                                       block_base=block_base)
-            out = _fd.paged_decode_attention(
-                q, new_cache, block_tables, cache_index,
-                window=window, prefix_len=prefix_len, scale=scale,
-                block_base=block_base,
-            )
-        else:
-            # Dense decode: append this step's k/v then attend over the cache.
-            k_cache = jax.lax.dynamic_update_slice_in_dim(cache.k, k.astype(cache.k.dtype), cache_index, axis=1)
-            v_cache = jax.lax.dynamic_update_slice_in_dim(cache.v, v.astype(cache.v.dtype), cache_index, axis=1)
-            new_cache = KVCache(k_cache, v_cache)
-            out = decode_attention(
-                q, k_cache, v_cache, index=cache_index,
-                window=window, prefix_len=prefix_len, scale=scale,
-            )
+        assert block_tables is not None, "paged cache needs block_tables"
+        new_cache = paged.write_kv(cache, block_tables, k, v, cache_index,
+                                   block_base=block_base)
+        out = _fd.paged_decode_attention(
+            q, new_cache, block_tables, cache_index,
+            window=window, prefix_len=prefix_len, scale=scale,
+            block_base=block_base,
+        )
     else:
         new_cache = None
-        if cross and cache is not None:
-            # Cross-attention decode reuses the precomputed encoder cache.
-            k, v = cache.k, cache.v
-            new_cache = cache
         out = blockwise_attention(
             q, k, v, causal=causal and not cross, window=window,
             prefix_len=prefix_len, softcap=cfg.logit_softcap, scale=scale,

@@ -89,12 +89,12 @@ def apply_block(
     cache: Optional[Any] = None,
     cache_index: Optional[jax.Array] = None,
     encoder_out: Optional[jax.Array] = None,
-    cross_cache: Optional[attn_lib.KVCache] = None,
     block_tables: Optional[jax.Array] = None,
     block_base=0,
     collect_states: bool = False,
 ) -> Tuple[jax.Array, Any]:
-    """Returns (x, new_mixer_cache).  cache is the mixer state (KV / SSM).
+    """Returns (x, new_mixer_cache).  cache is the mixer state (paged KV
+    pool / recurrent state).
 
     ``collect_states`` asks recurrent mixers for per-position states (an
     extra (S,) axis on every state leaf) instead of the final state —
@@ -136,8 +136,7 @@ def apply_block(
             h = _norm(x, p["norm_cross"], cfg)
             h, _ = attn_lib.attention(
                 h, p["cross"], cfg, positions=positions, causal=False,
-                kv_src=encoder_out if cross_cache is None else h,  # decode
-                cache=cross_cache, cache_index=None,
+                kv_src=encoder_out,
             )
             x = _residual(x, h, cfg)
 
@@ -169,7 +168,7 @@ def init_group(key, cfg, *, cross_attention: bool = False):
 
 def apply_group(
     x, gp, cfg, *, positions, causal=True, prefix_len=0,
-    caches=None, cache_index=None, encoder_out=None, cross_caches=None,
+    caches=None, cache_index=None, encoder_out=None,
     block_tables=None, block_base=0, collect_states=False,
 ):
     """Apply one group of cfg.group_size blocks; returns (x, new_caches).
@@ -184,7 +183,6 @@ def apply_group(
             cache=None if caches is None else caches[i],
             cache_index=cache_index,
             encoder_out=encoder_out,
-            cross_cache=None if cross_caches is None else cross_caches[i],
             block_tables=block_tables, block_base=block_base,
             collect_states=collect_states,
         )
@@ -192,14 +190,13 @@ def apply_group(
     return x, tuple(new_caches)
 
 
-def init_cache_for_kind(cfg, kind: str, batch: int, max_seq: int):
-    """Decode-state template for one block of the given kind."""
+def init_cache_for_kind(cfg, kind: str, batch: int):
+    """Per-slot recurrent state of one block of a recurrent kind; attention
+    kinds keep their KV in a paged pool (``init_paged_cache_for_kind``)."""
     if kind in ("attn", "attn_local"):
-        hd = cfg.resolved_head_dim
-        shape = (batch, max_seq, cfg.n_kv_heads, hd)
-        return attn_lib.KVCache(
-            k=jnp.zeros(shape, cfg.jax_dtype), v=jnp.zeros(shape, cfg.jax_dtype)
-        )
+        raise ValueError(
+            f"{kind!r} layers hold no per-slot state: their KV lives in a "
+            f"paged pool, see init_paged_cache_for_kind")
     if kind == "mamba":
         return ssm.init_mamba_state(cfg, batch)
     if kind == "mlstm":
@@ -224,4 +221,4 @@ def init_paged_cache_for_kind(
             num_blocks, block_size, cfg.n_kv_heads, cfg.resolved_head_dim,
             cfg.jax_dtype, kv_precision=kv_precision,
         )
-    return init_cache_for_kind(cfg, kind, batch, 0)
+    return init_cache_for_kind(cfg, kind, batch)

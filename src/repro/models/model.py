@@ -1,13 +1,16 @@
 """Model assembly: decoder LMs, hybrid/SSM LMs, encoder-decoder (whisper),
-and prefix-LM VLM (paligemma), with scan-over-groups execution, KV/SSM decode
-caches and the training loss.
+and prefix-LM VLM (paligemma), with scan-over-groups execution, the paged
+serving steps and the training loss.
 
 Public API:
   init_model(key, cfg)                         -> params
   forward(params, cfg, batch)                  -> logits        (train/prefill)
   loss_fn(params, cfg, batch)                  -> scalar loss
-  init_decode_state(params, cfg, batch, seq)   -> DecodeState
-  decode_step(params, cfg, state, tokens)      -> (logits, DecodeState)
+  init_paged_decode_state(cfg, slots, ...)     -> PagedDecodeState
+  paged_decode_step(params, cfg, state, tokens, active)
+                                               -> (logits, PagedDecodeState)
+  prefill_chunk(params, cfg, state, tokens, slot)
+                                               -> (logits, PagedDecodeState)
 
 `batch` dict keys: "tokens" (B, S) int32 always; "frames" (B, S_enc, d) for
 encdec (audio frontend stub); "patches" (B, P, d_vision) for vlm.
@@ -20,7 +23,6 @@ from typing import Any, Dict, NamedTuple, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.models import attention as attn_lib
 from repro.models import blocks, layers
 from repro.models.config import ArchConfig
 from repro.parallel.logical import shard
@@ -92,21 +94,14 @@ def _run_encoder(frames, params, cfg):
     return blocks._norm(x, params["encoder_norm"], cfg)
 
 
-def forward(
-    params, cfg: ArchConfig, batch: Dict[str, jax.Array], *,
-    last_only: bool = False,
-) -> jax.Array:
-    """Logits for the whole sequence, or only the final position when
-    `last_only` (serving prefill: the (B, S, vocab) tensor at 32k x 262k
-    vocab is ~TBs and is never needed — only the next-token logits are)."""
+def trunk(params, cfg: ArchConfig, batch: Dict[str, jax.Array]) -> jax.Array:
+    """Final hidden states (B, S, d) before the unembedding."""
     tokens = batch["tokens"]
-    B, S = tokens.shape
+    S = tokens.shape[1]
     x = layers.embed(tokens, params["embed"], cfg.embedding_multiplier)
-
     prefix_len = 0
     encoder_out = None
     positions = jnp.arange(S)
-
     if cfg.family == "vlm":
         prefix = layers.dense(batch["patches"].astype(cfg.jax_dtype), params["projector"])
         x = jnp.concatenate([prefix, x], axis=1)
@@ -114,17 +109,29 @@ def forward(
         positions = jnp.arange(x.shape[1])
     elif cfg.family == "encdec":
         encoder_out = _run_encoder(batch["frames"], params, cfg)
-
     x = shard(x, "batch", "seq", "embed")
     x = _run_groups(
         x, params["blocks"], cfg, positions=positions,
         prefix_len=prefix_len, encoder_out=encoder_out,
     )
+    x = blocks._norm(x, params["final_norm"], cfg)
     if cfg.family == "vlm":
         x = x[:, prefix_len:]
+    return x
+
+
+def forward(
+    params, cfg: ArchConfig, batch: Dict[str, jax.Array], *,
+    last_only: bool = False,
+) -> jax.Array:
+    """Logits for the whole sequence, or only the final position when
+    `last_only` (serving prefill: the (B, S, vocab) tensor at 32k x 262k
+    vocab is ~TBs and is never needed — only the next-token logits are)."""
+    x = trunk(params, cfg, batch)
     if last_only:
         x = x[:, -1:]
-    return _head(x, params, cfg)
+    with jax.named_scope("unembed"):
+        return _unembed(x, params, cfg)
 
 
 def _head(x, params, cfg):
@@ -149,32 +156,6 @@ def _unembed(x, params, cfg):
     if cfg.logits_scaling != 1.0:
         logits = logits / jnp.asarray(cfg.logits_scaling, logits.dtype)
     return logits
-
-
-def trunk(params, cfg: ArchConfig, batch: Dict[str, jax.Array]) -> jax.Array:
-    """Final hidden states (B, S, d) before the unembedding."""
-    tokens = batch["tokens"]
-    B, S = tokens.shape
-    x = layers.embed(tokens, params["embed"], cfg.embedding_multiplier)
-    prefix_len = 0
-    encoder_out = None
-    positions = jnp.arange(S)
-    if cfg.family == "vlm":
-        prefix = layers.dense(batch["patches"].astype(cfg.jax_dtype), params["projector"])
-        x = jnp.concatenate([prefix, x], axis=1)
-        prefix_len = prefix.shape[1]
-        positions = jnp.arange(x.shape[1])
-    elif cfg.family == "encdec":
-        encoder_out = _run_encoder(batch["frames"], params, cfg)
-    x = shard(x, "batch", "seq", "embed")
-    x = _run_groups(
-        x, params["blocks"], cfg, positions=positions,
-        prefix_len=prefix_len, encoder_out=encoder_out,
-    )
-    x = blocks._norm(x, params["final_norm"], cfg)
-    if cfg.family == "vlm":
-        x = x[:, prefix_len:]
-    return x
 
 
 def loss_fn(
@@ -211,89 +192,14 @@ def loss_fn(
 
 
 # ---------------------------------------------------------------------------
-# decode
-# ---------------------------------------------------------------------------
-
-class DecodeState(NamedTuple):
-    caches: Any                   # per-group tuple-of-kind states (stacked)
-    cross_caches: Any             # encdec only
-    index: jax.Array              # current position (scalar int32)
-
-
-def init_decode_state(
-    params, cfg: ArchConfig, batch: int, max_seq: int,
-    encoder_out: Optional[jax.Array] = None,
-) -> DecodeState:
-    kinds = cfg.layer_kinds()
-
-    def make_group(_):
-        return tuple(
-            blocks.init_cache_for_kind(cfg, kind, batch, max_seq) for kind in kinds
-        )
-
-    caches = jax.vmap(make_group)(jnp.arange(cfg.n_groups))
-    cross = None
-    if cfg.family == "encdec":
-        assert encoder_out is not None
-
-        def make_cross(gp):
-            out = []
-            for i in range(cfg.group_size):
-                p = gp[f"sub{i}"]["cross"]
-                hd, hkv = cfg.resolved_head_dim, cfg.n_kv_heads
-                k = layers.dense(encoder_out, p["wk"]).reshape(
-                    batch, -1, hkv, hd)
-                v = layers.dense(encoder_out, p["wv"]).reshape(
-                    batch, -1, hkv, hd)
-                out.append(attn_lib.KVCache(k, v))
-            return tuple(out)
-
-        cross = jax.vmap(lambda g: make_cross(g))(params["blocks"])
-    return DecodeState(caches=caches, cross_caches=cross, index=jnp.zeros((), jnp.int32))
-
-
-def decode_step(
-    params, cfg: ArchConfig, state: DecodeState, tokens: jax.Array,
-) -> Tuple[jax.Array, DecodeState]:
-    """One token for every sequence: tokens (B, 1) -> logits (B, 1, vocab)."""
-    B = tokens.shape[0]
-    x = _embed_tokens(params, cfg, tokens)
-    positions = state.index[None] + jnp.zeros((B, 1), jnp.int32)
-
-    if state.cross_caches is None:
-        x, new_caches = _trunk_step(
-            params, cfg, x, positions, state.caches, state.index, None)
-    else:
-
-        def body(h, xs):
-            gp, gcache, gcross = xs
-            h, new_caches = blocks.apply_group(
-                h, gp, cfg, positions=positions, causal=True,
-                caches=gcache, cache_index=state.index, cross_caches=gcross,
-            )
-            return h, new_caches
-
-        x, new_caches = jax.lax.scan(
-            body, x, (params["blocks"], state.caches, state.cross_caches)
-        )
-
-    logits = _head(x, params, cfg)
-    new_state = DecodeState(
-        caches=new_caches, cross_caches=state.cross_caches, index=state.index + 1
-    )
-    return logits, new_state
-
-
-# ---------------------------------------------------------------------------
 # paged serving: per-slot lengths, block-table KV addressing, chunked prefill
 # ---------------------------------------------------------------------------
 
 class PagedDecodeState(NamedTuple):
     """Serving decode state: shared KV block pools + per-slot request state.
 
-    Unlike `DecodeState`'s single scalar position, every slot tracks its own
-    length, so slots can be refilled mid-flight (continuous batching) without
-    re-initializing anyone else's state.
+    Every slot tracks its own length, so slots can be refilled mid-flight
+    (continuous batching) without re-initializing anyone else's state.
     """
 
     caches: Any                   # per-group tuple-of-kind states (stacked);
@@ -331,8 +237,8 @@ def _trunk_step(params, cfg, x, positions, caches, cache_index, block_tables,
                 collect_states=False):
     """Scan the block groups in decode mode; returns (hidden, new_caches).
 
-    Paged KV pools ride in the scan's carry, recurrent and dense caches in
-    its xs/ys.  Each stacked ``(G, nb, H, bs, D)`` pool is seen as one
+    Paged KV pools ride in the scan's carry, recurrent states in its
+    xs/ys.  Each stacked ``(G, nb, H, bs, D)`` pool is seen as one
     ``(G * nb, H, bs, D)`` pool (merging the leading dims is a bitcast), so
     group g's block b is row ``g * nb + b`` and every layer writes its new
     tokens in place.  Scanned as xs/ys, each pool would be sliced out and
@@ -773,7 +679,7 @@ def reset_slots(
         if isinstance(cur, PagedKVCache):
             fresh.append(cur)
             continue
-        one = blocks.init_cache_for_kind(cfg, kind, 1, 0)   # batch-1 template
+        one = blocks.init_cache_for_kind(cfg, kind, 1)      # batch-1 template
 
         def sel(full, init):
             m = mask.reshape((1, -1) + (1,) * (full.ndim - 2))
@@ -783,31 +689,3 @@ def reset_slots(
     lengths = jnp.where(mask, 0, state.lengths)
     return PagedDecodeState(
         caches=tuple(fresh), block_tables=state.block_tables, lengths=lengths)
-
-
-def prefill(
-    params, cfg: ArchConfig, batch: Dict[str, jax.Array], max_seq: int,
-) -> Tuple[jax.Array, DecodeState]:
-    """Run the full prompt, building decode caches (serving prefill path).
-
-    Returns (last-position logits, DecodeState ready for decode_step).
-    Implemented as forward + cache construction through decode-shaped
-    updates; for simplicity the caches are built by re-projecting K/V per
-    group (no attention recompute).
-    """
-    tokens = batch["tokens"]
-    B, S = tokens.shape
-    encoder_out = None
-    if cfg.family == "encdec":
-        encoder_out = _run_encoder(batch["frames"], params, cfg)
-    state = init_decode_state(params, cfg, B, max_seq, encoder_out=encoder_out)
-    logits = forward(params, cfg, batch)
-    # Populate caches by replaying K/V projections blockwise.
-    # (The dry-run lowers decode_step and forward separately; this utility is
-    # for the CPU serving example, where S is small.)
-    def write_token(state, t):
-        logits_t, state = decode_step(params, cfg, state, tokens[:, t][:, None])
-        return state, logits_t
-
-    state, _ = jax.lax.scan(write_token, state, jnp.arange(S))
-    return logits[:, -1:], state

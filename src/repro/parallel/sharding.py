@@ -290,29 +290,39 @@ def batch_sharding(batch, mesh: Mesh, plan: ParallelPlan):
     return jax.tree_util.tree_map(leaf, batch)
 
 
-def cache_sharding(cache_struct, mesh: Mesh, plan: ParallelPlan):
-    """Decode-state sharding: batch on data axes, heads/d_inner on model.
+def cache_sharding(state, mesh: Mesh, plan: ParallelPlan):
+    """Sharding of a paged decode state (models/model.py PagedDecodeState).
 
-    Cache leaves (after group stacking, leading G dim):
-      KV:        (G, B, S, H_kv, hd)
-      Mamba h:   (G, B, d_inner, d_state);  conv (G, B, dc-1, d_inner)
-      mLSTM C:   (G, B, H, hd, hd); n (G, B, H, hd); m (G, B, H)
-      sLSTM:     (G, B, H, hd) x3; m (G, B, H)
+    Block tables and lengths put their slots on the batch axes.  KV pools
+    (G, num_blocks, H_kv, block_size, D) and their scales split H_kv over
+    the model axis where it divides, else replicate: any slot may read any
+    block.  Recurrent states (G, slots, ...) put slots on the batch axes and
+    their first wide dim on the model axis where it divides.
     """
-    M = plan.model_axis
+    from repro.serving.kv_cache import PagedKVCache
 
-    def leaf(path, x):
-        spec: list = [None] * x.ndim
-        if x.ndim >= 2:
-            axes = _best_batch_axes(x.shape[1], plan.batch_axes, mesh)
-            if axes:
-                spec[1] = axes if len(axes) > 1 else axes[0]
-        # shard the "wide" state dim on model where divisible
-        if M is not None:
-            for d in range(2, x.ndim):
-                if x.shape[d] % mesh.shape[M] == 0 and x.shape[d] >= mesh.shape[M]:
-                    spec[d] = M
-                    break
+    M = plan.model_axis
+    model = mesh.shape[M] if M is not None else 0
+
+    def batch(n):
+        axes = _best_batch_axes(n, plan.batch_axes, mesh)
+        return (axes if len(axes) > 1 else axes[0]) if axes else None
+
+    def pool(x):
+        heads = M if model and x.shape[2] % model == 0 else None
+        return NamedSharding(mesh, P(None, None, heads, *[None] * (x.ndim - 3)))
+
+    def recurrent(x):
+        spec: list = [None, batch(x.shape[1])] + [None] * (x.ndim - 2)
+        wide = [d for d in range(2, x.ndim)
+                if model and x.shape[d] % model == 0 and x.shape[d] >= model]
+        if wide:
+            spec[wide[0]] = M
         return NamedSharding(mesh, P(*spec))
 
-    return jax.tree_util.tree_map_with_path(leaf, cache_struct)
+    caches = tuple(jax.tree_util.tree_map(
+        pool if isinstance(c, PagedKVCache) else recurrent, c)
+        for c in state.caches)
+    tables, lengths = batch_sharding((state.block_tables, state.lengths),
+                                     mesh, plan)
+    return state._replace(caches=caches, block_tables=tables, lengths=lengths)

@@ -47,9 +47,9 @@ NULL_BLOCK = 0
 class PagedKVCache(NamedTuple):
     """Block-pooled decode cache for one attention layer (or a stacked group).
 
-    Mirrors ``attention.KVCache``'s (k, v) fields so the two cache kinds are
-    interchangeable pytree leaves; ``isinstance`` distinguishes them where
-    the addressing differs.
+    It sits in a decode state's per-kind caches beside the recurrent
+    kinds' per-slot states; ``isinstance`` tells the pools apart where the
+    addressing differs.
 
     int8 residency (``Engine(kv_precision="int8")``): the pools hold int8
     codes and ``k_scale``/``v_scale`` hold per-(block, position, kv-head)

@@ -27,50 +27,69 @@ def make_batch(cfg, B=2, S=16, seed=0):
 
 @pytest.mark.parametrize("name", ARCHS)
 def test_smoke_forward_and_grad(name):
-    """Reduced same-family config: one forward + train grad on CPU."""
+    """Reduced same-family config: one forward + train grad on CPU; the
+    last-position forward is the full forward's last position (past the
+    VLM prefix and beside the encoder alike)."""
     cfg = configs.get_smoke(name)
     params = M.init_model(jax.random.PRNGKey(0), cfg)
     batch = make_batch(cfg)
     logits = M.forward(params, cfg, batch)
     assert logits.shape == (2, 16, cfg.vocab)
     assert not bool(jnp.any(jnp.isnan(logits))), name
+    last = M.forward(params, cfg, batch, last_only=True)
+    np.testing.assert_allclose(last, logits[:, -1:], rtol=1e-5, atol=1e-6)
     loss, grads = jax.value_and_grad(M.loss_fn)(params, cfg, batch)
     assert np.isfinite(float(loss))
     gn = sum(float(jnp.sum(jnp.abs(g))) for g in jax.tree_util.tree_leaves(grads))
     assert np.isfinite(gn) and gn > 0
 
 
+def paged_state(cfg, slots, max_len, block_size=4):
+    """A paged decode state whose slots own disjoint blocks for max_len
+    tokens each (block 0 stays the null block)."""
+    width = -(-max_len // block_size)
+    state = M.init_paged_decode_state(
+        cfg, slots, num_blocks=1 + slots * width, block_size=block_size,
+        max_blocks_per_slot=width)
+    tables = 1 + jnp.arange(slots * width, dtype=jnp.int32)
+    return state._replace(block_tables=tables.reshape(slots, width))
+
+
 @pytest.mark.parametrize("name", ARCHS)
 def test_smoke_decode_shapes(name):
+    """One paged decode step for every family paged serving serves; the
+    encoder-decoder and prefix-LM families are refused."""
     cfg = configs.get_smoke(name)
-    params = M.init_model(jax.random.PRNGKey(0), cfg)
     B = 2
-    enc = None
-    if cfg.family == "encdec":
-        frames = jax.random.normal(jax.random.PRNGKey(1), (B, cfg.encoder_seq, cfg.d_model))
-        enc = M._run_encoder(frames, params, cfg)
-    state = M.init_decode_state(params, cfg, B, 24, encoder_out=enc)
+    if cfg.family in ("encdec", "vlm"):
+        with pytest.raises(NotImplementedError, match=cfg.family):
+            paged_state(cfg, B, 24)
+        return
+    params = M.init_model(jax.random.PRNGKey(0), cfg)
+    state = paged_state(cfg, B, 24)
     tok = jnp.zeros((B, 1), jnp.int32)
-    logits, state = M.decode_step(params, cfg, state, tok)
+    logits, state = M.paged_decode_step(params, cfg, state, tok)
     assert logits.shape == (B, 1, cfg.vocab)
     assert not bool(jnp.any(jnp.isnan(logits)))
-    assert int(state.index) == 1
+    assert state.lengths.tolist() == [1] * B
 
 
 @pytest.mark.parametrize("name", ["qwen3-14b", "gemma3-1b", "xlstm-1.3b",
                                   "jamba-1.5-large-398b", "dbrx-132b"])
 def test_decode_matches_forward(name):
-    """Teacher-forced decode must reproduce the training forward logits."""
+    """Teacher-forced paged decode must reproduce the training forward
+    logits."""
     cfg = configs.get_smoke(name)
     params = M.init_model(jax.random.PRNGKey(0), cfg)
     B, S = 2, 8
     batch = make_batch(cfg, B, S)
     ref_logits = np.asarray(M.forward(params, cfg, batch), np.float32)
 
-    state = M.init_decode_state(params, cfg, B, S + 2)
+    state = paged_state(cfg, B, S)
     outs = []
     for t in range(S):
-        lg, state = M.decode_step(params, cfg, state, batch["tokens"][:, t:t + 1])
+        lg, state = M.paged_decode_step(params, cfg, state,
+                                        batch["tokens"][:, t:t + 1])
         outs.append(np.asarray(lg[:, 0], np.float32))
     dec_logits = np.stack(outs, axis=1)
     np.testing.assert_allclose(dec_logits, ref_logits, rtol=2e-2, atol=2e-3)
